@@ -21,6 +21,4 @@ def shard_ids(bits: int) -> list[int]:
 
 def shard_count(bits: int) -> int:
     """Number of shards present in a shard_bits word."""
-    # bin().count, not int.bit_count(): identical here and runs on
-    # interpreters older than 3.10 too
-    return bin(bits & ((1 << MAX_SHARD_ID) - 1)).count("1")
+    return (bits & ((1 << MAX_SHARD_ID) - 1)).bit_count()

@@ -19,8 +19,8 @@ fill, compute, write — genuinely overlap:
 * **Writeback plane.** Completed data/parity runs are handed to a
   `WriterPool` — one io thread per target shard-file group, bounded work
   queues, `os.pwrite` of batch-contiguous runs — so shard writeback overlaps
-  fill and compute instead of serializing behind them (BENCH_r04: 9.75 s of
-  coder under 43.66 s of serial writes). A writer failure (ENOSPC, bad disk)
+  fill and compute instead of serializing behind them (a round-4 host run:
+  9.75 s of coder under 43.66 s of serial writes). A writer failure (ENOSPC, bad disk)
   poisons the pool: the job fails cleanly, threads join, partial shard files
   are removed.
 * **Writer-gated double buffering.** `ErasureCoder.encode` on the JAX path
@@ -851,7 +851,7 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
         return done
 
     t_wall0 = time.perf_counter()
-    fill_s = dispatch_s = 0.0
+    fill_s = dispatch_s = first_dispatch_s = 0.0
     batches = 0
     drain_block = [0.0]
     dispatch_ts: list = []  # per-batch submit time (FIFO pipe)
@@ -898,6 +898,8 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
             t0 = time.perf_counter()
             fut = coder.encode(buf)
             dispatch_s += time.perf_counter() - t0
+            if not batches:  # the first dispatch traces and compiles
+                first_dispatch_s = dispatch_s
             dispatch_ts.append(t0)
             pipe.submit(fut, runs, drain)
             batches += 1
@@ -915,6 +917,7 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                      batch_bytes=batch * geo.d * chunk,
                      wall_s=time.perf_counter() - t_wall0,
                      fill_s=fill_s, dispatch_s=dispatch_s,
+                     first_dispatch_s=first_dispatch_s,
                      drain_block_s=drain_block[0],
                      write_s=pool.busy_s,
                      write_block_s=pool.block_s + pipe.recycle_wait_s,
@@ -924,4 +927,6 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                      # is the device-occupancy window, replacing the old
                      # estimated per-batch-time multiplication
                      dispatch_ts=dispatch_ts, done_ts=done_ts)
+        if getattr(coder, "batch_bytes_by_device", None):
+            stats["batch_bytes_by_device"] = coder.batch_bytes_by_device
     return out

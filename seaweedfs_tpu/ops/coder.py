@@ -7,8 +7,11 @@ default [CPU] path is untouched". Implementations:
 * ``NativeCoder`` — C++ sidecar (seaweedfs_tpu/native), AVX2 PSHUFB split
   tables: the faithful stand-in for klauspost/reedsolomon's asm, used as the
   CPU baseline that `vs_baseline` is measured against.
-* ``JaxCoder`` — the TPU path (ops/rs_jax bit-matmul; Pallas kernel when
-  available), batching [B, d, L] stripe tensors through the device.
+* ``JaxCoder`` — the TPU path (Pallas kernel, ops/rs_pallas), batching
+  [B, d, L] stripe tensors through the device. Constructing one goes
+  through the device gate (ops/device.py): without a TPU it raises, unless
+  the process was told ``JAX_PLATFORMS=cpu``, where it runs the XLA einsum
+  formulation (ops/rs_jax) so tests cover the same seam.
 
 All coders operate on uint8 arrays shaped [d, L] / [B, d, L] and are
 stateless w.r.t. data; geometry is fixed per instance.
@@ -20,7 +23,7 @@ import abc
 
 import numpy as np
 
-from . import gf8
+from . import device, gf8
 
 
 class ErasureCoder(abc.ABC):
@@ -85,20 +88,19 @@ class JaxCoder(ErasureCoder):
     """Device coder. Accepts numpy or jax arrays; returns device arrays
     (callers `np.asarray` when they need host bytes).
 
-    On a real TPU backend the Pallas kernel (ops/rs_pallas.py) carries the
-    hot path — unpack/matmul/pack pinned in VMEM; elsewhere (CPU tests,
-    GPU) it falls back to the XLA einsum formulation (ops/rs_jax.py).
+    On a TPU the Pallas kernel (ops/rs_pallas.py) carries the hot path —
+    unpack/matmul/pack pinned in VMEM. The device gate decides at
+    construction: no TPU raises DeviceError; a process told
+    JAX_PLATFORMS=cpu runs the XLA einsum formulation (ops/rs_jax.py).
     """
 
     async_dispatch = True
 
-    def __init__(self, d: int, p: int, use_pallas: "bool | None" = None):
+    def __init__(self, d: int, p: int):
         super().__init__(d, p)
-        if use_pallas is None:
-            from . import rs_pallas
-            use_pallas = rs_pallas.available()
-        self.use_pallas = use_pallas
-        self._interpret = False  # PallasCoder flips this for CPU tests
+        self.use_pallas = device.require(
+            type(self).__name__).platform == "tpu"
+        self._interpret = False  # PallasCoder flips this when told CPU
 
     def encode(self, data):
         if self.use_pallas:
@@ -133,13 +135,13 @@ def _as_batch(arr):
 
 
 class PallasCoder(JaxCoder):
-    """Force the Pallas path; interpreter mode off-TPU so tests cover the
-    kernel logic everywhere."""
+    """Always the Pallas kernel: compiled on a TPU, interpreted in a
+    process told JAX_PLATFORMS=cpu so tests cover the kernel logic."""
 
     def __init__(self, d: int, p: int):
-        from . import rs_pallas
-        super().__init__(d, p, use_pallas=True)
-        self._interpret = not rs_pallas.available()
+        super().__init__(d, p)
+        self._interpret = not self.use_pallas
+        self.use_pallas = True
 
 
 _REGISTRY = {"numpy": NumpyCoder, "jax": JaxCoder, "pallas": PallasCoder}

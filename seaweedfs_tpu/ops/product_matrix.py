@@ -245,16 +245,15 @@ class ProductMatrixCoder(ErasureCoder):
         if rows.shape[-1] == 0 or mat.shape[0] == 0 or mat.shape[1] == 0:
             return np.zeros((mat.shape[0], rows.shape[-1]), dtype=np.uint8)
         if self.backend not in ("numpy", "native"):
-            try:
-                import jax.numpy as jnp
+            # the inner coder's constructor already went through the
+            # device gate; a device failure here raises, like everywhere
+            import jax.numpy as jnp
 
-                from . import rs_jax
-                bmat = gf8.expand_to_bits(np.asarray(mat)).astype(np.int8)
-                out = rs_jax.apply_bitmatrix(jnp.asarray(bmat),
-                                             jnp.asarray(rows))
-                return np.asarray(out, dtype=np.uint8)
-            except Exception:  # noqa: BLE001  # swtpu-lint: disable=silent-except (device path is an optimization; numpy below is the correctness path)
-                pass
+            from . import rs_jax
+            bmat = gf8.expand_to_bits(np.asarray(mat)).astype(np.int8)
+            out = rs_jax.apply_bitmatrix(jnp.asarray(bmat),
+                                         jnp.asarray(rows))
+            return np.asarray(out, dtype=np.uint8)
         return gf8.np_gf_apply(mat, rows)
 
     # -- core: score-ordered layered decode --------------------------------

@@ -27,9 +27,18 @@ Replaces: klauspost/reedsolomon's AVX2 galMulSlicesAvx2 loops invoked from
 reference weed/storage/erasure_coding/ec_encoder.go:183 (`enc.Encode`) and
 weed/storage/store_ec.go:402 (`ReconstructData`).
 
-Availability: the compiled path needs a real TPU; `available()` gates it and
-ops/coder.JaxCoder falls back to rs_jax elsewhere. Tests run the kernel in
-interpreter mode on CPU so its logic is covered everywhere.
+Availability: the compiled path needs a TPU. `available()` asks the device
+gate (ops/device.py), which raises when the backend the process was told to
+use does not come up; JaxCoder runs this kernel on a TPU and the einsum
+formulation only in a process told JAX_PLATFORMS=cpu, where tests also run
+this kernel in interpreter mode so its logic is covered everywhere.
+
+Compiled on a v5e (JAX 0.9.0) at the shapes the daemons dispatch — encode
+and rebuild at [32, d, 1 MiB] for RS(14,2) and RS(10,4), degraded-read
+intervals at [1, d, C] — tile 32768 fits the default 16 MiB scoped VMEM at
+both geometries. The lane axis is padded to whole tiles inside `_apply`: a
+whole-C block of arbitrary length (the old fallback for a C no 128-multiple
+divides) took 9 s to compile at C=300001 and ran out of VMEM at C=777777.
 """
 
 from __future__ import annotations
@@ -43,17 +52,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import gf8
+from . import device, gf8
 
 DEFAULT_TILE = 1 << 15  # lane-dim tile; best measured on v5e (sweep 2K-32K)
+_LANE = 128
 
 
-@functools.lru_cache(maxsize=1)
 def available() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 — no backend at all
-        return False
+    """True on a TPU; a backend that cannot come up raises in the gate."""
+    return device.info().platform == "tpu"
 
 
 @functools.lru_cache(maxsize=512)
@@ -117,15 +124,6 @@ def _make_kernel(d: int, dp: int, tile: int):
     return kernel
 
 
-def _pick_tile(c: int, tile: int) -> int:
-    if c % tile == 0:
-        return tile
-    # largest 128-aligned divisor of c no bigger than the requested tile;
-    # Mosaic requires the lane block be 128-divisible or the full dim
-    return next((t for t in range(tile - tile % 128, 0, -128)
-                 if c % t == 0), c)
-
-
 def _apply(bmat_key: tuple, data: jax.Array, seed: jax.Array, tile: int,
            interpret: bool) -> jax.Array:
     b, d, c = data.shape
@@ -133,10 +131,18 @@ def _apply(bmat_key: tuple, data: jax.Array, seed: jax.Array, tile: int,
     m = bmat.shape[0] // 8
     packm = _pack_matrix(m)
     dp = (d + 3) // 4 * 4
-    tile = _pick_tile(c, tile)
-    return pl.pallas_call(
+    # every block is whole lanes: C splits into the fewest equal tiles
+    # no longer than `tile`, each rounded up to the lane multiple, and
+    # the padding (< 128 columns per tile; zero columns encode to zero)
+    # is sliced off. 1 MiB slabs divide evenly and copy nothing.
+    steps = -(-c // tile)
+    tile = -(-c // (steps * _LANE)) * _LANE
+    cp = steps * tile
+    if cp != c:
+        data = jnp.pad(data, ((0, 0), (0, 0), (0, cp - c)))
+    out = pl.pallas_call(
         _make_kernel(d, dp, tile),
-        grid=(b, c // tile),
+        grid=(b, cp // tile),
         in_specs=[
             pl.BlockSpec(bmat.shape, lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
@@ -148,9 +154,10 @@ def _apply(bmat_key: tuple, data: jax.Array, seed: jax.Array, tile: int,
         ],
         out_specs=pl.BlockSpec((1, m, tile), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, m, c), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((b, m, cp), jnp.uint8),
         interpret=interpret,
     )(jnp.asarray(bmat), jnp.asarray(packm), seed, data)
+    return out if cp == c else out[..., :c]
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))

@@ -17,6 +17,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from ..ops import device
+
 
 def build_mesh(n_devices: int | None = None, shard_axis: int | None = None,
                devices=None) -> Mesh:
@@ -26,6 +28,7 @@ def build_mesh(n_devices: int | None = None, shard_axis: int | None = None,
     single chip yields a 1x1 mesh and 8 virtual devices a 2x4 mesh.
     """
     if devices is None:
+        device.require("a device mesh")
         devices = jax.devices()
     if n_devices is not None:
         if len(devices) < n_devices:

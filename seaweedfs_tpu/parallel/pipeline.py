@@ -22,13 +22,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf8
+from ..ops.coder import ErasureCoder, register_coder
 from ..ops.crc32c import device_crc_states
 from ..ops.rs_jax import pack_bits, unpack_bits
-
-try:  # jax >= 0.4.31 exports it at top level; older trees ship experimental
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -62,7 +58,7 @@ def encode_sharded(mesh: Mesh, data: jax.Array, d: int, p: int) -> jax.Array:
                          preferred_element_type=jnp.int32)
         return pack_bits(acc & 1)  # [B_loc, rows_per, L]
 
-    fn = _shard_map(kernel, mesh=mesh,
+    fn = jax.shard_map(kernel, mesh=mesh,
                        in_specs=P("data", None, None),
                        out_specs=P("data", "shard", None))
     return fn(data)
@@ -109,7 +105,7 @@ def rebuild_sharded(mesh: Mesh, shards: jax.Array,
                          preferred_element_type=jnp.int32)
         return pack_bits(acc & 1)
 
-    fn = _shard_map(kernel, mesh=mesh,
+    fn = jax.shard_map(kernel, mesh=mesh,
                        in_specs=P("data", "shard", None),
                        out_specs=P("data", "shard", None))
     return fn(shards)
@@ -132,7 +128,7 @@ def scrub_sharded(mesh: Mesh, blocks: jax.Array, expected_states: jax.Array,
         bad = jnp.sum((states != exp).astype(jnp.int32))
         return jax.lax.psum(bad, ("data", "shard"))
 
-    fn = _shard_map(kernel, mesh=mesh,
+    fn = jax.shard_map(kernel, mesh=mesh,
                        in_specs=(P(("data", "shard"), None), P(("data", "shard"))),
                        out_specs=P())
     return fn(blocks, expected_states)
@@ -144,8 +140,8 @@ def shard_put(mesh: Mesh, arr: np.ndarray, spec: P) -> jax.Array:
     return jax.device_put(arr, NamedSharding(mesh, spec))
 
 
-class MeshCoder:
-    """ErasureCoder facade over the mesh-sharded encode: the seam that lets
+class MeshCoder(ErasureCoder):
+    """ErasureCoder over the mesh-sharded encode: the seam that lets
     the disk-fed streaming pipeline (ec/stream.encode_volumes) batch host
     slabs straight onto a multi-chip mesh. Batches ride the 'data' axis,
     parity rows the 'shard' axis — the same layout dryrun_multichip
@@ -155,10 +151,11 @@ class MeshCoder:
     async_dispatch = True  # device arrays materialize on np.asarray
 
     def __init__(self, mesh: Mesh, d: int, p: int):
+        super().__init__(d, p)
         self.mesh = mesh
-        self.d = d
-        self.p = p
-        self.n = d + p
+        #: {device: bytes} of the last host batch put on the mesh — what
+        #: each chip holds of one input batch (ec.encode.finish carries it)
+        self.batch_bytes_by_device: "dict[str, int]" = {}
 
     def encode(self, data) -> jax.Array:
         b = data.shape[0]
@@ -181,20 +178,30 @@ class MeshCoder:
         over the interconnect inside the jit."""
         if isinstance(data, jax.Array):
             return data
-        return jax.device_put(
+        arr = jax.device_put(
             data, NamedSharding(self.mesh, P("data", None, None)))
+        self.batch_bytes_by_device = {
+            str(s.device): int(s.data.nbytes)
+            for s in arr.addressable_shards}
+        return arr
 
     def reconstruct(self, survivors, present, wanted):
-        """survivors [B, d, L] = shard rows sorted(present)[:d]."""
+        """survivors [B, d, L] (or one [d, L] stripe, as a degraded read
+        hands over) = shard rows sorted(present)[:d]."""
+        survivors = np.asarray(survivors)
+        squeeze = survivors.ndim == 2
+        if squeeze:
+            survivors = survivors[None]
         present = tuple(sorted(present))[:self.d]
         b, _, l = survivors.shape
-        n_shard = self.mesh.shape["shard"]
-        n_pad = _ceil_to(self.n, n_shard)
-        wiped = np.zeros((b, n_pad, l), dtype=np.uint8)
-        wiped[:, list(present), :] = np.asarray(survivors)
+        n_pad = _ceil_to(self.n, self.mesh.shape["shard"])
+        # the batch rides the 'data' axis: round it up with zero stripes
+        wiped = np.zeros((_ceil_to(b, self.mesh.shape["data"]), n_pad, l),
+                         dtype=np.uint8)
+        wiped[:b, list(present), :] = survivors
         rebuilt = rebuild_sharded(self.mesh, jnp.asarray(wiped), present,
-                                  self.d, self.p)
-        return rebuilt[:, list(wanted), :]
+                                  self.d, self.p)[:b, list(wanted), :]
+        return rebuilt[0] if squeeze else rebuilt
 
 
 def _all_device_mesh_coder(d: int, p: int) -> MeshCoder:
@@ -204,7 +211,5 @@ def _all_device_mesh_coder(d: int, p: int) -> MeshCoder:
     from .mesh import build_mesh
     return MeshCoder(build_mesh(), d, p)
 
-
-from ..ops.coder import register_coder  # noqa: E402 — avoid cycle at import
 
 register_coder("mesh", _all_device_mesh_coder)

@@ -34,8 +34,8 @@ log = logger("volume")
 
 def _observe_stages(kind: str, t_recv: float, t_parsed: float, t0: float,
                     t_admit, t_done, t_end: float) -> dict:
-    """Per-stage timing for the protocol-ceiling teardown (BENCH_r05:
-    93-139 us of protocol per hop): contiguous perf_counter segments
+    """Per-stage timing for the protocol-ceiling teardown (round 5, host
+    clock: 93-139 us of protocol per hop): contiguous perf_counter segments
     recv/parse (first wire byte -> request parsed), queue_wait (parsed
     -> handler entry: drain-queue + event-loop queueing, the split that
     de-confounds the old queueing-inflated recv_parse number),
@@ -91,11 +91,12 @@ def _ec_stage_fields(stats: dict) -> dict:
     /debug/events shows WHERE an encode spent its wall time without pulling
     the trace."""
     fields = {}
-    for key in ("fill_s", "dispatch_s", "coder_s", "drain_block_s",
-                "write_s", "write_block_s", "wall_s"):
+    for key in ("fill_s", "dispatch_s", "first_dispatch_s", "coder_s",
+                "drain_block_s", "write_s", "write_block_s", "wall_s"):
         if key in stats:
             fields[key] = round(stats[key], 3)
-    for key in ("write_overlap", "writers", "batches", "mode"):
+    for key in ("write_overlap", "writers", "batches", "mode",
+                "batch_bytes_by_device"):
         if key in stats:
             fields[key] = stats[key]
     return fields
@@ -2375,8 +2376,9 @@ class VolumeServer:
                    vpb.VolumeScrubResponse)
         def volume_scrub(req, context):
             """Stream live needles through the batched CRC kernel
-            (storage/scrub.py); device='auto' uses the accelerator when
-            jax initializes, else the host loop. One failing volume never
+            (storage/scrub.py); device='auto' follows the backend this
+            server's -coder resolved to (the JAX kernel on a device
+            coder, the host loop otherwise). One failing volume never
             loses the other volumes' results; a time budget + rotating
             cursor lets the admin cron cover large servers across sweeps."""
             from ..storage.scrub import scrub_volume

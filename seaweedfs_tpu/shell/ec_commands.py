@@ -165,9 +165,9 @@ def cmd_ec_encode(env: CommandEnv, args):
 
 def _encode_on_server(env: CommandEnv, srv: dict,
                       vols: "list[tuple[int, str]]", opt) -> int:
-    """Freeze + batch-generate + spread one server's volumes. A failure
-    rolls the un-encoded volumes back to writable and never aborts other
-    servers' batches (caller loops on)."""
+    """Freeze + batch-generate + spread one server's volumes. A failed
+    generate rolls the frozen volumes back to writable and raises: the
+    verb (and `shell -c`) must not report success over zero shards."""
     stub = _stub(env, srv)
     collection = vols[0][1]
     vids = [v for v, _ in vols]
@@ -179,7 +179,6 @@ def _encode_on_server(env: CommandEnv, srv: dict,
                   vpb.VolumeMarkReadonlyResponse)
         frozen.append(vid)
     done: list[int] = []
-    d = p = 0
     try:
         gen = stub.call("VolumeEcShardsGenerateBatch",
                         vpb.VolumeEcShardsGenerateBatchRequest(
@@ -190,19 +189,18 @@ def _encode_on_server(env: CommandEnv, srv: dict,
                         vpb.VolumeEcShardsGenerateBatchResponse,
                         timeout=3600 * len(vids))
         done = list(gen.encoded_volume_ids)
-        d, p = gen.data_shards, gen.parity_shards
-        if gen.codec:
-            env.println(f"    codec {gen.codec} RS({d},{p})")
-    except Exception as e:  # noqa: BLE001
-        env.println(f"    batch generate failed on {srv['id']}: {e}")
-    for vid in frozen:
-        if vid not in done:  # rollback: un-encoded volumes take writes again
-            try:
-                stub.call("VolumeMarkWritable",
-                          vpb.VolumeMarkWritableRequest(volume_id=vid),
-                          vpb.VolumeMarkWritableResponse)
-            except Exception:  # noqa: BLE001  # swtpu-lint: disable=silent-except (best-effort rollback of mark-readonly)
-                pass
+    finally:
+        for vid in frozen:
+            if vid not in done:  # rollback: un-encoded volumes take writes
+                try:
+                    stub.call("VolumeMarkWritable",
+                              vpb.VolumeMarkWritableRequest(volume_id=vid),
+                              vpb.VolumeMarkWritableResponse)
+                except Exception:  # noqa: BLE001  # swtpu-lint: disable=silent-except (best-effort rollback of mark-readonly)
+                    pass
+    d, p = gen.data_shards, gen.parity_shards
+    if gen.codec:
+        env.println(f"    codec {gen.codec} RS({d},{p})")
     coll_by_vid = dict(vols)
     for vid in done:
         _spread_and_clean(env, vid, coll_by_vid.get(vid, collection), srv, d, p)

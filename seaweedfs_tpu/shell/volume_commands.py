@@ -65,8 +65,9 @@ def cmd_volume_list(env: CommandEnv, args):
 def cmd_volume_scrub(env: CommandEnv, args):
     """BASELINE config 4 as an operational surface: every volume server
     streams its .dat needles through the batched CRC kernel
-    (storage/scrub.py; device when jax initializes, host loop otherwise)
-    and reports corrupt needles + needles/s. Exceeds the reference —
+    (storage/scrub.py; each server follows its own -coder: the JAX
+    kernel on a device coder, the host loop otherwise; -device on
+    demands a TPU) and reports corrupt needles + needles/s. Exceeds the reference —
     command_volume_fsck.go:81 walks needles but never hardware-verifies
     CRCs."""
     import argparse

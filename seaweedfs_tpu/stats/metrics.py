@@ -556,8 +556,8 @@ QOS_WAIT_SECONDS = _histogram(
 # of one perf_counter timeline (recv/parse -> auth/admit -> store ->
 # serialize/flush), so per-{type} stage sums account for ~100% of
 # SeaweedFS_volumeServer_request_seconds — the per-hop protocol
-# breakdown the ROADMAP's protocol-ceiling teardown needs (BENCH_r05:
-# 6.7 us store read under 93-139 us/hop). Microsecond-resolution
+# breakdown the ROADMAP's protocol-ceiling teardown needs (round 5,
+# host clock: 6.7 us store read under 93-139 us/hop). Microsecond-resolution
 # buckets; exemplar-linked to /debug/traces via the shared Histogram
 # plumbing. `stage` is a closed set the registry lint caps at the tier
 # ceiling.

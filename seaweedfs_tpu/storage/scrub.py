@@ -1,6 +1,6 @@
 """Operational CRC scrub: stream a volume's needles through the batched
-device CRC kernel (ops/crc32c.device_crc_states) — or the host loop when
-no accelerator is available — and report corrupt needles.
+device CRC kernel (ops/crc32c.device_crc_states) on a TPU, or the host
+CRC loop in a host-coder process, and report corrupt needles.
 
 BASELINE config 4 is "1B-needle scrub, device-batched"; round 4 proved
 the kernel rate in the bench only. This module is the *operations* wiring
@@ -10,15 +10,22 @@ admin cron all call scrub_volume(). Reference analogue:
 shell/command_volume_fsck.go:81 (volume.fsck walks needles; it never got
 hardware CRC — this exceeds it).
 
-Batching: needles are LEFT-zero-padded into [B, L] blocks (L = the
-batch's max data length rounded up to the 512-byte chunk); the raw
-device states are corrected for the zero prefix with
-crc32c.finalize(lengths) — the same math the bench kernel uses, applied
-to real variable-length volume records.
+Which path runs follows the process's device gate (ops/device.py):
+`device="auto"` uses the JAX backend only if this process already
+resolved one (a `-coder numpy|native` server never imports jax), `"on"`
+demands a TPU, and `mode` says "device" only for CRCs a TPU computed.
+
+Batching: needles are grouped by length bucket (the power of two at or
+above the data length, at least one 512-byte chunk) and LEFT-zero-padded
+into fixed [B, L] blocks with B * L = _DISPATCH_BYTES, so one dispatch is
+bounded by bytes whatever the size mix and a sweep compiles one program
+per bucket. The raw device states are corrected for the zero prefix with
+crc32c.finalize(lengths).
 """
 
 from __future__ import annotations
 
+import functools
 import struct
 import time
 from dataclasses import dataclass, field
@@ -26,6 +33,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..ops import crc32c as crcmod
+from ..ops import device as devgate
 from ..utils.log import logger
 from . import types as t
 from .needle import record_size_from_header
@@ -34,6 +42,9 @@ from .volume import Volume
 log = logger("scrub")
 
 _CHUNK = 512
+# padded bytes in one device dispatch; each length bucket L runs at the
+# fixed shape [_DISPATCH_BYTES // L, L] (one row for longer needles)
+_DISPATCH_BYTES = 8 << 20
 
 
 @dataclass
@@ -51,43 +62,29 @@ class ScrubResult:
         return self.scanned / self.elapsed_s if self.elapsed_s else 0.0
 
 
-class _DeviceCrc:
-    """Jitted batched CRC with shape bucketing (pow2 L buckets keep the
-    number of XLA compilations logarithmic in the size spread)."""
+@functools.lru_cache(maxsize=1)
+def _crc_jit():
+    """The jitted batched CRC; one compiled program per block shape."""
+    import jax
 
-    _instance: "_DeviceCrc | None" = None
-
-    def __init__(self):
-        import jax
-
-        self._jit = jax.jit(
-            lambda x: crcmod.device_crc_states(x, chunk=_CHUNK))
-        self._np = np
-
-    @classmethod
-    def get(cls) -> "_DeviceCrc | None":
-        if cls._instance is None:
-            try:
-                cls._instance = cls()
-            except Exception as e:  # noqa: BLE001 — no jax: cpu fallback
-                log.info("device CRC unavailable (%s); cpu scrub", e)
-                return None
-        return cls._instance
-
-    def crcs(self, blocks: np.ndarray, lengths: np.ndarray) -> np.ndarray:
-        raw = np.asarray(self._jit(blocks)).astype(np.uint32)
-        return crcmod.finalize(raw, lengths)
+    return jax.jit(lambda x: crcmod.device_crc_states(x, chunk=_CHUNK))
 
 
-def _pad_pow2(n: int) -> int:
+def _bucket(n: int) -> int:
     out = _CHUNK
     while out < n:
         out *= 2
     return out
 
 
-def _iter_batches(v: Volume, batch: int, res: ScrubResult):
-    """Yield (ids, datas, stored_crcs) batches of LIVE needles, walking
+def _block_shape(length: int) -> "tuple[int, int]":
+    """(B, L) of the fixed device block a needle of `length` rides in."""
+    pad_l = _bucket(length)
+    return max(1, _DISPATCH_BYTES // pad_l), pad_l
+
+
+def _iter_needles(v: Volume, res: ScrubResult):
+    """Yield (needle_id, data, stored_crc) for every LIVE needle, walking
     the .dat through volume.iter_records (the single source of truth for
     the on-disk record walk) on a private read-only handle — no lock
     contention with writers. Garbage records (overwritten/tombstoned,
@@ -100,9 +97,6 @@ def _iter_batches(v: Volume, batch: int, res: ScrubResult):
     with v._lock:
         v._dat.flush()  # the private read handle must see buffered appends
         end = v._append_offset
-    ids: list[int] = []
-    datas: list[bytes] = []
-    stored: list[int] = []
     last_end = SUPER_BLOCK_SIZE
     with open(v.dat_path, "rb") as f:
         for pos, nid, nsize in iter_records(f, SUPER_BLOCK_SIZE, end):
@@ -120,56 +114,76 @@ def _iter_batches(v: Volume, batch: int, res: ScrubResult):
                 res.corrupt.append(nid)
                 res.scanned += 1
                 continue
-            ids.append(nid)
-            datas.append(bytes(body[4:4 + dlen]))
-            stored.append(struct.unpack_from("<I", body, nsize)[0])
-            if len(ids) >= batch:
-                yield ids, datas, stored
-                ids, datas, stored = [], [], []
-    if ids:
-        yield ids, datas, stored
+            yield (nid, bytes(body[4:4 + dlen]),
+                   struct.unpack_from("<I", body, nsize)[0])
     if last_end < end:
         res.error = (f"record walk torn at offset {last_end}: "
                      f"{end - last_end} trailing bytes unscanned "
                      f"(header rot or torn write)")
 
 
-def scrub_volume(v: Volume, device: str = "auto",
-                 batch: int = 4096) -> ScrubResult:
+def _device_crcs(shape: "tuple[int, int]",
+                 datas: "list[bytes]") -> np.ndarray:
+    """CRCs of up to B needles through one fixed [B, L] dispatch."""
+    rows, pad_l = shape
+    blocks = np.zeros((rows, pad_l), dtype=np.uint8)
+    lengths = np.zeros(rows, dtype=np.int64)
+    for i, d in enumerate(datas):
+        lengths[i] = len(d)
+        if d:
+            blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
+    raw = np.asarray(_crc_jit()(blocks)).astype(np.uint32)
+    return crcmod.finalize(raw, lengths)[:len(datas)]
+
+
+def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
     """Verify every live needle's stored CRC against its data bytes.
 
-    device: 'auto' (device if jax initializes, else cpu), 'on', 'off'.
-    Tiered volumes (remote .dat) are skipped — a scrub must not pull the
-    whole volume back over the network; their integrity story is the
-    backend's checksums plus verify-before-delete at upload time.
+    device: 'auto' (this process's resolved backend: the JAX kernel if
+    the device gate brought one up, else the host loop), 'on' (a TPU or
+    an error), 'off' (host loop). Tiered volumes (remote .dat) are
+    skipped — a scrub must not pull the whole volume back over the
+    network; their integrity story is the backend's checksums plus
+    verify-before-delete at upload time.
     """
     res = ScrubResult(volume_id=v.id)
     if v.remote_spec is not None:
         res.mode = "skipped-tiered"
         return res
-    dev = _DeviceCrc.get() if device in ("auto", "on") else None
-    if device == "on" and dev is None:
-        raise RuntimeError("device CRC requested but jax is unavailable")
-    res.mode = "device" if dev is not None else "cpu"
+    backend = None if device == "off" else devgate.current()
+    if device == "on" and (backend is None or backend.platform != "tpu"):
+        raise devgate.DeviceError(
+            "volume.scrub -device on needs a TPU; this process runs on "
+            f"platform={backend.platform if backend else None!r}")
+    if backend is not None:
+        # "device" is a TPU's word; the same kernel on the CPU backend a
+        # process was told to use says so
+        res.mode = "device" if backend.platform == "tpu" \
+            else f"xla-{backend.platform}"
     t0 = time.monotonic()
-    for ids, datas, stored in _iter_batches(v, batch, res):
-        lengths = np.array([len(d) for d in datas], dtype=np.int64)
-        if dev is not None:
-            pad_l = _pad_pow2(int(lengths.max()) if len(datas) else _CHUNK)
-            blocks = np.zeros((len(datas), pad_l), dtype=np.uint8)
-            for i, d in enumerate(datas):
-                if d:
-                    blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
-            got = dev.crcs(blocks, lengths)
-        else:
-            got = np.array([crcmod.crc32c(d) for d in datas],
-                           dtype=np.uint32)
-        want = np.array(stored, dtype=np.uint32)
-        bad = np.nonzero(got != want)[0]
-        for i in bad:
-            res.corrupt.append(ids[int(i)])
-        res.scanned += len(ids)
-        res.bytes_checked += int(lengths.sum())
+    pending: "dict[tuple[int, int], tuple[list, list, list]]" = {}
+
+    def dispatch(shape, ids, datas, stored) -> None:
+        got = _device_crcs(shape, datas)
+        bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
+        res.corrupt.extend(ids[int(i)] for i in bad)
+
+    for nid, data, crc in _iter_needles(v, res):
+        res.scanned += 1
+        res.bytes_checked += len(data)
+        if backend is None:
+            if crcmod.crc32c(data) != crc:
+                res.corrupt.append(nid)
+            continue
+        shape = _block_shape(len(data))
+        ids, datas, stored = pending.setdefault(shape, ([], [], []))
+        ids.append(nid)
+        datas.append(data)
+        stored.append(crc)
+        if len(ids) == shape[0]:
+            dispatch(shape, *pending.pop(shape))
+    for shape, batch in pending.items():
+        dispatch(shape, *batch)
     res.elapsed_s = time.monotonic() - t0
     if res.corrupt:
         log.warning("scrub volume %d: %d/%d needles corrupt: %s",

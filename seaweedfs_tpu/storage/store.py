@@ -15,7 +15,8 @@ from ..ec import files as ec_files
 from ..ec.encoder import decode_volume, encode_volume, rebuild_shards
 from ..ec.locate import EcGeometry
 from ..ec.volume import EcVolume
-from ..ops.coder import ErasureCoder, get_coder
+from ..ops import device
+from ..ops.coder import ErasureCoder, codec_coder, get_coder
 from ..utils import failpoints, fsutil
 from ..utils.log import logger
 from . import types as t
@@ -36,7 +37,13 @@ class Store:
         self.public_url = public_url or f"{ip}:{port}"
         self.locations = locations
         self.ec_geometry = ec_geometry or EcGeometry()
-        self.coder_name = coder_name
+        # -coder resolves HERE, once (ops/device.py): `auto` becomes the
+        # device coder on a TPU and native/numpy without one; a device
+        # coder without a TPU raises instead of starting a server that
+        # would encode on the host and say nothing
+        self.backend = device.resolve_coder(coder_name)
+        log.info("coder %s -> %s %s", coder_name, self.backend,
+                 device.status())
         # erasure CODEC for new encodes ("rs" | "piggyback") — orthogonal
         # to coder_name, which picks the compute backend. Reads/rebuilds
         # always follow the codec sealed in each volume's .vif.
@@ -75,37 +82,17 @@ class Store:
                 for vid, ent in list(self._access.items())}
 
     # -- coder selection (the pluggable north-star seam) --------------------
-    def _backend_name(self) -> str:
-        name = self.coder_name
-        if name == "auto":
-            try:
-                import jax  # noqa: F401
-                name = "jax"
-            except Exception:  # noqa: BLE001
-                name = "numpy"
-        return name
-
     def coder(self, d: int | None = None, p: int | None = None,
               codec: str | None = None) -> ErasureCoder:
+        """The resolved backend's coder; layered codecs (piggyback, msr)
+        wrap it as their GF engine. A coder that fails to construct or
+        compute raises to the RPC — there is no host retry."""
         d = d or self.ec_geometry.d
         p = p or self.ec_geometry.p
         codec = codec or self.ec_codec
-        name = self._backend_name()
         if codec and codec != "rs":
-            # layered codecs (piggyback, msr, ...) resolve through the
-            # registry and wrap the compute backend as their GF engine.
-            # A failing BACKEND (bad -coder name, jax init) degrades to
-            # numpy like the plain-RS branch below; an unknown CODEC
-            # raises from the numpy retry too — never silently rs.
-            from ..ops.coder import codec_coder
-            try:
-                return codec_coder(codec, d, p, backend=name)
-            except Exception:  # noqa: BLE001  # swtpu-lint: disable=silent-except (numpy retry below re-raises unknown codecs)
-                return codec_coder(codec, d, p, backend="numpy")
-        try:
-            return get_coder(name, d, p)
-        except Exception:  # noqa: BLE001
-            return get_coder("numpy", d, p)
+            return codec_coder(codec, d, p, backend=self.backend)
+        return get_coder(self.backend, d, p)
 
     # -- volume lifecycle ---------------------------------------------------
     def find_volume(self, vid: int) -> Volume | None:
@@ -796,6 +783,8 @@ class Store:
             "volumes": sum(len(l.volumes) for l in self.locations),
             "ec_volumes": sum(len(l.ec_volumes) for l in self.locations),
             "locations": [l.directory for l in self.locations],
+            "coder": self.backend,
+            **device.status(),
         }
 
     def close(self) -> None:

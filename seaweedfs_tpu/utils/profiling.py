@@ -72,6 +72,9 @@ def cpu_profile(seconds: float = 5.0, top: int = 60,
 def start_jax_profiler(port: int = 9999) -> str:
     """Start (once) the jax.profiler gRPC server for device traces."""
     global _jax_server
+    from ..ops import device
+    if device.current() is None:  # a host-coder process stays off jax
+        return "no JAX backend in this process (-coder numpy|native)\n"
     with _lock:
         if _jax_server is not None:
             return f"jax profiler already running on :{_jax_server}\n"

@@ -31,20 +31,14 @@ def test_config_tier_chain(tmp_path, monkeypatch):
 
 
 def test_scaffold_templates_parse():
-    try:
-        import tomllib
-
-        def parse(body):
-            return tomllib.loads(body)
-    except ImportError:  # Python < 3.11: the config module's fallback
-        from seaweedfs_tpu.utils.config import _parse_toml_subset as parse
+    import tomllib
 
     from seaweedfs_tpu.utils.scaffold import TEMPLATES
 
     assert set(TEMPLATES) == {"security", "master", "filer", "replication",
                               "notification", "shell"}
     for name, body in TEMPLATES.items():
-        parse(body)  # every template must be valid TOML
+        tomllib.loads(body)  # every template must be valid TOML
 
 
 def test_scaffold_verb_writes_file(tmp_path):

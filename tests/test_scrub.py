@@ -1,9 +1,11 @@
 """Operational CRC scrub (storage/scrub.py, VolumeScrub RPC,
 volume.scrub shell command) — BASELINE config 4 wired into operations.
 
-The device path runs on the test env's CPU-jax (same kernel the real
-chip compiles); the cpu path is the host loop. Both must agree with the
-stored CRCs and both must catch injected bit rot.
+device="auto" follows the process's resolved backend (ops/device.py):
+here the test env's CPU-jax, the same kernel the chip compiles, reported
+as mode "xla-cpu" — "device" is a TPU's word. device="off" is the host
+loop. Both must agree with the stored CRCs and both must catch injected
+bit rot.
 """
 
 import os
@@ -36,6 +38,11 @@ def _fill(v: Volume, n: int = 50) -> dict[int, bytes]:
 
 
 class TestScrubVolume:
+    @pytest.fixture(autouse=True)
+    def _resolved_backend(self):
+        from seaweedfs_tpu.ops import device
+        device.info()  # a device-coder process: "auto" means the kernel
+
     @pytest.mark.parametrize("device", ["off", "auto"])
     def test_clean_volume_scans_all(self, tmp_path, device):
         v = Volume(str(tmp_path), "", 1)
@@ -44,7 +51,7 @@ class TestScrubVolume:
         assert res.scanned == 60
         assert res.corrupt == []
         assert res.bytes_checked > 0
-        assert res.mode == ("cpu" if device == "off" else res.mode)
+        assert res.mode == ("cpu" if device == "off" else "xla-cpu")
         v.close()
 
     @pytest.mark.parametrize("device", ["off", "auto"])
