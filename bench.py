@@ -376,36 +376,6 @@ def bench_e2e(out: dict, n_vols: int, mb: int, smoke: bool) -> None:
             log(f"e2e encode from disk ({name}, {n_vols}x{mb}MB): "
                 f"{out[key]} GB/s ({dt:.1f}s; write overlap "
                 f"{stats.get('write_overlap')})")
-            if name == "device" and stats.get("batches"):
-                # MEASURED busy fraction (VERDICT r4 ask 1): union of the
-                # per-batch dispatch->drain-return spans recorded by the
-                # pipeline itself, not an estimated per-batch time. The
-                # union is exact when the pipe is saturated; lazy drains
-                # can stretch spans, so it is an upper bound — the stall
-                # complement (1 - drain_block/wall) is the lower bound.
-                spans = sorted(zip(stats.get("dispatch_ts", []),
-                                   stats.get("done_ts", [])))
-                busy = 0.0
-                cur_s = cur_e = None
-                for s0, e0 in spans:
-                    if cur_e is None or s0 > cur_e:
-                        if cur_e is not None:
-                            busy += cur_e - cur_s
-                        cur_s, cur_e = s0, e0
-                    else:
-                        cur_e = max(cur_e, e0)
-                if cur_e is not None:
-                    busy += cur_e - cur_s
-                out["ec_encode_e2e_device_overlap"] = round(
-                    min(1.0, busy / stats["wall_s"]), 3)
-                out["ec_encode_e2e_device_overlap_lower"] = round(
-                    max(0.0, 1 - stats.get("drain_block_s", 0)
-                        / stats["wall_s"]), 3)
-                out["ec_encode_e2e_device_batches"] = stats["batches"]
-                log(f"device overlap: {out['ec_encode_e2e_device_overlap']}"
-                    f" measured (busy {busy:.1f}s / wall "
-                    f"{stats['wall_s']:.1f}s; lower bound "
-                    f"{out['ec_encode_e2e_device_overlap_lower']})")
         # raw disk write rate of the same directory, for context: the e2e
         # pipeline writes (d+p)/d output bytes per input byte, so when
         # e2e_host ~= disk_rate * d/(d+p+d) the pipeline is disk-bound

@@ -105,13 +105,14 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
                    "codec": coder.codec}) as sp:
         from . import repair
         counter = repair.RepairCounter(coder.codec)
+        acct = tracing.StageAccount("rebuild", _REBUILD_STAGES)
         readers, frag_readers, close = repair.make_readers(
             base, present_local, shard_reader, remote, counter,
             fragment_reader=fragment_reader)
         try:
             path = _dispatch_rebuild(base, geo, coder, tuple(sorted(present)),
                                      missing, readers, frag_readers,
-                                     shard_size, chunk, batch, counter,
+                                     shard_size, chunk, batch, counter, acct,
                                      fold_planner=fold_planner,
                                      local_sids=frozenset(present_local))
         finally:
@@ -119,12 +120,24 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
         sp.set_attr("bytes_read", counter.bytes_read)
         sp.set_attr("bytes_written", counter.bytes_written)
         sp.set_attr("path", path)
+        acct.publish(sp)
         if stats is not None:
             stats.update(bytes_read=counter.bytes_read,
                          bytes_written=counter.bytes_written,
                          codec=coder.codec, path=path,
-                         shard_size=shard_size)
+                         shard_size=shard_size,
+                         batches=acct.count("dispatch"), **acct.fields())
         return missing
+
+
+# a rebuild's stages (tracing.StageAccount, annotated `swtpu/rebuild.*`):
+# `read` loads survivors (local preads or remote ranged fetches) into the
+# batch, `dispatch` is coder.reconstruct (H2D + launch; a host coder
+# computes here), `drain` blocks on the result (device + D2H), `write`
+# stores into the rebuilt shard files and flushes them. The codecs' own
+# executors (ranged / general, ec/repair.py) are one coarser stage
+# `codec` (decode + pwrite) with their survivor reads taken out as `read`.
+_REBUILD_STAGES = ("read", "dispatch", "drain", "write")
 
 
 def _shard_size(base: str, geo: EcGeometry,
@@ -142,7 +155,7 @@ def _shard_size(base: str, geo: EcGeometry,
 def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
                       present: tuple, missing: list[int], readers: dict,
                       frag_readers: dict, shard_size: int, chunk: int,
-                      batch: int, counter, fold_planner=None,
+                      batch: int, counter, acct, fold_planner=None,
                       local_sids: frozenset = frozenset()) -> str:
     """Pick the cheapest reconstruction the codec supports — resolved
     through the repair.REBUILDERS registry, so a new codec plugs in its
@@ -151,6 +164,10 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
     from . import repair
     ranged, general = repair.REBUILDERS.get(coder.codec, (None, None))
     plan = coder.repair_plan(present, tuple(missing), shard_size)
+    if (plan is not None and ranged is not None) or general is not None:
+        readers = {s: acct.timed("read", r) for s, r in readers.items()}
+        frag_readers = {s: acct.timed("read", r)
+                        for s, r in frag_readers.items()}
     if plan is not None and ranged is not None:
         folds = ()
         if fold_planner is not None and coder.codec == "msr":
@@ -158,69 +175,77 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
             # relay hop, and a stale holder list must not reroute them
             folds = tuple(x for x in (fold_planner(coder, missing[0]) or ())
                           if not set(x[0]) & local_sids)
-        if folds:
+        with acct.stage("codec"):
+            if folds:
+                ranged(base, coder, missing[0], readers, frag_readers,
+                       shard_size, counter, folds=folds)
+                return "ranged-folded"
             ranged(base, coder, missing[0], readers, frag_readers,
-                   shard_size, counter, folds=folds)
-            return "ranged-folded"
-        ranged(base, coder, missing[0], readers, frag_readers,
-               shard_size, counter)
-        return "ranged"
+                   shard_size, counter)
+            return "ranged"
     if general is not None:
-        general(base, coder, present, missing, readers, frag_readers,
-                shard_size, counter)
+        with acct.stage("codec"):
+            general(base, coder, present, missing, readers, frag_readers,
+                    shard_size, counter)
         return "general"
     _rebuild_positional(base, geo, coder, present, missing, readers,
-                        shard_size, chunk, batch, counter)
+                        shard_size, chunk, batch, counter, acct)
     return "full"
 
 
 def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
                         present: tuple, missing: list[int], readers: dict,
                         shard_size: int, chunk: int, batch: int,
-                        counter) -> None:
+                        counter, acct) -> None:
     """Plain-RS path: positional reconstruct over [batch, d, chunk] slabs
     of the first d survivors (device-batched like encode)."""
     use = sorted(present)[:geo.d]
     outs = {}
-    for m in missing:
-        p = base + files.shard_ext(m)
-        with open(p, "wb") as f:
-            f.truncate(shard_size)
-        outs[m] = np.memmap(p, dtype=np.uint8, mode="r+", shape=(shard_size,))
+    with acct.stage("write"):
+        for m in missing:
+            p = base + files.shard_ext(m)
+            with open(p, "wb") as f:
+                f.truncate(shard_size)
+            outs[m] = np.memmap(p, dtype=np.uint8, mode="r+",
+                                shape=(shard_size,))
 
     present_t = tuple(use)
     wanted_t = tuple(missing)
     from ..stats import EC_REBUILD_BYTES
     from .stream import AsyncPipe
-    pipe = AsyncPipe((batch, geo.d, chunk))
+    pipe = AsyncPipe((batch, geo.d, chunk), acct)
 
     def drain(rebuilt: np.ndarray, ctx) -> None:
         off, span, nb = ctx
-        for k, m in enumerate(missing):
-            outs[m][off:off + span] = rebuilt[:nb, k].reshape(-1)[:span]
+        with acct.stage("write"):
+            for k, m in enumerate(missing):
+                outs[m][off:off + span] = rebuilt[:nb, k].reshape(-1)[:span]
         counter.wrote(span * len(missing))
 
-    for off in range(0, shard_size, chunk * batch):
+    for n, off in enumerate(range(0, shard_size, chunk * batch)):
         span = min(chunk * batch, shard_size - off)
         nb = (span + chunk - 1) // chunk
         arr = pipe.next_buffer()
         # vectorized survivor load: one strided copy per survivor shard
-        for r, sid in enumerate(use):
-            row = readers[sid](off, span)
-            if span < nb * chunk:
-                padded = np.zeros(nb * chunk, dtype=np.uint8)
-                padded[:span] = row
-                arr[:nb, r] = padded.reshape(nb, chunk)
-            else:
-                arr[:nb, r] = row.reshape(nb, chunk)
-        if nb < batch:
-            arr[nb:] = 0
+        with acct.stage("read", batch=n):
+            for r, sid in enumerate(use):
+                row = readers[sid](off, span)
+                if span < nb * chunk:
+                    padded = np.zeros(nb * chunk, dtype=np.uint8)
+                    padded[:span] = row
+                    arr[:nb, r] = padded.reshape(nb, chunk)
+                else:
+                    arr[:nb, r] = row.reshape(nb, chunk)
+            if nb < batch:
+                arr[nb:] = 0
         EC_REBUILD_BYTES.inc(type(coder).__name__, amount=arr.nbytes)
-        pipe.submit(coder.reconstruct(arr, present_t, wanted_t),
-                    (off, span, nb), drain)
+        with acct.stage("dispatch", batch=n):
+            fut = coder.reconstruct(arr, present_t, wanted_t)
+        pipe.submit(fut, (off, span, nb), drain)
     pipe.flush()
-    for o in outs.values():
-        o.flush()
+    with acct.stage("write"):
+        for o in outs.values():
+            o.flush()
 
 
 def decode_volume(base: str, dat_out: str, geo: EcGeometry,

@@ -172,8 +172,12 @@ class WriterPool:
     `submit()`/`drain()` on the submitting thread.
     """
 
-    def __init__(self, writers: "int | None" = None,
+    def __init__(self, acct, writers: "int | None" = None,
                  queue_depth: "int | None" = None):
+        # the pipeline's StageAccount: the submitting thread's seconds
+        # lost to backpressure are booked as stage `write_block` as they
+        # happen, out of whichever stage (fill, drain) it was in
+        self._acct = acct
         self.writers = max(1, int(writers if writers is not None
                                   else _default_writers()))
         depth = max(1, int(queue_depth if queue_depth is not None
@@ -181,7 +185,6 @@ class WriterPool:
         self._queues = [queue.Queue(maxsize=depth)
                         for _ in range(self.writers)]
         self._busy = [0.0] * self.writers
-        self.block_s = 0.0          # submitting-thread seconds lost to backpressure
         self._err: "BaseException | None" = None
         self._err_lock = threading.Lock()
         self._closed = False
@@ -247,14 +250,14 @@ class WriterPool:
                 if self._err is not None:
                     EC_WRITER_QUEUE_DEPTH.add(amount=-1)  # never enqueued
                     raise self._err from None
-        self.block_s += time.perf_counter() - t0
+        self._acct.add("write_block", time.perf_counter() - t0)
 
     def drain(self) -> None:
         """Barrier: wait for every queued run, then re-raise any failure."""
         t0 = time.perf_counter()
         for q in self._queues:
             q.join()
-        self.block_s += time.perf_counter() - t0
+        self._acct.add("write_block", time.perf_counter() - t0)
         if self._err is not None:
             raise self._err
 
@@ -303,11 +306,16 @@ class AsyncPipe:
     runs submitted to writers keep READING the fill buffer after its batch
     drained. Callers `retain(buf)` per outstanding run and the writer's
     completion callback `release(buf)`s it; `next_buffer` blocks until the
-    slot's hold count is zero. `recycle_wait_s` accumulates that blocking —
+    slot's hold count is zero. That blocking is booked as `write_block` —
     it shows up as writer backpressure in the pipeline stats.
     """
 
-    def __init__(self, shape: tuple, depth: int = DEFAULT_DEPTH):
+    def __init__(self, shape: tuple, acct, depth: int = DEFAULT_DEPTH):
+        # the operation's StageAccount: each blocking fetch is one stage
+        # `drain` (annotated with the batch number), a wait for writers
+        # still reading a buffer is booked as `write_block`
+        self._acct = acct
+        self._drained = 0
         self.depth = depth
         self.pool = [np.zeros(shape, dtype=np.uint8)
                      for _ in range(depth + 2)]
@@ -316,16 +324,17 @@ class AsyncPipe:
         self._holds = [0] * len(self.pool)
         self._ids = {id(b): i for i, b in enumerate(self.pool)}
         self._cv = threading.Condition()
-        self.recycle_wait_s = 0.0
 
     def next_buffer(self) -> np.ndarray:
         i = self._slot
         self._slot = (self._slot + 1) % len(self.pool)
         t0 = time.perf_counter()
         with self._cv:
+            held = bool(self._holds[i])
             while self._holds[i]:
                 self._cv.wait()
-        self.recycle_wait_s += time.perf_counter() - t0
+        if held:
+            self._acct.add("write_block", time.perf_counter() - t0)
         return self.pool[i]
 
     def retain(self, buf: np.ndarray) -> None:
@@ -345,7 +354,11 @@ class AsyncPipe:
 
     def drain_one(self) -> None:
         fut, ctx, drain_fn = self.pending.popleft()
-        drain_fn(np.asarray(fut), ctx)  # np.asarray blocks on the device
+        # FIFO: the n-th fetch is the n-th dispatch's result
+        with self._acct.stage("drain", batch=self._drained):
+            out = np.asarray(fut)  # np.asarray blocks on the device
+        self._drained += 1
+        drain_fn(out, ctx)
 
     def flush(self) -> None:
         while self.pending:
@@ -565,9 +578,10 @@ def _unlink_quiet(path: str) -> None:
                     exc_info=True)
 
 
-def _reap(finishing: deque, pool: "WriterPool | None" = None,
+def _reap(finishing: deque, acct, pool: "WriterPool | None" = None,
           force: bool = False) -> None:
-    """Finish (in submit order) every plan whose writeback has completed.
+    """Finish (in submit order) every plan whose writeback has completed,
+    each as one stage `finish` of the pipeline's account.
 
     A poisoned pool's writers SKIP queued runs but still fire their
     completion callbacks (so buffer gating can't hang), which makes
@@ -581,7 +595,8 @@ def _reap(finishing: deque, pool: "WriterPool | None" = None,
     while finishing and (force or finishing[0].writes_done()):
         if not force and pool is not None and pool.error is not None:
             return  # job is failing; _abort removes the partial outputs
-        finishing.popleft().finish()
+        with acct.stage("finish"):
+            finishing.popleft().finish()
 
 
 def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
@@ -597,7 +612,10 @@ def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
     Returns {dat_path: [shard paths]}. `chunk` is clamped to the largest
     value that divides both block sizes (fit_chunk). Pass a dict as `stats`
     to receive pipeline timings (wall_s, fill_s, write_s, write_block_s,
-    ...). `writers` sizes the writeback plane (default SWTPU_EC_WRITERS).
+    finish_s, bytes, ...): the stage sums of one tracing.StageAccount,
+    whose stages `swtpu/ec.fill|dispatch|drain|finish` also land in a
+    live JAX profiler trace. `writers` sizes the writeback plane (default
+    SWTPU_EC_WRITERS).
 
     Reference equivalent: the per-volume VolumeEcShardsGenerate RPC body
     (volume_grpc_erasure_coding.go:39 -> WriteEcFiles ec_encoder.go:57), but
@@ -634,35 +652,42 @@ def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
             attrs={"volumes": len(jobs), "bytes": total,
                    "coder": type(coder).__name__, "codec": coder.codec,
                    "geometry": f"{geo.d}+{geo.p}"}) as sp:
+        acct = tracing.StageAccount("ec", _STAGES)
+        t0 = time.perf_counter()
         if not slab_coder.async_dispatch:
             res = _encode_volumes_sync(jobs, geo, slab_coder, chunk, batch,
-                                       stats, null_sink=null_sink,
+                                       stats, acct, null_sink=null_sink,
                                        writers=writers, pb=pb)
         else:
             res = _encode_volumes_async(jobs, geo, slab_coder, chunk, batch,
-                                        depth, stats, writers=writers, pb=pb)
-        _publish_pipeline_stats(stats, sp)
+                                        depth, stats, acct, writers=writers,
+                                        pb=pb)
+        stats.update(wall_s=time.perf_counter() - t0, bytes=total,
+                     fill_s=acct.seconds("fill"),
+                     write_block_s=acct.seconds("write_block"),
+                     finish_s=acct.seconds("finish"))
+        _publish_pipeline_stats(stats, acct, sp)
         return res
 
 
-def _publish_pipeline_stats(stats: dict, span) -> None:
+# the pipeline's stages (exclusive: they partition wall_s). What is left
+# of the wall is opening the plans (truncate n shard files, MAP_POPULATE
+# the .dat), queueing runs to the writers and joining their threads.
+_STAGES = ("fill", "dispatch", "drain", "write_block", "finish")
+
+
+def _publish_pipeline_stats(stats: dict, acct, span) -> None:
     """Feed the per-call stage breakdown into the stage histogram (with the
     active trace exemplar-linked automatically) and onto the ec.encode span
     so /debug/traces shows where an encode spent its wall time."""
     from ..stats import EC_PIPELINE_SECONDS
     wall = stats.get("wall_s", 0.0)
-    stages = {
-        "fill": stats.get("fill_s", 0.0),
-        "dispatch": stats.get("dispatch_s", stats.get("coder_s", 0.0)),
-        "drain": stats.get("drain_block_s", 0.0),
-        "write": stats.get("write_s", 0.0),
-    }
-    for stage, secs in stages.items():
-        EC_PIPELINE_SECONDS.observe(stage, value=secs)
-    for key, val in stages.items():
-        span.set_attr(f"{key}_s", round(val, 4))
+    for stage in ("fill", "dispatch", "drain"):
+        EC_PIPELINE_SECONDS.observe(stage, value=acct.seconds(stage))
+    EC_PIPELINE_SECONDS.observe("write", value=stats.get("write_s", 0.0))
+    acct.publish(span)
+    span.set_attr("write_s", round(stats.get("write_s", 0.0), 4))
     span.set_attr("wall_s", round(wall, 4))
-    span.set_attr("write_block_s", round(stats.get("write_block_s", 0.0), 4))
     span.set_attr("writers", stats.get("writers", 0))
     if wall > 0:
         # fraction of writer busy time hidden behind fill/compute: 1 means
@@ -675,7 +700,7 @@ def _publish_pipeline_stats(stats: dict, span) -> None:
 
 
 def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
-                         chunk: int, batch: int, stats: "dict | None",
+                         chunk: int, batch: int, stats: dict, acct,
                          null_sink: bool = False,
                          writers: "int | None" = None,
                          pb=None,
@@ -696,9 +721,7 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
     d, p = geo.d, geo.p
     out: dict[str, list[str]] = {}
     scratch = None
-    t_wall0 = time.perf_counter()
-    coder_s = fill_s = 0.0
-    pool = None if null_sink else WriterPool(writers)
+    pool = None if null_sink else WriterPool(acct, writers)
     finishing: deque = deque()
     created: list[_VolumePlan] = []
     try:
@@ -710,7 +733,8 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
                              for i in range(geo.n)]
             plan.open(open_fds=not null_sink)
             if plan.dat_size == 0:
-                plan.finish()
+                with acct.stage("finish"):
+                    plan.finish()
                 continue
             for view, base, rows, nch in plan.regions:
                 contiguous = nch == 1 and view.base is not None
@@ -726,13 +750,12 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
                             scratch = np.zeros((batch, d, chunk),
                                                dtype=np.uint8)
                         k = min(batch, nch - ch)
-                        t0 = time.perf_counter()
-                        scratch[:k] = view[row, :, ch:ch + k].transpose(1, 0, 2)
-                        fill_s += time.perf_counter() - t0
+                        with acct.stage("fill"):
+                            scratch[:k] = view[row, :, ch:ch + k].transpose(
+                                1, 0, 2)
                         inp = scratch[:k]
-                    t0 = time.perf_counter()
-                    parity = np.asarray(coder.encode(inp))
-                    coder_s += time.perf_counter() - t0
+                    with acct.stage("dispatch"):  # a host coder computes here
+                        parity = np.asarray(coder.encode(inp))
                     if not null_sink:
                         shard_off = base + r0 * chunk
                         # data runs come straight off the source mapping
@@ -753,22 +776,20 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
             EC_ENCODE_BYTES.inc(type(coder).__name__, amount=plan.dat_size)
             if not plan.finished:
                 finishing.append(plan)
-            _reap(finishing, pool)  # seal volumes whose writeback drained
+            # seal volumes whose writeback drained
+            _reap(finishing, acct, pool)
         if pool is not None:
             pool.drain()
-        _reap(finishing, force=True)
+        _reap(finishing, acct, force=True)
     except BaseException:
         _abort(pool, created)
         raise
     finally:
         if pool is not None:
             pool.close()
-    if stats is not None:
-        stats.update(mode="sync", wall_s=time.perf_counter() - t_wall0,
-                     coder_s=coder_s, fill_s=fill_s,
-                     write_s=pool.busy_s if pool else 0.0,
-                     write_block_s=pool.block_s if pool else 0.0,
-                     writers=pool.writers if pool else 0)
+    stats.update(mode="sync", coder_s=acct.seconds("dispatch"),
+                 write_s=pool.busy_s if pool else 0.0,
+                 writers=pool.writers if pool else 0)
     return out
 
 
@@ -787,7 +808,7 @@ def _abort(pool: "WriterPool | None", created: "list[_VolumePlan]") -> None:
 
 def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                           chunk: int, batch: int, depth: int,
-                          stats: "dict | None",
+                          stats: dict, acct,
                           writers: "int | None" = None,
                           pb=None,
                           ) -> "dict[str, list[str]]":
@@ -801,8 +822,8 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
         out[dat_path] = [out_base + files.shard_ext(i) for i in range(geo.n)]
 
     d, p = geo.d, geo.p
-    pool = WriterPool(writers)
-    pipe = AsyncPipe((batch, d, chunk), depth)
+    pool = WriterPool(acct, writers)
+    pipe = AsyncPipe((batch, d, chunk), acct, depth)
     finishing: deque = deque()
     created: list[_VolumePlan] = []
 
@@ -839,7 +860,8 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
             created.append(plan)
             plan.open()
             if plan.dat_size == 0:
-                plan.finish()
+                with acct.stage("finish"):
+                    plan.finish()
                 continue
             active.append(plan)
         return True
@@ -850,83 +872,64 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
             plan.write_done()
         return done
 
-    t_wall0 = time.perf_counter()
-    fill_s = dispatch_s = first_dispatch_s = 0.0
+    first_dispatch_s = 0.0
     batches = 0
-    drain_block = [0.0]
-    dispatch_ts: list = []  # per-batch submit time (FIFO pipe)
-    done_ts: list = []      # per-batch drain-return time
-    orig_drain_one = pipe.drain_one
 
-    def timed_drain_one():
-        t0 = time.perf_counter()
-        orig_drain_one()
-        t1 = time.perf_counter()
-        drain_block[0] += t1 - t0
-        done_ts.append(t1)
-    pipe.drain_one = timed_drain_one
+    def fill(buf: np.ndarray) -> "tuple[int, list[_Run]]":
+        b0, runs = 0, []
+        while b0 < batch and pump():
+            plan = active[0]
+            k, shard_off = plan.fill(buf, b0)
+            if k:
+                run = _Run(plan, shard_off, b0, k)
+                plan.inflight_runs += 1
+                runs.append(run)
+                # data shards go to the writer pool straight out of the
+                # host batch (one disk read per input byte; reference
+                # re-reads per shard); each run holds the buffer until
+                # its writer flushes it
+                done = _data_done(plan, buf)
+                for i in range(d):
+                    pipe.retain(buf)
+                    plan.note_write()
+                    pool.submit(i, plan.fds[i], shard_off,  # swtpu-lint: disable=executor-no-context
+                                buf[b0:b0 + k, i], done)
+                b0 += k
+        return b0, runs
 
     try:
         while pump():
             buf = pipe.next_buffer()  # waits for writers still reading it
-            b0, runs = 0, []
-            t0 = time.perf_counter()
-            while b0 < batch and pump():
-                plan = active[0]
-                k, shard_off = plan.fill(buf, b0)
-                if k:
-                    run = _Run(plan, shard_off, b0, k)
-                    plan.inflight_runs += 1
-                    runs.append(run)
-                    # data shards go to the writer pool straight out of the
-                    # host batch (one disk read per input byte; reference
-                    # re-reads per shard); each run holds the buffer until
-                    # its writer flushes it
-                    done = _data_done(plan, buf)
-                    for i in range(d):
-                        pipe.retain(buf)
-                        plan.note_write()
-                        pool.submit(i, plan.fds[i], shard_off,  # swtpu-lint: disable=executor-no-context
-                                    buf[b0:b0 + k, i], done)
-                    b0 += k
-            fill_s += time.perf_counter() - t0
+            with acct.stage("fill", batch=batches):
+                b0, runs = fill(buf)
+                if b0 and b0 < batch:
+                    buf[b0:] = 0  # final partial batch: stable jit shape
             if b0 == 0:
                 break
-            if b0 < batch:
-                buf[b0:] = 0  # final partial batch: stable jit shape
             EC_ENCODE_BYTES.inc(type(coder).__name__, amount=buf.nbytes)
-            t0 = time.perf_counter()
-            fut = coder.encode(buf)
-            dispatch_s += time.perf_counter() - t0
+            # H2D + launch; the batch number pairs a trace's program run
+            # with its dispatch and (AsyncPipe.drain_one) its drain
+            with acct.stage("dispatch", batch=batches):
+                fut = coder.encode(buf)
             if not batches:  # the first dispatch traces and compiles
-                first_dispatch_s = dispatch_s
-            dispatch_ts.append(t0)
+                first_dispatch_s = acct.seconds("dispatch")
             pipe.submit(fut, runs, drain)
             batches += 1
-            _reap(finishing, pool)
+            _reap(finishing, acct, pool)
         pipe.flush()
         pool.drain()
-        _reap(finishing, force=True)
+        _reap(finishing, acct, force=True)
     except BaseException:
         _abort(pool, created)
         raise
     finally:
         pool.close()
-    if stats is not None:
-        stats.update(mode="async", batches=batches,
-                     batch_bytes=batch * geo.d * chunk,
-                     wall_s=time.perf_counter() - t_wall0,
-                     fill_s=fill_s, dispatch_s=dispatch_s,
-                     first_dispatch_s=first_dispatch_s,
-                     drain_block_s=drain_block[0],
-                     write_s=pool.busy_s,
-                     write_block_s=pool.block_s + pipe.recycle_wait_s,
-                     writers=pool.writers,
-                     # MEASURED per-batch spans (dispatch -> blocking
-                     # drain return, FIFO-paired): their interval union
-                     # is the device-occupancy window, replacing the old
-                     # estimated per-batch-time multiplication
-                     dispatch_ts=dispatch_ts, done_ts=done_ts)
-        if getattr(coder, "batch_bytes_by_device", None):
-            stats["batch_bytes_by_device"] = coder.batch_bytes_by_device
+    stats.update(mode="async", batches=batches,
+                 batch_bytes=batch * geo.d * chunk,
+                 dispatch_s=acct.seconds("dispatch"),
+                 first_dispatch_s=first_dispatch_s,
+                 drain_block_s=acct.seconds("drain"),
+                 write_s=pool.busy_s, writers=pool.writers)
+    if getattr(coder, "batch_bytes_by_device", None):
+        stats["batch_bytes_by_device"] = coder.batch_bytes_by_device
     return out

@@ -20,6 +20,7 @@ from ..ec.encoder import rebuild_shards
 from ..ec.locate import EcGeometry
 from ..pb import master_pb2 as mpb
 from ..pb import volume_server_pb2 as vpb
+from ..stats import VOLUME_STORE_READ_SECONDS
 from ..storage.needle import Needle
 from ..storage.store import Store
 from ..storage.types import TTL, parse_file_id
@@ -92,10 +93,11 @@ def _ec_stage_fields(stats: dict) -> dict:
     the trace."""
     fields = {}
     for key in ("fill_s", "dispatch_s", "first_dispatch_s", "coder_s",
-                "drain_block_s", "write_s", "write_block_s", "wall_s"):
+                "drain_block_s", "write_s", "write_block_s", "finish_s",
+                "wall_s"):
         if key in stats:
             fields[key] = round(stats[key], 3)
-    for key in ("write_overlap", "writers", "batches", "mode",
+    for key in ("write_overlap", "writers", "batches", "mode", "bytes",
                 "batch_bytes_by_device"):
         if key in stats:
             fields[key] = stats[key]
@@ -1554,7 +1556,7 @@ class VolumeServer:
                 # concurrent GETs proceed while a writer fsyncs
                 n = await loop.run_in_executor(
                     self._read_pool, ctx.run, self._store_read,
-                    vid, key, cookie)
+                    vid, key, cookie, request.method.lower())
                 if epoch is not None:
                     cache.put(vid, key, n, epoch=epoch)
         except KeyError:
@@ -1638,11 +1640,21 @@ class VolumeServer:
                         content_type=(n.mime.decode() if n.mime else
                                       "application/octet-stream"))
 
-    def _store_read(self, vid: int, key: int, cookie: "int | None"):
-        """Blocking storage read (runs on the read pool)."""
-        return self.store.read_needle(
-            vid, key, cookie=cookie,
-            shard_reader=self._make_shard_reader(vid))
+    def _store_read(self, vid: int, key: int, cookie: "int | None",
+                    kind: str = "get"):
+        """Blocking storage read (runs on the read pool), timed there:
+        with the pool's queue wait it splits the request's `store` stage
+        into queue, read and the way back onto the loop. Every
+        microsecond of Python here is one the event loop waits for the
+        GIL: no exemplar lookup, few buckets."""
+        t0 = time.perf_counter()
+        try:
+            return self.store.read_needle(
+                vid, key, cookie=cookie,
+                shard_reader=self._make_shard_reader(vid))
+        finally:
+            VOLUME_STORE_READ_SECONDS.observe(
+                kind, value=time.perf_counter() - t0, trace_id="")
 
     async def _read_remote(self, request, fid: str, vid: int):
         from ..utils.fastweb import Redirect, Response, json_response
@@ -2381,6 +2393,7 @@ class VolumeServer:
             coder, the host loop otherwise). One failing volume never
             loses the other volumes' results; a time budget + rotating
             cursor lets the admin cron cover large servers across sweeps."""
+            from ..ops import events
             from ..storage.scrub import scrub_volume
             if req.volume_id:
                 v = store.find_volume(req.volume_id)
@@ -2410,6 +2423,17 @@ class VolumeServer:
                                      bytes_checked=r.bytes_checked,
                                      elapsed_s=r.elapsed_s, mode=r.mode,
                                      error=r.error)
+                    events.emit(
+                        "volume.scrub.finish", vid=r.volume_id,
+                        node=vs.url, scanned=r.scanned,
+                        corrupt=len(r.corrupt),
+                        bytes_checked=r.bytes_checked,
+                        bytes_dispatched=r.bytes_dispatched,
+                        blocks=r.blocks, elapsed_s=round(r.elapsed_s, 4),
+                        mode=r.mode, walk_s=round(r.walk_s, 4),
+                        pack_s=round(r.pack_s, 4),
+                        device_s=round(r.device_s, 4),
+                        compare_s=round(r.compare_s, 4))
                 except Exception as e:  # noqa: BLE001 — isolate per volume
                     resp.results.add(volume_id=v.id, mode="error",
                                      error=str(e))
@@ -2802,7 +2826,11 @@ class VolumeServer:
                         repair_path=stats.get("path"),
                         bytes_read=stats.get("bytes_read", 0),
                         bytes_written=stats.get("bytes_written", 0),
-                        duration_ms=round((time.perf_counter() - t0) * 1e3, 1))
+                        duration_ms=round((time.perf_counter() - t0) * 1e3, 1),
+                        # the rebuild's stage sums (ec/encoder.py)
+                        batches=stats.get("batches", 0),
+                        **{k: round(v, 3) for k, v in stats.items()
+                           if k.endswith("_s")})
             vs.flush_heartbeat()
             return vpb.VolumeEcShardsRebuildResponse(
                 rebuilt_shard_ids=rebuilt,

@@ -9,6 +9,7 @@ shell/commands.go and exposed through the CLI REPL (weed shell).
 from __future__ import annotations
 
 import shlex
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Callable, TextIO
@@ -54,7 +55,6 @@ class CommandEnv:
         if self.mc is None:
             self.mc = MasterClient(self.master_address, client_type="shell")
         if self.out is None:
-            import sys
             self.out = sys.stdout
 
     def println(self, *args) -> None:
@@ -119,11 +119,36 @@ def run_command(env: CommandEnv, line: str) -> bool:
         return True
     if cmd.needs_lock:
         env.confirm_is_locked()
-    t0 = time.monotonic()
-    cmd.fn(env, args)
-    if env.option.get("timing"):
-        env.println(f"({time.monotonic() - t0:.2f}s)")
+    from .. import tracing
+    t0 = time.perf_counter()
+    with tracing.start_span(f"shell/{name}", component="shell") as sp:
+        rpcs = (tracing.StageAccount(f"shell/{name}")
+                if sp.context.sampled else None)
+        token = tracing.RPC_ACCOUNT.set(rpcs)
+        try:
+            cmd.fn(env, args)
+        finally:
+            tracing.RPC_ACCOUNT.reset(token)
+            if rpcs is not None:
+                print(timing_line(name, time.perf_counter() - t0, rpcs),
+                      file=sys.stderr, flush=True)
     return True
+
+
+def timing_line(verb: str, total_s: float, rpcs) -> str:
+    """`timing <verb> total=<s> rpc=<s> <Method>=<s>/<calls> ...`: the
+    command's wall, the seconds of it inside client RPCs (the shell is
+    sequential, so the methods partition `rpc`), and each method's
+    seconds/calls, most seconds first. `rpcs` is the StageAccount the
+    command ran under (utils/rpc.py books each call onto it);
+    the trace id of that span rides every call, so /debug/traces on the
+    servers shows the same verb from their side."""
+    # rounded first, so that the line's methods add up to its `rpc`
+    secs = {m: round(rpcs.seconds(m), 3) for m in rpcs.names()}
+    parts = [f"timing {verb}", f"total={total_s:.3f}",
+             f"rpc={sum(secs.values()):.3f}"]
+    parts += [f"{m}={s:.3f}/{rpcs.count(m)}" for m, s in secs.items()]
+    return " ".join(parts)
 
 
 def repl(env: CommandEnv) -> None:

@@ -8,6 +8,7 @@ runs the optional push-gateway loop).
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 import urllib.request
@@ -159,13 +160,14 @@ class Histogram(_Metric):
             except Exception:  # noqa: BLE001 — exemplars must never break IO
                 trace_id = ""
         lv = tuple(str(v) for v in label_values)
+        # the first bucket that holds the value (len = +Inf); every later
+        # one holds it too. An observation runs under the GIL on request
+        # paths: walk only those buckets
+        idx = bisect.bisect_left(self.buckets, value)
         with self._lock:
             counts = self._counts.setdefault(lv, [0] * len(self.buckets))
-            idx = len(self.buckets)  # +Inf unless a finite bucket matches
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    counts[i] += 1
-                    idx = min(idx, i)
+            for i in range(idx, len(counts)):
+                counts[i] += 1
             self._sums[lv] = self._sums.get(lv, 0.0) + value
             self._totals[lv] = self._totals.get(lv, 0) + 1
             if trace_id:
@@ -569,6 +571,19 @@ VOLUME_STAGE_SECONDS = _histogram(
     buckets=(0.000005, 0.00001, 0.000025, 0.00005, 0.0001, 0.00025,
              0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
              0.5, 1.0))
+# The storage read of a GET/HEAD itself, timed inside the read-pool
+# thread (volume_server._store_read): with
+# SeaweedFS_pool_queue_wait_seconds{pool="read"} it splits the `store`
+# stage above into pool queue, read, and the trip back onto the loop.
+# A family of its own, NOT a sixth `stage`: the stage sums partition the
+# request and this interval lies inside `store`.
+VOLUME_STORE_READ_SECONDS = _histogram(
+    "SeaweedFS_volumeServer_store_read_seconds",
+    "storage read seconds inside the read-pool thread, per request type",
+    ("type",),
+    # few buckets: an observation walks them all under the GIL, on the
+    # request path (PERF.md §6, PR 25)
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.01, 0.1))
 # Continuous profiling plane (profiling/): the always-on sampler's
 # thread-sample counts by thread class and run state — the cheap
 # "where do the threads sit" rollup (full folded stacks live at

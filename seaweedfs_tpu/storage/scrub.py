@@ -21,6 +21,15 @@ into fixed [B, L] blocks with B * L = _DISPATCH_BYTES, so one dispatch is
 bounded by bytes whatever the size mix and a sweep compiles one program
 per bucket. The raw device states are corrected for the zero prefix with
 crc32c.finalize(lengths).
+
+Where the time goes is kept per volume in a tracing.StageAccount:
+`walk` (the record walk and body reads between two dispatches), `pack`
+(zero-fill and copy into the [B, L] block), `device` (the jitted call
+through np.asarray: H2D, scan, D2H) and `compare` (finalize + the CRC
+compare). In a process with jax loaded each is a `swtpu/scrub.<stage>`
+annotation in a live profiler trace; `scrub.device` carries `needed`
+(needle bytes in the block), `dispatched` (B x L) and `L`. The host
+loop has the `walk` alone.
 """
 
 from __future__ import annotations
@@ -56,6 +65,14 @@ class ScrubResult:
     elapsed_s: float = 0.0
     mode: str = "cpu"
     error: str = ""  # volume-level trouble (torn walk, tiered skip, ...)
+    # exclusive stage seconds (they partition elapsed_s), device blocks
+    # dispatched and their padded bytes (>= bytes_checked)
+    walk_s: float = 0.0
+    pack_s: float = 0.0
+    device_s: float = 0.0
+    compare_s: float = 0.0
+    blocks: int = 0
+    bytes_dispatched: int = 0
 
     @property
     def needles_per_s(self) -> float:
@@ -122,18 +139,23 @@ def _iter_needles(v: Volume, res: ScrubResult):
                      f"(header rot or torn write)")
 
 
-def _device_crcs(shape: "tuple[int, int]",
-                 datas: "list[bytes]") -> np.ndarray:
-    """CRCs of up to B needles through one fixed [B, L] dispatch."""
+def _device_crcs(shape: "tuple[int, int]", datas: "list[bytes]",
+                 acct) -> np.ndarray:
+    """CRCs of up to B needles through one fixed [B, L] dispatch, as the
+    stages `pack`, `device` and `compare` of the scrub's account."""
     rows, pad_l = shape
-    blocks = np.zeros((rows, pad_l), dtype=np.uint8)
-    lengths = np.zeros(rows, dtype=np.int64)
-    for i, d in enumerate(datas):
-        lengths[i] = len(d)
-        if d:
-            blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
-    raw = np.asarray(_crc_jit()(blocks)).astype(np.uint32)
-    return crcmod.finalize(raw, lengths)[:len(datas)]
+    with acct.stage("pack"):
+        blocks = np.zeros((rows, pad_l), dtype=np.uint8)
+        lengths = np.zeros(rows, dtype=np.int64)
+        for i, d in enumerate(datas):
+            lengths[i] = len(d)
+            if d:
+                blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
+    with acct.stage("device", needed=int(lengths.sum()),
+                    dispatched=rows * pad_l, L=pad_l):
+        raw = np.asarray(_crc_jit()(blocks))
+    with acct.stage("compare"):
+        return crcmod.finalize(raw.astype(np.uint32), lengths)[:len(datas)]
 
 
 def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
@@ -160,31 +182,51 @@ def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
         # process was told to use says so
         res.mode = "device" if backend.platform == "tpu" \
             else f"xla-{backend.platform}"
+    from ..tracing import StageAccount
+    acct = StageAccount("scrub", ("walk", "pack", "device", "compare"))
     t0 = time.monotonic()
     pending: "dict[tuple[int, int], tuple[list, list, list]]" = {}
 
     def dispatch(shape, ids, datas, stored) -> None:
-        got = _device_crcs(shape, datas)
-        bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
-        res.corrupt.extend(ids[int(i)] for i in bad)
+        got = _device_crcs(shape, datas, acct)
+        with acct.stage("compare"):
+            bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
+            res.corrupt.extend(ids[int(i)] for i in bad)
+        res.blocks += 1
+        res.bytes_dispatched += shape[0] * shape[1]
 
-    for nid, data, crc in _iter_needles(v, res):
-        res.scanned += 1
-        res.bytes_checked += len(data)
-        if backend is None:
-            if crcmod.crc32c(data) != crc:
-                res.corrupt.append(nid)
-            continue
-        shape = _block_shape(len(data))
-        ids, datas, stored = pending.setdefault(shape, ([], [], []))
-        ids.append(nid)
-        datas.append(data)
-        stored.append(crc)
-        if len(ids) == shape[0]:
-            dispatch(shape, *pending.pop(shape))
+    needles = _iter_needles(v, res)
+
+    def next_block():
+        """Walk on to the next full [B, L] block (None: the walk is over),
+        checking on the host right here where no backend is up."""
+        for nid, data, crc in needles:
+            res.scanned += 1
+            res.bytes_checked += len(data)
+            if backend is None:
+                if crcmod.crc32c(data) != crc:
+                    res.corrupt.append(nid)
+                continue
+            shape = _block_shape(len(data))
+            ids, datas, stored = pending.setdefault(shape, ([], [], []))
+            ids.append(nid)
+            datas.append(data)
+            stored.append(crc)
+            if len(ids) == shape[0]:
+                return (shape, *pending.pop(shape))
+        return None
+
+    while True:
+        with acct.stage("walk"):  # from one dispatch to the next
+            block = next_block()
+        if block is None:
+            break
+        dispatch(*block)
     for shape, batch in pending.items():
         dispatch(shape, *batch)
     res.elapsed_s = time.monotonic() - t0
+    res.walk_s, res.pack_s, res.device_s, res.compare_s = (
+        acct.seconds(k) for k in ("walk", "pack", "device", "compare"))
     if res.corrupt:
         log.warning("scrub volume %d: %d/%d needles corrupt: %s",
                     v.id, len(res.corrupt), res.scanned,

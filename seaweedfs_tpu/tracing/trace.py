@@ -310,15 +310,16 @@ def add_event(name: str, **attrs) -> None:
         sp.add_event(name, **attrs)
 
 
-def injectable() -> str:
-    """traceparent value to put on the wire, or '' when nothing should
-    be added. Rate 0 (tracing disabled) injects NOTHING, leaving
+def injectable(span: "Span | None" = None) -> str:
+    """traceparent value to put on the wire for `span` (default: the
+    active one), or '' when nothing should be added. Rate 0 (tracing
+    disabled) injects NOTHING, leaving
     requests byte-identical to an untraced build. Under fractional
     sampling an unsampled trace still propagates its context with the
     00 flag — otherwise every downstream node would re-roll the dice
     and record fragmented mid-path root traces, blowing the effective
     rate past what was configured."""
-    sp = _current.get()
+    sp = span if span is not None else _current.get()
     if sp is None:
         return ""
     if sp.context.sampled:

@@ -11,6 +11,7 @@ cached channels.
 from __future__ import annotations
 
 import threading
+import time
 from concurrent import futures
 from typing import Callable
 
@@ -98,13 +99,14 @@ def set_cluster_key(key: str) -> None:
         _cluster_key = derive_cluster_key(key)
 
 
-def _outgoing_metadata() -> list[tuple[str, str]]:
+def _outgoing_metadata(span=None) -> list[tuple[str, str]]:
     md = []
-    # trace-context propagation: a sampled active span rides every gRPC
-    # hop as traceparent metadata (the HTTP plane uses the header form);
-    # unsampled/absent adds nothing to the wire
+    # trace-context propagation: a sampled active span (or `span`, a
+    # streaming call's client span, which is never the active one) rides
+    # every gRPC hop as traceparent metadata (the HTTP plane uses the
+    # header form); unsampled/absent adds nothing to the wire
     from .. import tracing
-    tp = tracing.injectable()
+    tp = tracing.injectable(span)
     if tp:
         md.append((tracing.TRACEPARENT_HEADER, tp))
     # QoS class tag: maintenance-tagged flows (repair executor, rebuild
@@ -364,8 +366,86 @@ def drop_channel(address: str) -> None:
         ch.close()
 
 
+def _client_span(method: str, address: str):
+    """The `rpc.client/<Method>` span of one outgoing call made inside a
+    trace, and the account its caller runs under (a shell verb's, else
+    None); (None, None) outside a trace: a call with no active span
+    (heartbeats, pollers) starts no trace of its own, and the
+    subscriptions the server side leaves unspanned are left so here."""
+    from .. import tracing
+    if method in _LONG_LIVED_STREAMS or tracing.current_span() is None:
+        return None, None
+    sp = tracing.start_span(f"rpc.client/{method}", component="rpc",
+                            attrs={"peer": address})
+    return sp, tracing.RPC_ACCOUNT.get()
+
+
+def _end_client_span(sp, acct, method: str, seconds: float) -> None:
+    """End a client span and book the call's seconds onto the verb's
+    account (summed as calls end, so a verb of thousands of RPCs is not
+    cut by the span ring)."""
+    sp.end()
+    if acct is not None:
+        acct.add(method, seconds)
+
+
+class _SpannedStream:
+    """A server stream called inside a trace: iterates and cancels like
+    the grpc call it wraps (every other attribute is the call's own), and
+    ends the call's client span when the stream is exhausted, fails, is
+    cancelled or is dropped. The seconds booked are those spent inside
+    `next()` — waiting for the peer — not the consumer's between them."""
+
+    def __init__(self, stream, sp, acct, method: str, seconds: float):
+        self._stream = stream
+        self._sp = sp
+        self._acct = acct
+        self._method = method
+        self._seconds = seconds
+        self._ended = False
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        t0 = time.perf_counter()
+        try:
+            item = next(self._stream)
+        except BaseException as e:
+            self._seconds += time.perf_counter() - t0
+            if not isinstance(e, StopIteration):
+                self._sp.set_error(e)
+            self._end()
+            raise
+        self._seconds += time.perf_counter() - t0
+        return item
+
+    def _end(self) -> None:
+        if not self._ended:
+            self._ended = True
+            _end_client_span(self._sp, self._acct, self._method,
+                             self._seconds)
+
+    def cancel(self):
+        if not self._ended:
+            self._sp.status = "cancelled"
+        self._end()
+        return self._stream.cancel()
+
+    def __getattr__(self, name: str):
+        if name.startswith("_"):  # never the wrapper's own, half-built
+            raise AttributeError(name)
+        return getattr(self._stream, name)
+
+    def __del__(self):
+        if not getattr(self, "_ended", True):  # dropped half-read or unread
+            self.cancel()
+
+
 class Stub:
-    """Thin client for one service on one address."""
+    """Thin client for one service on one address. A call made inside a
+    trace runs under a child span `rpc.client/<Method>` (peer as attr)
+    whose context is what the server's `rpc/<Method>` span parents on."""
 
     def __init__(self, address: str, service: str):
         self.address = address
@@ -377,14 +457,34 @@ class Stub:
             f"/{self.service}/{method}",
             request_serializer=type(request).SerializeToString,
             response_deserializer=resp_cls.FromString)
-        return fn(request, timeout=timeout, metadata=_outgoing_metadata())
+        sp, acct = _client_span(method, self.address)
+        if sp is None:
+            return fn(request, timeout=timeout,
+                      metadata=_outgoing_metadata())
+        t0 = time.perf_counter()
+        try:
+            return fn(request, timeout=timeout,
+                      metadata=_outgoing_metadata(sp))
+        except BaseException as e:
+            sp.set_error(e)
+            raise
+        finally:
+            _end_client_span(sp, acct, method, time.perf_counter() - t0)
 
     def call_stream(self, method: str, request, resp_cls, timeout: float = 300.0):
         fn = self._ch.unary_stream(
             f"/{self.service}/{method}",
             request_serializer=type(request).SerializeToString,
             response_deserializer=resp_cls.FromString)
-        return fn(request, timeout=timeout, metadata=_outgoing_metadata())
+        sp, acct = _client_span(method, self.address)
+        if sp is None:
+            return fn(request, timeout=timeout,
+                      metadata=_outgoing_metadata())
+        t0 = time.perf_counter()
+        stream = fn(request, timeout=timeout,
+                    metadata=_outgoing_metadata(sp))
+        return _SpannedStream(stream, sp, acct, method,
+                              time.perf_counter() - t0)
 
     def stream_stream(self, method: str, request_iter, req_cls, resp_cls):
         fn = self._ch.stream_stream(

@@ -141,6 +141,66 @@ print("ok")
     assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
 
 
+def test_host_coder_rebuild_never_imports_jax(tmp_path):
+    """A host-coder seal and rebuild run their stages (tracing/stages.py)
+    as plain sums: no profiler annotation, and jax is not loaded for
+    one."""
+    code = f"""
+import os, sys
+import numpy as np
+from seaweedfs_tpu.ec import encoder, files, stream
+from seaweedfs_tpu.ec.locate import EcGeometry
+from seaweedfs_tpu.ops.coder import get_coder
+from seaweedfs_tpu.tracing import stages
+geo = EcGeometry(d=4, p=2, large_block=1 << 16, small_block=1 << 12)
+base = os.path.join({str(tmp_path)!r}, "v")
+with open(base + ".dat", "wb") as f:
+    f.write(np.random.default_rng(1).integers(
+        0, 256, 300000, dtype=np.uint8).tobytes())
+coder = get_coder("numpy", 4, 2)
+sealed = {{}}
+stream.encode_volumes([(base + ".dat", base, None)], geo, coder,
+                      stats=sealed)
+assert sealed["mode"] == "sync" and sealed["finish_s"] > 0, sealed
+os.unlink(base + files.shard_ext(0))
+stats = {{}}
+assert encoder.rebuild_shards(base, geo, coder, stats=stats) == [0]
+assert stats["read_s"] > 0 and stats["batches"] == 1, stats
+assert stages._annotation("swtpu/rebuild.read", {{}}) is None
+assert "jax" not in sys.modules, "a host-coder rebuild imported jax"
+print("ok")
+"""
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, text=True,
+                       capture_output=True, timeout=120,
+                       env={**os.environ, "JAX_PLATFORMS": ""})
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
+
+
+def test_shell_verb_never_imports_jax():
+    """The shell's root span, client spans and `timing` line: a verb
+    (here against a master that is not there) ends without jax."""
+    code = """
+import contextlib, io, sys
+from seaweedfs_tpu.shell import ec_commands, volume_commands
+from seaweedfs_tpu.shell.commands import CommandEnv, run_command
+env = CommandEnv("127.0.0.1:1", out=io.StringIO())
+err = io.StringIO()
+with contextlib.redirect_stderr(err):
+    try:
+        run_command(env, "volume.list")
+    except Exception:
+        pass  # nobody answers; the verb still ends with its line
+assert err.getvalue().startswith("timing volume.list total="), err.getvalue()
+assert " VolumeList=" in err.getvalue(), err.getvalue()
+assert "jax" not in sys.modules, "a shell verb imported jax"
+print("ok")
+"""
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, text=True,
+                       capture_output=True, timeout=120,
+                       env={**os.environ, "JAX_PLATFORMS": ""})
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
+
+
 def test_scrub_dispatch_stays_under_byte_bound(tmp_path, monkeypatch):
     """One 4 MiB needle among thousands of 1 KiB ones used to pad the
     whole 4096-needle batch to 4 MiB rows (a 16 GiB allocation). Every
@@ -162,8 +222,8 @@ def test_scrub_dispatch_stays_under_byte_bound(tmp_path, monkeypatch):
         shapes.append(blocks.shape)
         return real_jit(blocks)
 
-    def spy_block(shape, datas):
-        out = real_block(shape, datas)
+    def spy_block(shape, datas, acct):
+        out = real_block(shape, datas, acct)
         assert [int(c) for c in out] == [crc32c.crc32c(d) for d in datas]
         return out
     monkeypatch.setattr(scrub, "_crc_jit", lambda: spy_jit)

@@ -22,6 +22,7 @@ from seaweedfs_tpu.ec.locate import EcGeometry
 from seaweedfs_tpu.ops import gf8
 from seaweedfs_tpu.ops.coder import NumpyCoder, get_coder
 from seaweedfs_tpu.stats import EC_PIPELINE_SECONDS, EC_WRITER_QUEUE_DEPTH
+from seaweedfs_tpu.tracing import StageAccount
 
 GEO = EcGeometry(d=4, p=2, large_block=4096, small_block=512)
 
@@ -134,7 +135,7 @@ def test_writer_pool_enospc_fails_cleanly(tmp_path, monkeypatch):
 def test_writer_pool_error_skips_queued_runs_and_callbacks_fire(tmp_path):
     """After poison, queued runs are skipped but completion callbacks still
     run — the invariant that keeps buffer gating from hanging."""
-    pool = stream.WriterPool(writers=1, queue_depth=4)
+    pool = stream.WriterPool(StageAccount("ec"), writers=1, queue_depth=4)
     path = tmp_path / "t.bin"
     fd = os.open(str(path), os.O_WRONLY | os.O_CREAT)
     fired = []
@@ -166,18 +167,19 @@ def test_reap_never_seals_behind_a_poisoned_pool(tmp_path):
     jobs, _ = _make_jobs(tmp_path, [3000], seed=11)
     plan = stream._VolumePlan(jobs[0][0], jobs[0][1], None, GEO, 512)
     plan.open()
-    pool = stream.WriterPool(writers=1, queue_depth=2)
+    acct = StageAccount("ec")
+    pool = stream.WriterPool(acct, writers=1, queue_depth=2)
     try:
         plan.note_write()
         pool.poison()
         plan.write_done()  # the skipped run's callback
         finishing = deque([plan])
-        stream._reap(finishing, pool)
+        stream._reap(finishing, acct, pool)
         assert not plan.finished  # left for _abort to clean up
         assert finishing  # still queued, not popped
         assert not os.path.exists(jobs[0][1] + ".vif")
         # a healthy pool (or the post-drain force path) still seals
-        stream._reap(finishing, pool, force=True)
+        stream._reap(finishing, acct, pool, force=True)
         assert plan.finished
     finally:
         pool.close()
@@ -186,7 +188,7 @@ def test_reap_never_seals_behind_a_poisoned_pool(tmp_path):
 def test_writer_pool_routes_and_writes_runs(tmp_path):
     """Strided [k, chunk] runs land at consecutive chunk offsets; 1-D runs
     are a single pwrite."""
-    pool = stream.WriterPool(writers=3, queue_depth=2)
+    pool = stream.WriterPool(StageAccount("ec"), writers=3, queue_depth=2)
     path = tmp_path / "shard.bin"
     fd = os.open(str(path), os.O_WRONLY | os.O_CREAT)
     try:
@@ -208,7 +210,8 @@ def test_writer_pool_routes_and_writes_runs(tmp_path):
 
 def test_async_pipe_recycling_gated_on_writers():
     """next_buffer must not hand out a buffer a writer still reads."""
-    pipe = stream.AsyncPipe((2, 2, 4), depth=0)  # pool of 2 buffers
+    acct = StageAccount("ec")
+    pipe = stream.AsyncPipe((2, 2, 4), acct, depth=0)  # pool of 2 buffers
     first = pipe.next_buffer()
     pipe.retain(first)
     got = []
@@ -224,7 +227,7 @@ def test_async_pipe_recycling_gated_on_writers():
     pipe.release(first)
     t.join(timeout=2)
     assert not t.is_alive() and got and got[0] is first
-    assert pipe.recycle_wait_s > 0.0
+    assert acct.seconds("write_block") > 0.0
 
 
 def test_volume_plan_closes_source_mmap(tmp_path):
