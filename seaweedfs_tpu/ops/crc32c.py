@@ -11,12 +11,18 @@ runs on TPU using the fact that CRC is GF(2)-affine in the message bits:
     state' = A @ state  ^  D @ byte_bits      (per byte, over GF(2))
 
 so K bytes fold into one [32, 32] state matrix S_K = A^K and one [32, 8K]
-injection matrix C_K, and a batch of B equal-length blocks is two int8
-matmuls. Variable needle lengths are handled by LEFT-padding with zeros:
-with a zero initial state, leading zero bytes leave the state unchanged, and
-the true init (0xFFFFFFFF) is restored afterwards with the length-dependent
-affine correction  crc_raw(m, I) = crc_raw(pad||m, 0) ^ A^len @ I,
-computed on host from precomputed A^(2^j) powers (a batched 32-bit matvec).
+injection matrix C_K. Nothing in that needs an order: with a zero initial
+state the raw state of a row of T chunks is  XOR_t S_K^(T-1-t) C_K bits(chunk_t),
+so the device program (device_crc_states) takes every chunk of every row
+as one batch row of a single int8 product against C_K (its rows regrouped
+by bit plane on the host, so the device unpacks no interleaved bits) and
+then folds the T states of a row in log2(T) halvings with S_K, S_2K, S_4K,
+... — no loop, no step that waits for another. Variable needle lengths are
+handled by LEFT-padding with zeros: with a zero initial state, leading zero
+bytes leave the state unchanged, and the true init (0xFFFFFFFF) is restored
+afterwards with the length-dependent affine correction
+crc_raw(m, I) = crc_raw(pad||m, 0) ^ A^len @ I, computed on host from
+precomputed A^(2^j) powers (a batched 32-bit matvec).
 """
 
 from __future__ import annotations
@@ -180,41 +186,61 @@ def finalize(raw_states: np.ndarray, lengths: np.ndarray) -> np.ndarray:
 # Device kernel: batched CRC over [B, L] blocks (L % K == 0), LEFT-padded.
 # ---------------------------------------------------------------------------
 
+def plane_matrices(k: int) -> np.ndarray:
+    """C_K's rows regrouped by bit plane: [8, K, 32] int8, so that the raw
+    state of a K-byte chunk x is  sum_j ((x >> j) & 1) @ out[j]  (mod 2) and
+    the device never interleaves bits along the lane axis."""
+    _, c = chunk_matrices(k)  # [32, 8K], column 8*i + j = byte i, bit j
+    return c.T.reshape(k, 8, 32).transpose(1, 0, 2).astype(np.int8)
+
+
+def fold_matrices(k: int, depth: int) -> list[np.ndarray]:
+    """[S_K^T, S_2K^T, ..., S_{K*2^(depth-1)}^T] as [32, 32] int8, by
+    repeated squaring: S_2n = S_n @ S_n."""
+    s, _ = chunk_matrices(k)
+    out = []
+    for _ in range(depth):
+        out.append(s.T.astype(np.int8))
+        s = _m2mul(s, s)
+    return out
+
+
 def device_crc_states(blocks, chunk: int = 512):
     """blocks [B, L] uint8 (L multiple of `chunk`) -> raw states [B] uint32.
 
-    Pure-JAX scan over L/chunk steps; each step is two bit-matmuls batched
-    over B. Intended to be wrapped in jit (and shard_mapped over a mesh for
-    the distributed scrub — see parallel/pipeline.py).
+    No step depends on another. Every `chunk`-byte piece of every row is a
+    batch row of ONE integer product, its eight bit planes against
+    plane_matrices(chunk), which gives the piece's own raw state; the
+    T = L/chunk states of a row then fold in log2(T) halvings,
+    s[t] <- S_{K*T/2} s[t] ^ s[t + T/2]  (T left-padded with zero states to
+    a power of two, harmless as left-padding bytes is). int8 in, int32
+    accumulate, `& 1`: exact. Intended to be wrapped in jit (and
+    shard_mapped over a mesh for the distributed scrub, parallel/pipeline.py).
     """
     import jax
     import jax.numpy as jnp
 
-    from .rs_jax import unpack_bits
-
     b, l = blocks.shape
     assert l % chunk == 0, (l, chunk)
-    s_k, c_k = chunk_matrices(chunk)
-    s_kt = jnp.asarray(s_k.T, dtype=jnp.int8)
-    c_kt = jnp.asarray(c_k.T, dtype=jnp.int8)
-
-    steps = blocks.reshape(b, l // chunk, chunk).transpose(1, 0, 2)  # [T,B,K]
-
-    def step(state, chunk_bytes):
-        bits = unpack_bits(chunk_bytes[..., None])[..., 0]  # [B, 8K] byte-major
-        nxt = (
-            jnp.einsum("bi,ij->bj", state, s_kt, preferred_element_type=jnp.int32)
-            + jnp.einsum("bk,kj->bj", bits, c_kt, preferred_element_type=jnp.int32)
-        ) & 1
-        return nxt.astype(jnp.int8), None
-
-    if steps.shape[0] == 0:
-        # no chunks: state stays zero (plain zeros are fine; scan never runs)
-        state = jnp.zeros((b, 32), dtype=jnp.int8)
-    else:
-        # derive the zero init from the input so it carries the same
-        # varying-axes marking under shard_map (scan needs matching carry types)
-        init = jnp.tile((steps[0, :, :1] & 0).astype(jnp.int8), (1, 32))
-        state, _ = jax.lax.scan(step, init, steps)
+    t = l // chunk
+    if t == 0:
+        return jnp.zeros((b,), dtype=jnp.uint32)
+    # [B, T, K] and not [B*T, K]: merging B into T is a second relayout of
+    # the block on a TPU (and ten times the compile). The barrier keeps the
+    # one relayout in front of the eight plane extractions; XLA otherwise
+    # extracts first and relays eight planes.
+    pieces = jax.lax.optimization_barrier(blocks.reshape(b, t, chunk))
+    acc = sum(
+        jnp.einsum("btk,kj->btj", ((pieces >> j) & 1).astype(jnp.int8),
+                   jnp.asarray(m), preferred_element_type=jnp.int32)
+        for j, m in enumerate(plane_matrices(chunk)))
+    s = (acc & 1).astype(jnp.int8)
+    depth = (t - 1).bit_length()
+    s = jnp.pad(s, ((0, 0), ((1 << depth) - t, 0), (0, 0)))
+    for s_t in reversed(fold_matrices(chunk, depth)):
+        half = s.shape[1] // 2
+        s = ((jnp.einsum("bti,ij->btj", s[:, :half], jnp.asarray(s_t),
+                         preferred_element_type=jnp.int32)
+              + s[:, half:]) & 1).astype(jnp.int8)
     weights = jnp.asarray([np.uint32(1 << i) for i in range(32)], dtype=jnp.uint32)
-    return jnp.sum(state.astype(jnp.uint32) * weights, axis=1)
+    return jnp.sum(s[:, 0].astype(jnp.uint32) * weights, axis=1)

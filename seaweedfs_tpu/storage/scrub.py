@@ -19,17 +19,19 @@ Batching: needles are grouped by length bucket (the power of two at or
 above the data length, at least one 512-byte chunk) and LEFT-zero-padded
 into fixed [B, L] blocks with B * L = _DISPATCH_BYTES, so one dispatch is
 bounded by bytes whatever the size mix and a sweep compiles one program
-per bucket. The raw device states are corrected for the zero prefix with
+per bucket. Every bucket's block is the same 16,384 chunks to the device
+program: one product over all of them, then a fold whose depth alone
+follows L. The raw device states are corrected for the zero prefix with
 crc32c.finalize(lengths).
 
 Where the time goes is kept per volume in a tracing.StageAccount:
 `walk` (the record walk and body reads between two dispatches), `pack`
 (zero-fill and copy into the [B, L] block), `device` (the jitted call
-through np.asarray: H2D, scan, D2H) and `compare` (finalize + the CRC
-compare). In a process with jax loaded each is a `swtpu/scrub.<stage>`
-annotation in a live profiler trace; `scrub.device` carries `needed`
-(needle bytes in the block), `dispatched` (B x L) and `L`. The host
-loop has the `walk` alone.
+through np.asarray: H2D, the loop-free CRC program, D2H) and `compare`
+(finalize + the CRC compare). In a process with jax loaded each is a
+`swtpu/scrub.<stage>` annotation in a live profiler trace; `scrub.device`
+carries `needed` (needle bytes in the block), `dispatched` (B x L) and
+`L`. The host loop has the `walk` alone.
 """
 
 from __future__ import annotations

@@ -121,6 +121,47 @@ def test_device_crc_batch():
         assert int(vals[i]) == crc32c.crc32c(m.tobytes()), f"len={lengths[i]}"
 
 
+@pytest.mark.parametrize("chunk", [64, 256, 512])
+@pytest.mark.parametrize("t", [0, 1, 2, 3, 5, 8, 13, 64])
+def test_device_crc_fold_matches_host(t, chunk):
+    """The loop-free program against the host CRC: T chunks a row, a power
+    of two and not, every length that sits on a chunk's or the row's edge,
+    in blocks of one row, two rows (the scrub's 8 MiB and 4 MiB buckets)
+    and all of them."""
+    import jax
+
+    l = t * chunk
+    lengths = sorted({n for n in (0, 1, chunk - 1, chunk, chunk + 1, l - 1, l)
+                      if 0 <= n <= l})
+    rng = np.random.default_rng(chunk + t)
+    fn = jax.jit(lambda b: crc32c.device_crc_states(b, chunk))
+    for rows in (1, 2, len(lengths)):
+        # the longest lengths last, so one and two rows hold L-1 and L
+        lens = (lengths * rows)[-rows:]
+        blocks = np.zeros((rows, l), dtype=np.uint8)
+        for i, n in enumerate(lens):
+            blocks[i, l - n:] = rng.integers(0, 256, n, dtype=np.uint8)
+        states = np.asarray(fn(blocks))
+        assert states.shape == (rows,) and states.dtype == np.uint32
+        vals = crc32c.finalize(states, np.array(lens))
+        for i, n in enumerate(lens):
+            assert int(vals[i]) == crc32c.crc32c(blocks[i, l - n:].tobytes()), \
+                f"rows={rows} len={n}"
+
+
+@pytest.mark.parametrize("k", [1, 64, 256, 512])
+def test_crc_fold_and_plane_matrices(k):
+    """The fold's matrices are S_K squared over and over, S_2n = S_n S_n,
+    and the plane matrices are C_K's columns regrouped by bit."""
+    s_k, c_k = crc32c.chunk_matrices(k)
+    for j, m in enumerate(crc32c.fold_matrices(k, 3)):  # S_K, S_2K, S_4K
+        np.testing.assert_array_equal(m.T, crc32c.chunk_matrices(k << j)[0])
+    planes = crc32c.plane_matrices(k)
+    assert planes.shape == (8, k, 32) and planes.dtype == np.int8
+    for j in range(8):
+        np.testing.assert_array_equal(planes[j], c_k[:, j::8].T)
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel (ops/rs_pallas.py) — interpreter mode on CPU, compiled on TPU
 # ---------------------------------------------------------------------------

@@ -53,11 +53,12 @@ def test_rebuild_sharded_all_patterns(mesh):
         np.testing.assert_array_equal(out[:, :n], shards[:, :n], err_msg=f"lost={lost}")
 
 
-def test_scrub_sharded_counts_corruption(mesh):
+@pytest.mark.parametrize("L", [256, 768])  # chunk 256: T = 1, T = 3
+def test_scrub_sharded_counts_corruption(mesh, L):
     from seaweedfs_tpu.ops import crc32c
     rng = np.random.default_rng(2)
-    nb, L = 16, 256
-    lengths = rng.integers(1, 200, nb)
+    nb = 16
+    lengths = rng.integers(1, L - 56, nb)
     blocks = np.zeros((nb, L), dtype=np.uint8)
     for i, ln in enumerate(lengths):
         blocks[i, L - ln:] = rng.integers(0, 256, ln, dtype=np.uint8)

@@ -110,6 +110,25 @@ class TestScrubVolume:
         v.close()
 
 
+@pytest.mark.parametrize("n", [1, 512, 513, 64 << 10, 1 << 20, 4 << 20])
+def test_crc_program_has_no_loop_and_keeps_its_name(n):
+    """Every block shape of the scrub lowers to a program with no `while`
+    (no step of the CRC depends on another), and the program is still the
+    unnamed lambda behind _crc_jit(): `jit__lambda` is the name the
+    benchmark's roofline readers find it by in a device trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from seaweedfs_tpu.storage import scrub
+
+    rows, pad_l = scrub._block_shape(n)
+    assert rows * pad_l == scrub._DISPATCH_BYTES and pad_l >= n
+    text = scrub._crc_jit().lower(
+        jax.ShapeDtypeStruct((rows, pad_l), jnp.uint8)).as_text()
+    assert text.startswith("module @jit__lambda"), text[:80]
+    assert "while" not in text
+
+
 def test_scrub_rpc_and_shell(tmp_path):
     """VolumeScrub RPC on a live server + the volume.scrub shell verb."""
     from conftest import wait_cluster_up
