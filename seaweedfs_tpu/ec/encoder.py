@@ -14,7 +14,10 @@ reference — decode reproduces the original file bit-for-bit.
 
 from __future__ import annotations
 
+import contextvars
 import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Sequence
 
 import numpy as np
@@ -138,6 +141,12 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
 # executors (ranged / general, ec/repair.py) are one coarser stage
 # `codec` (decode + pwrite) with their survivor reads taken out as `read`.
 _REBUILD_STAGES = ("read", "dispatch", "drain", "write")
+# the plain-RS path loads a batch's survivors side by side: `read` stays
+# the stage's wall on the rebuild's thread, and the loads' own seconds are
+# summed beside it, all of them and by kind of survivor (never stages:
+# they overlap, so they partition nothing). read_busy_s / read_s is the
+# overlap achieved.
+_READ_BUSY = ("read_busy", "read_local_busy", "read_remote_busy")
 
 
 def _shard_size(base: str, geo: EcGeometry,
@@ -189,17 +198,21 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
                     shard_size, counter)
         return "general"
     _rebuild_positional(base, geo, coder, present, missing, readers,
-                        shard_size, chunk, batch, counter, acct)
+                        shard_size, chunk, batch, counter, acct, local_sids)
     return "full"
 
 
 def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
                         present: tuple, missing: list[int], readers: dict,
                         shard_size: int, chunk: int, batch: int,
-                        counter, acct) -> None:
+                        counter, acct, local_sids: frozenset) -> None:
     """Plain-RS path: positional reconstruct over [batch, d, chunk] slabs
-    of the first d survivors (device-batched like encode)."""
+    of the first d survivors (device-batched like encode). A batch's
+    survivors load side by side, one task each: the batch waits for its
+    slowest survivor, not for their sum."""
     use = sorted(present)[:geo.d]
+    for name in _READ_BUSY:  # the fields exist though a kind has no load
+        acct.add(name, 0.0, n=0)
     outs = {}
     with acct.stage("write"):
         for m in missing:
@@ -222,26 +235,50 @@ def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
                 outs[m][off:off + span] = rebuilt[:nb, k].reshape(-1)[:span]
         counter.wrote(span * len(missing))
 
-    for n, off in enumerate(range(0, shard_size, chunk * batch)):
-        span = min(chunk * batch, shard_size - off)
-        nb = (span + chunk - 1) // chunk
-        arr = pipe.next_buffer()
-        # vectorized survivor load: one strided copy per survivor shard
-        with acct.stage("read", batch=n):
-            for r, sid in enumerate(use):
-                row = readers[sid](off, span)
-                if span < nb * chunk:
-                    padded = np.zeros(nb * chunk, dtype=np.uint8)
-                    padded[:span] = row
-                    arr[:nb, r] = padded.reshape(nb, chunk)
-                else:
-                    arr[:nb, r] = row.reshape(nb, chunk)
-            if nb < batch:
-                arr[nb:] = 0
-        EC_REBUILD_BYTES.inc(type(coder).__name__, amount=arr.nbytes)
-        with acct.stage("dispatch", batch=n):
-            fut = coder.reconstruct(arr, present_t, wanted_t)
-        pipe.submit(fut, (off, span, nb), drain)
+    def load(arr: np.ndarray, r: int, sid: int, off: int, span: int,
+             nb: int) -> None:
+        """One survivor's range into its rows of the batch (disjoint from
+        every other task's): one strided copy."""
+        t0 = time.perf_counter()
+        row = readers[sid](off, span)
+        if span < nb * chunk:
+            padded = np.zeros(nb * chunk, dtype=np.uint8)
+            padded[:span] = row
+            row = padded
+        arr[:nb, r] = row.reshape(nb, chunk)
+        # booked from the worker: no stage is open on this thread, so
+        # nothing is taken out of the caller's `read`
+        busy = time.perf_counter() - t0
+        acct.add("read_busy", busy)
+        acct.add("read_local_busy" if sid in local_sids
+                 else "read_remote_busy", busy)
+
+    loaders = ThreadPoolExecutor(max_workers=len(use),
+                                 thread_name_prefix="ec-rebuild-read")
+    try:
+        for n, off in enumerate(range(0, shard_size, chunk * batch)):
+            span = min(chunk * batch, shard_size - off)
+            nb = (span + chunk - 1) // chunk
+            arr = pipe.next_buffer()
+            with acct.stage("read", batch=n):
+                # each task under a copy of this thread's context: the
+                # QoS class rides a remote read to its holder, and the
+                # fetch spans keep `ec.rebuild` as their parent
+                loads = [loaders.submit(contextvars.copy_context().run, load,
+                                        arr, r, sid, off, span, nb)
+                         for r, sid in enumerate(use)]
+                if nb < batch:
+                    arr[nb:] = 0
+                for task in loads:
+                    task.result()
+            EC_REBUILD_BYTES.inc(type(coder).__name__, amount=arr.nbytes)
+            with acct.stage("dispatch", batch=n):
+                fut = coder.reconstruct(arr, present_t, wanted_t)
+            pipe.submit(fut, (off, span, nb), drain)
+    finally:
+        # waits: after an error too every load has ended before the
+        # caller closes the survivors' fds
+        loaders.shutdown()
     pipe.flush()
     with acct.stage("write"):
         for o in outs.values():

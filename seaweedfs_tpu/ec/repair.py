@@ -16,6 +16,7 @@ never needs d shards of RAM.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Callable
 
 import numpy as np
@@ -42,15 +43,18 @@ FragmentReader = Callable[[int, list], bytes]
 
 class RepairCounter:
     """bytes_read / bytes_written accounting for one repair, mirrored to
-    the codec-labelled repair counters as it accumulates."""
+    the codec-labelled repair counters as it accumulates. `read` is
+    called from every survivor's loader at once (ec/encoder.py)."""
 
     def __init__(self, codec: str):
         self.codec = codec or "rs"
         self.bytes_read = 0
         self.bytes_written = 0
+        self._lock = threading.Lock()
 
     def read(self, n: int) -> None:
-        self.bytes_read += n
+        with self._lock:
+            self.bytes_read += n
         try:
             from ..stats import REPAIR_BYTES_READ
             REPAIR_BYTES_READ.inc(self.codec, amount=n)

@@ -196,6 +196,47 @@ def test_rebuild_stages_are_returned(tmp_path):
     assert 0.5 * wall <= four <= wall
 
 
+def test_rebuild_books_its_loads_beside_the_read_stage(tmp_path):
+    """`read_s` is the stage's wall on the rebuild's thread; the loads'
+    own seconds are summed beside it, all and by kind of survivor, and
+    taken out of no stage."""
+    base = str(tmp_path / "v")
+    _dat(base + ".dat", 1 << 20, 6)
+    coder = NumpyCoder(GEO.d, GEO.p)
+    stream.encode_volumes([(base + ".dat", base, None)], GEO, coder)
+    held = {}
+    for sid in (0, 2, 3, 4):  # 0 is lost; three answer from elsewhere
+        with open(base + files.shard_ext(sid), "rb") as f:
+            held[sid] = f.read()
+        os.unlink(base + files.shard_ext(sid))
+
+    def holder(sid, off, ln):
+        time.sleep(0.02)
+        return held[sid][off:off + ln]
+
+    stats: dict = {}
+    t0 = time.perf_counter()
+    assert encoder.rebuild_shards(base, GEO, coder, wanted=[0],
+                                  chunk=1 << 12, batch=8, stats=stats,
+                                  shard_reader=holder,
+                                  remote_shards=[2, 3, 4]) == [0]
+    wall = time.perf_counter() - t0
+    batches = stats["batches"]
+    # three sleeping loads a batch overlap: their sum is over the wall
+    # of the stage they ran under, and the stage lost none of it
+    assert stats["read_remote_busy_s"] >= 3 * batches * 0.02
+    assert stats["read_s"] >= batches * 0.02
+    assert stats["read_busy_s"] >= stats["read_s"]
+    assert stats["read_local_busy_s"] > 0
+    assert stats["read_busy_s"] == pytest.approx(
+        stats["read_local_busy_s"] + stats["read_remote_busy_s"], abs=2e-4)
+    four = (stats["read_s"] + stats["dispatch_s"] + stats["drain_s"]
+            + stats["write_s"])
+    assert 0.5 * wall <= four <= wall
+    with open(base + files.shard_ext(0), "rb") as f:
+        assert f.read() == held[0]
+
+
 @pytest.mark.parametrize("mode", ["auto", "off"])
 def test_scrub_stages_partition_the_elapsed_time(tmp_path, mode):
     """The device path (the kernel on the CPU backend this process

@@ -187,8 +187,10 @@ def test_encode_finish_keeps_its_fields_and_gains_two(sealed):
     assert ev["wall_s"] * 1e3 <= ev["duration_ms"] + 1
 
 
-def test_rebuild_finish_keeps_its_fields_and_gains_the_stages(
-        cluster, sealed, capsys):
+@pytest.fixture(scope="module")
+def rebuilt(cluster, sealed):
+    """Two of one server's three shards lost and rebuilt by the verb;
+    what the rebuild left behind: its finish event, its timing lines."""
     master, servers, mc, env = cluster
     vid = sealed["vid"]
     wait_until(lambda: sorted(master.topo.lookup_ec(vid)) == list(range(6)),
@@ -204,23 +206,53 @@ def test_rebuild_finish_keeps_its_fields_and_gains_the_stages(
     victim.trigger_heartbeat()
     wait_until(lambda: sorted(master.topo.lookup_ec(vid)) == sorted(
         set(range(6)) - set(held)), msg="shards dropped from the registry")
+    import contextlib
+    err = io.StringIO()
     seq = last_seq()
-    run_command(env, "ec.rebuild")
+    with contextlib.redirect_stderr(err):
+        run_command(env, "ec.rebuild")
     assert f"rebuilt {len(held)} shards" in env.out.getvalue()
-    ev = finish("ec.rebuild.finish", seq)
+    return {"held": held, "err": err.getvalue(),
+            "event": finish("ec.rebuild.finish", seq)}
+
+
+def test_rebuild_finish_keeps_its_fields_and_gains_the_stages(
+        cluster, sealed, rebuilt):
+    master, servers, mc, env = cluster
+    ev = rebuilt["event"]
     for key in ("vid", "node", "ok", "rebuilt_shard_ids", "codec",
                 "repair_path", "bytes_read", "bytes_written", "duration_ms",
                 "read_s", "dispatch_s", "drain_s", "write_s", "batches"):
         assert key in ev, key
-    assert ev["ok"] and sorted(ev["rebuilt_shard_ids"]) == held
+    assert ev["ok"] and sorted(ev["rebuilt_shard_ids"]) == rebuilt["held"]
     assert ev["repair_path"] == "full" and ev["batches"] >= 1
     four = ev["read_s"] + ev["dispatch_s"] + ev["drain_s"] + ev["write_s"]
     assert 0.9 <= four / (ev["duration_ms"] / 1e3) <= 1.0, (four, ev)
-    (line,) = [ln for ln in parse(capsys.readouterr().err)
+    (line,) = [ln for ln in parse(rebuilt["err"])
                if ln["verb"] == "ec.rebuild"]
     assert "VolumeEcShardsRebuild" in line["methods"]
     for fid, data in list(sealed["payloads"].items())[:4]:
         assert operation.read(mc, fid) == data
+
+
+def test_rebuild_finish_books_the_loads_beside_the_read_stage(rebuilt):
+    """Four survivors loaded side by side, on this disk and from the
+    other server: `read_s` is still the stage's wall inside the RPC, the
+    loads' own seconds are summed beside it and split by kind."""
+    ev = rebuilt["event"]
+    for key in ("read_s", "read_busy_s", "read_local_busy_s",
+                "read_remote_busy_s"):
+        assert key in ev, key
+    assert ev["read_local_busy_s"] > 0 and ev["read_remote_busy_s"] > 0
+    # each of the four loads ran inside the stage: their sum is at most
+    # d walls (that it is over one wall is held in test_stage_account.py,
+    # with loads that sleep: here a busy host may start a loader late)
+    assert 0 < ev["read_busy_s"] <= 4 * ev["read_s"] + 0.002
+    # each of the three is rounded to a millisecond on its own
+    assert ev["read_busy_s"] == pytest.approx(
+        ev["read_local_busy_s"] + ev["read_remote_busy_s"], abs=0.0016)
+    assert ev["read_s"] <= ev["duration_ms"] / 1e3
+    assert ev["bytes_read"] == 4 * ev["bytes_written"] // 2
 
 
 def test_scrub_writes_one_event_a_volume_and_prints_as_before(
