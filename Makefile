@@ -1,13 +1,13 @@
 # seaweedfs_tpu delivery loop
 
-.PHONY: test stress chaos chaos-ha chaos-geo race bench bench-ec bench-ingest bench-repair bench-read bench-filer bench-qos bench-balance bench-tier bench-geo bench-ha bench-telemetry bench-profile smoke protos lint metrics-lint swtpu-lint crashsim
+.PHONY: test stress chaos chaos-ha chaos-geo race protos lint metrics-lint swtpu-lint crashsim
 
-# lint and the EC pipeline + bulk-ingest smokes run FIRST so a
-# concurrency-rule, exposition-grammar, encode-pipeline, or ingest-plane
-# regression fails the default path before the suite spends minutes; the
-# suite itself includes the cluster.check-against-mini-cluster smoke
-# (tests/test_health.py) so health regressions fail tier-1 too
-test: lint crashsim bench-ec bench-ingest bench-repair bench-read bench-filer bench-qos bench-balance bench-tier bench-geo bench-telemetry bench-profile
+# lint and the crash-state matrix run FIRST so a concurrency-rule,
+# exposition-grammar or durability regression fails the default path
+# before the suite spends minutes; the suite itself includes the
+# cluster.check-against-mini-cluster smoke (tests/test_health.py) so
+# health regressions fail tier-1 too
+test: lint crashsim
 	python -m pytest tests/ -q
 
 # static analysis gate: the repo-specific AST rules (blocking calls in
@@ -39,14 +39,14 @@ crashsim:
 # race/stress harness with artifact (tests/stress/run_stress.py);
 # bounded ~60s total at 6 s/scenario on an idle box
 stress:
-	python tests/stress/run_stress.py STRESS_r05.json 6
+	python tests/stress/run_stress.py $${TMPDIR:-/tmp}/STRESS.json 6
 
 # the stress suite under the runtime lock-order/race detector
 # (utils/locktrack.py): every threading.Lock/RLock/Condition is wrapped,
 # ABBA ordering cycles and >100ms holds are reported at process exit
 # and via /debug/locks on every daemon
 race:
-	SWTPU_LOCKCHECK=1 python tests/stress/run_stress.py STRESS_race.json 6
+	SWTPU_LOCKCHECK=1 python tests/stress/run_stress.py $${TMPDIR:-/tmp}/STRESS_race.json 6
 
 # randomized fault schedules against a live mini-cluster (opt-in gate
 # like stress); bounded time, failing runs print their seed — replay with
@@ -78,128 +78,6 @@ chaos-ha:
 # cycles. Part of `make chaos` (tests/chaos discovery).
 chaos-geo:
 	SWTPU_CHAOS=1 SWTPU_LOCKCHECK=1 python -m pytest tests/chaos/test_chaos_geo.py -q
-
-bench:
-	python bench.py
-
-# seconds-long fixed-size encode through the full writeback plane (CPU
-# coder, tiny volumes): asserts the fill/compute/write overlap accounting
-# is sane and the writer pool drains — the encode-pipeline smoke gate
-bench-ec:
-	JAX_PLATFORMS=cpu python bench.py --ec-only
-
-# seconds-long bulk-ingest smoke on a separate-process cluster: fid-range
-# leases + framed /bulk PUTs at small N, asserting zero errors, bulk
-# frames observed on the volume server, and the master's
-# SeaweedFS_fid_leases_active gauge draining back to 0
-bench-ingest:
-	JAX_PLATFORMS=cpu python bench.py --ingest-only
-
-# seconds-long repair-traffic CODEC MATRIX: rebuild a lost data AND a
-# lost parity shard under rs / piggyback / msr at RS(14,2) and RS(10,4),
-# recording per-codec repair_bytes_read_per_lost_byte (via
-# SeaweedFS_repair_bytes_read_total) with byte-identical results; gates
-# piggyback <= 0.7x rs at 10,4 and msr <= 8.0 / <= 4.0 shard-equivalents
-# (data AND parity; cut-set bounds 7.5 / 3.25), msr multi-loss reading
-# each survivor exactly once
-bench-repair:
-	JAX_PLATFORMS=cpu python bench.py --repair-only
-
-# seconds-long read-path smoke on a separate-process cluster: Zipfian
-# per-needle GETs vs framed /bulk-read on the same topology, asserting
-# bulk >= 3x per-needle needles/s, warm read-cache hit ratio >= 0.5,
-# and a non-negative cache bytes gauge; also records the per-stage GET
-# breakdown (resolve/lock/pread/serialize)
-bench-read:
-	JAX_PLATFORMS=cpu python bench.py --read-only
-
-# seconds-long large-object data plane smoke on separate-process filer
-# daemons: windowed chunk fan-out must beat the serial window >= 2x on a
-# multi-chunk PUT (byte/ETag-identical), and a 256 MB streamed PUT+GET
-# must grow the filer's peak RSS by less than half the object size;
-# records filer_put_MBps / s3_get_cold_MBps in the artifact
-bench-filer:
-	JAX_PLATFORMS=cpu python bench.py --filer-only
-
-# multi-tenant isolation gate on a separate-process cluster: an
-# antagonist tenant saturates bulk ingest + bulk GET while a
-# maintenance-class storm runs; the victim tenant's paced read p99 must
-# stay <= 3x its solo p99 and its goodput >= 50% of solo with QoS on,
-# the SAME schedule must violate that bound with the policy
-# hot-disabled, and shed requests answer 503 + Retry-After counted in
-# SeaweedFS_qos_requests_total{tenant,outcome="shed"}
-bench-qos:
-	JAX_PLATFORMS=cpu python bench.py --qos-only
-
-# scale-out placement & rebalance gate: a 4-server/2-rack topology must
-# push >= 2.5x one server's aggregate bulk PUT/GET needles/s under an
-# identical deterministic per-frame delay (per-node bottleneck modeled,
-# host CPU factored out), then a rack-skewed fleet must converge to
-# per-server byte skew <= 1.3 via volume.balance/ec.balance with EC
-# stripes rack-safe (<= p shards per rack), -dryRun mutation-free, and
-# rebalance traffic visible as maintenance-class in qos metrics
-bench-balance:
-	JAX_PLATFORMS=cpu python bench.py --balance-only
-
-# tiered-storage lifecycle gate: a cooling collection must auto-
-# transition hot -> EC -> remote under the master cron's
-# -lifecyclePolicy with zero operator commands, cold GETs must read
-# through the remote backend byte-identical and promote the volume
-# back on heat, `lifecycle.apply -dryRun` must issue zero mutating
-# RPCs, and a migration storm must run maintenance-class: the victim
-# tenant's paced read p99 stays <= 3x its solo p99 while
-# SeaweedFS_lifecycle_bytes_moved_total{from,to} books the move
-bench-tier:
-	JAX_PLATFORMS=cpu python bench.py --tier-only
-
-# geo plane gate: a separate-process 2-DC cluster (dc1: 2 servers, dc2:
-# 4) with `-linkCosts` on the master and deterministic per-link delay
-# failpoints on remote shard reads. MSR repair of a shard whose
-# survivors span DCs must ship <= 0.5x the cross-DC bytes of the
-# locality-blind path (the dc2 relay folds 4 helpers' beta-row
-# fragments into one alpha-row partial; SWTPU_GEO_FOLD=0 is the blind
-# baseline; both rebuilds byte-identical), and the cost-aware balance
-# plan must converge an intra-DC-fixable skew with ZERO cross-DC moves
-bench-geo:
-	JAX_PLATFORMS=cpu python bench.py --geo-only
-
-# HA control-plane gate: closed-loop assign (gRPC, redirect-following)
-# and lookup (HTTP, round-robin across ALL masters) workers drive an
-# in-process 3-master quorum through a 2-cycle leader kill/restart
-# election storm. Storm p99 must stay <= 5x the steady-state p99 for
-# both classes, follower-served lookups must be observed
-# (SeaweedFS_master_lookup_requests{source="follower"} > 0), and the
-# raft metrics must book >= 2 leader changes.
-bench-ha:
-	JAX_PLATFORMS=cpu python bench.py --ha-only
-
-# fleet telemetry & SLO plane gate: on a separate-process master + two
-# volume servers, the leader-resident collector must cost <= 3% RPS on
-# a delay-dominated read workload (one scrape/evaluate cycle every
-# 0.5s), its merged cluster p99 must land within 10% of a direct merge
-# of both nodes' raw scrapes, the per-stage hot-path histograms
-# (recv_parse/auth_admit/store/serialize_flush) must account for
-# >= 90% of end-to-end GET time, and live scrapes must pass the
-# exposition lint; records the no-failpoint per-stage means for the
-# protocol-ceiling teardown
-bench-telemetry:
-	JAX_PLATFORMS=cpu python bench.py --telemetry-only
-
-# continuous-profiling plane gate: on a separate-process master +
-# volume server with a deterministic 10 ms store.read delay, the
-# always-on sampler must cost <= 2% read RPS (hz=0/19/0 A/B/A via the
-# /debug/profile?hz= runtime retune), the new queue_wait stage plus
-# recv_parse must re-add to the pre-split recv_parse proxy within 10%
-# (stage-sum minus e2e-sum — no time lost or double-counted by the
-# split), live ?mode=continuous output must parse as collapsed
-# `stack count` lines with event_loop attribution, and /debug/flight
-# must hold slowest-request entries whose trace ids resolve in
-# /debug/traces
-bench-profile:
-	JAX_PLATFORMS=cpu python bench.py --profile-only
-
-smoke:
-	python bench.py --smoke
 
 protos:
 	python -m seaweedfs_tpu.pb.build

@@ -6,7 +6,7 @@ made the reservation (sequencer.next_id(count) is the allocation — keys
 are never handed out twice whether or not the lease is used); this
 registry only tracks how many grants are still live so operators can see
 outstanding ingest leases (`SeaweedFS_fid_leases_active`) and the
-bench/chaos harnesses can assert leases drain to zero after a run.
+tests and chaos lanes can assert leases drain to zero after a run.
 
 TTL is advisory on the key range itself (expired keys simply go unused —
 the sequencer never reissues them) but REAL for the range-scoped write

@@ -2,8 +2,8 @@
 
 Every device user asks here — rs_pallas.available(), JaxCoder /
 PallasCoder / MeshCoder, Store's coder resolution, the scrub CRC kernel,
-parallel.mesh.build_mesh and bench.py — so "is there a TPU" has one
-answer per process, resolved once, and a missing chip is an error at
+and parallel.mesh.build_mesh — so "is there a TPU" has one answer per
+process, resolved once, and a missing chip is an error at
 the caller instead of a quiet host fallback.
 
 Rules:

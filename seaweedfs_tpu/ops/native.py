@@ -2,8 +2,9 @@
 
 Builds the library on first use (g++ via the Makefile) and degrades
 gracefully to None when no toolchain is available — callers fall back to the
-numpy/JAX paths. The NativeCoder here is the CPU baseline for bench.py:
-the same AVX2 split-table algorithm klauspost/reedsolomon uses.
+numpy/JAX paths. The NativeCoder here is the host coder (volume server B
+in every cell of benchmark/run.py): the same AVX2 split-table algorithm
+klauspost/reedsolomon uses.
 """
 
 from __future__ import annotations

@@ -168,15 +168,6 @@ def encode_jit(data: jax.Array, d: int, p: int, tile: int = DEFAULT_TILE,
                   tile, interpret)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def encode_seeded_jit(data: jax.Array, seed: jax.Array, d: int, p: int,
-                      tile: int = DEFAULT_TILE,
-                      interpret: bool = False) -> jax.Array:
-    """Benchmark entry: xors `seed` into the data INSIDE the kernel so
-    repeated timing loops can defeat CSE without an extra HBM pass."""
-    return _apply(("enc", d, p, (), ()), data, seed, tile, interpret)
-
-
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
 def reconstruct_jit(survivors: jax.Array, present: tuple, wanted: tuple,
                     d: int, p: int, tile: int = DEFAULT_TILE,
@@ -184,14 +175,3 @@ def reconstruct_jit(survivors: jax.Array, present: tuple, wanted: tuple,
     """survivors [B, d, C] (rows = sorted(present)[:d]) -> [B, |wanted|, C]."""
     key = ("rec", d, p, tuple(sorted(present)[:d]), tuple(wanted))
     return _apply(key, survivors, jnp.zeros(1, jnp.int32), tile, interpret)
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
-def reconstruct_seeded_jit(survivors: jax.Array, seed: jax.Array,
-                           present: tuple, wanted: tuple, d: int, p: int,
-                           tile: int = DEFAULT_TILE,
-                           interpret: bool = False) -> jax.Array:
-    """Benchmark entry: like encode_seeded_jit, xors `seed` in-kernel so a
-    timing fori_loop cannot hoist the reconstruction as loop-invariant."""
-    key = ("rec", d, p, tuple(sorted(present)[:d]), tuple(wanted))
-    return _apply(key, survivors, seed, tile, interpret)

@@ -41,8 +41,8 @@ log = logger("placement.plan")
 MOVE_VOLUME = "volume"
 MOVE_EC = "ec"
 
-# stop when max/min per-server byte load is at or under this (the bench
-# gate asserts 1.3; planning a little tighter leaves convergence slack
+# stop when max/min per-server byte load is at or under this (operators
+# accept 1.3; planning a little tighter leaves convergence slack
 # for in-flight writes between plan and execution)
 DEFAULT_TARGET_SKEW = 1.15
 DEFAULT_MAX_MOVES = 64

@@ -126,8 +126,8 @@ class ContinuousSampler:
         return self._hz
 
     def set_hz(self, hz: float) -> None:
-        """Runtime rate control: 0 pauses sampling (the bench's A/B
-        overhead phases toggle this on a live cluster), capped well
+        """Runtime rate control: 0 pauses sampling (`/debug/profile?hz=`
+        toggles this on a live cluster), capped well
         below anything that could matter for overhead."""
         self._hz = min(max(0.0, float(hz)), 250.0)
 

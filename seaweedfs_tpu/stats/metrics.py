@@ -427,8 +427,8 @@ REPAIRS_TOTAL = _counter(
 # Repair traffic in BYTES, per codec — the warehouse-cluster metric the
 # piggybacked code exists to move: a single-data-shard rebuild under
 # codec "piggyback" reads ~(d+|group|)/2 half-shards where plain "rs"
-# reads d full shards. bench-repair asserts the ratio; operators graph
-# read-bytes-per-written-byte to see the codec win in production.
+# reads d full shards. tests/test_piggyback.py holds the ratio as a count;
+# operators graph read-bytes-per-written-byte to see the codec win in production.
 REPAIR_BYTES_READ = _counter(
     "SeaweedFS_repair_bytes_read_total",
     "survivor bytes read (local + ranged remote) to execute repairs",
@@ -493,7 +493,7 @@ LIFECYCLE_BYTES_MOVED = _counter(
     "bytes moved by lifecycle tier transitions, by from/to tier",
     ("from", "to"))
 # Batched ingest plane (fid-range leases + bulk PUT): outstanding leases
-# on the master (a drained system reads 0 — the bench-ingest smoke
+# on the master (a drained system reads 0 — tests/test_lease_failover.py
 # asserts it), the per-frame batching the /bulk handler actually sees
 # (low percentiles = clients not amortizing), and client keep-alive
 # pool reuse (a bulk workload should reuse ~every request).

@@ -200,8 +200,8 @@ class Store:
     # -- data path ----------------------------------------------------------
     def write_needle(self, vid: int, n: Needle, sync: bool = False) -> int:
         # slow/failing disk on the single-needle write path (the chaos
-        # read-storm's store.read twin; bench-filer arms delay here to
-        # model a slow disk deterministically)
+        # read-storm's store.read twin; a delay armed here models a slow
+        # disk deterministically)
         failpoints.check("store.write")
         v = self.find_volume(vid)
         if v is None:

@@ -192,16 +192,3 @@ def test_pallas_reconstruct(d, p, lost):
     got = np.asarray(rs_pallas.reconstruct_jit(
         survivors, present, lost, d, p, interpret=interp))
     np.testing.assert_array_equal(got, shards[:, list(lost), :])
-
-
-def test_pallas_seeded_entry_matches_xor():
-    from seaweedfs_tpu.ops import rs_pallas
-    import jax.numpy as jnp
-    rng = np.random.default_rng(8)
-    interp = not rs_pallas.available()
-    data = rng.integers(0, 256, size=(1, 4, 256), dtype=np.uint8)
-    seeded = np.asarray(rs_pallas.encode_seeded_jit(
-        data, jnp.full((1,), 5, jnp.int32), 4, 2, interpret=interp))
-    plain = np.asarray(rs_pallas.encode_jit(data ^ np.uint8(5), 4, 2,
-                                            interpret=interp))
-    np.testing.assert_array_equal(seeded, plain)

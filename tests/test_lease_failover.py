@@ -172,9 +172,12 @@ def test_follower_serves_lookup_for_leased_volume(ha_cluster):
     assert source == "follower"
     assert any(l["url"] == vs.url for l in locs)
 
+    from seaweedfs_tpu.stats import MASTER_LOOKUP_COUNTER
+    served = MASTER_LOOKUP_COUNTER.value("follower")
     r = requests.get(f"http://127.0.0.1:{follower.http_port}/dir/lookup",
                      params={"volumeId": str(lease.vid)}, timeout=5)
     assert r.status_code == 200
+    assert MASTER_LOOKUP_COUNTER.value("follower") > served
     body = r.json()
     assert body.get("leader") == leader.address
     assert any(l["url"] == vs.url for l in body["locations"])

@@ -324,11 +324,18 @@ class TestExecutor:
         from seaweedfs_tpu.ops import events
         from seaweedfs_tpu.stats import (LIFECYCLE_BYTES_MOVED,
                                          LIFECYCLE_TRANSITIONS)
+        from seaweedfs_tpu import qos
         ex, ran = self._exec()
+        classes = []
+        count = ex._dispatch
+        ex._dispatch = lambda t: classes.append(qos.current_class()) \
+            or count(t)
         before_n = LIFECYCLE_TRANSITIONS.value(TIER_EC, TIER_REMOTE)
         before_b = LIFECYCLE_BYTES_MOVED.value(TIER_EC, TIER_REMOTE)
         seq = events.JOURNAL.last_seq
         ex.execute(_plan(_tr(7, nbytes=123)))
+        # the move's reads and uploads admit behind foreground tenants
+        assert classes == [qos.CLASS_MAINTENANCE]
         assert LIFECYCLE_TRANSITIONS.value(TIER_EC, TIER_REMOTE) \
             == before_n + 1
         assert LIFECYCLE_BYTES_MOVED.value(TIER_EC, TIER_REMOTE) \

@@ -23,6 +23,7 @@ from seaweedfs_tpu.ec.locate import EcGeometry
 from seaweedfs_tpu.ec.volume import EcVolume
 from seaweedfs_tpu.ops.coder import NumpyCoder, get_coder, repair_read_bytes
 from seaweedfs_tpu.ops.piggyback import PiggybackCoder, partition_groups
+from seaweedfs_tpu.stats import REPAIR_BYTES_READ
 
 D, P = 10, 4
 GEO = EcGeometry(d=D, p=P, large_block=4096, small_block=512)
@@ -141,14 +142,29 @@ def test_repair_plan_degenerate_cases():
     assert NumpyCoder(D, P).repair_plan(all_ids[1:], (0,), size) is None
 
 
-def test_repair_read_bytes_costing():
-    size = 1 << 20
-    assert repair_read_bytes("rs", D, P, [1], size) == D * size
-    g, grp = PiggybackCoder(D, P).group_of(1)
-    assert repair_read_bytes("piggyback", D, P, [1], size) == \
-        (D + len(grp)) * size // 2
+# (codec, d, p, lost) -> survivor bytes read, in shard-equivalents: the
+# exact count the plan gives and the ceiling an operator is promised
+# (piggyback <= 0.7 x d at RS(10,4); msr <= 8.0 at the fork's RS(14,2)
+# and <= 4.0 at upstream's RS(10,4), data or parity lost; the cut-set
+# bounds are 7.5 and 3.25). Counts from the plan: no file, no clock.
+@pytest.mark.parametrize("codec,d,p,lost,exact,ceiling", [
+    ("rs", 10, 4, [1], 10.0, 10.0),
+    ("piggyback", 10, 4, [1], 6.5, 7.0),   # (d + |S_g|) / 2, |S_g| = 3
     # multi-loss falls back to trivial under either codec
-    assert repair_read_bytes("piggyback", D, P, [0, 1], size) == D * size
+    ("piggyback", 10, 4, [0, 1], 10.0, 10.0),
+    # p=2: the only group is all of [d], nothing beats trivial
+    ("piggyback", 14, 2, [1], 14.0, 14.0),
+    ("msr", 14, 2, [1], 7.5, 8.0),
+    ("msr", 14, 2, [15], 7.5, 8.0),
+    ("msr", 10, 4, [1], 3.25, 4.0),
+    ("msr", 10, 4, [11], 3.25, 4.0),
+])
+def test_repair_read_bytes_costing(codec, d, p, lost, exact, ceiling):
+    size = 1 << 20
+    got = repair_read_bytes(codec, d, p, lost, size)
+    assert got == exact * size
+    assert got <= ceiling * size
+    assert got <= repair_read_bytes("rs", d, p, lost, size) == d * size
 
 
 # -- file-level: encode, seal, rebuild ---------------------------------------
@@ -183,11 +199,15 @@ def test_rebuild_single_data_shard_is_ranged_and_cheap(tmp_path):
     shard_size = len(orig[1])
     os.remove(base + ecf.shard_ext(1))
     stats = {}
+    counted = REPAIR_BYTES_READ.value("piggyback")
     assert rebuild_shards(base, GEO, pb, stats=stats) == [1]
     assert open(base + ecf.shard_ext(1), "rb").read() == orig[1]
     assert stats["path"] == "ranged"
     _g, grp = pb.group_of(1)
     assert stats["bytes_read"] == (D + len(grp)) * shard_size // 2
+    assert stats["bytes_read"] <= 0.7 * D * shard_size
+    assert REPAIR_BYTES_READ.value("piggyback") - counted \
+        == stats["bytes_read"]
     assert stats["bytes_written"] == shard_size
     assert stats["codec"] == "piggyback"
 

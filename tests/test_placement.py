@@ -417,9 +417,12 @@ def test_executor_move_metrics_and_journal():
     from seaweedfs_tpu.ops import events
     from seaweedfs_tpu.stats import BALANCE_BYTES_MOVED, BALANCE_MOVES
 
+    from seaweedfs_tpu import qos
+    classes = []
+
     class _Exec(BalanceExecutor):
         def _move_volume(self, m):
-            pass
+            classes.append(qos.current_class())
 
     before = BALANCE_MOVES.value("volume")
     before_bytes = BALANCE_BYTES_MOVED.value("true")
@@ -428,6 +431,8 @@ def test_executor_move_metrics_and_journal():
     since = events.JOURNAL.last_seq
     res = _Exec(_RecordingEnv()).execute(_plan_of([mv]))
     assert len(res["done"]) == 1
+    # rebalance traffic is maintenance-class at the source
+    assert classes == [qos.CLASS_MAINTENANCE]
     assert BALANCE_MOVES.value("volume") == before + 1
     assert BALANCE_BYTES_MOVED.value("true") == before_bytes + 777
     moved = [e for e in events.JOURNAL.snapshot(since=since,

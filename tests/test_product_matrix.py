@@ -28,6 +28,7 @@ from seaweedfs_tpu.ops.coder import (NumpyCoder, codec_coder, get_coder,
                                      registered_codecs, repair_read_bytes)
 from seaweedfs_tpu.ops.piggyback import PiggybackCoder
 from seaweedfs_tpu.ops.product_matrix import ProductMatrixCoder
+from seaweedfs_tpu.stats import REPAIR_BYTES_READ
 
 D, P = 4, 2
 GEO = EcGeometry(d=D, p=P, large_block=4096, small_block=512)
@@ -218,16 +219,23 @@ def test_planner_costs_msr_items():
 
 # -- file-level: seal, rebuild paths, byte accounting ------------------------
 
-def _encode(tmp_path, coder, seed=0, size=D * 4096 * 2 + 777):
+def _encode(tmp_path, coder, seed=0, size=None, geo=GEO):
+    size = size or geo.d * 4096 * 2 + 777
     rng = np.random.default_rng(seed)
     datp = str(tmp_path / "v.dat")
     with open(datp, "wb") as f:
         f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
     base = str(tmp_path / "v")
-    encode_volume(datp, base, GEO, coder, chunk=256, batch=4)
+    encode_volume(datp, base, geo, coder, chunk=256, batch=4)
     orig = {i: open(base + ecf.shard_ext(i), "rb").read()
-            for i in range(GEO.n)}
+            for i in range(geo.n)}
     return base, orig
+
+
+# the module's small ring, and the two shipped ones: the fork's RS(14,2)
+# and upstream's RS(10,4) (alpha 256 both)
+def _geo(d, p):
+    return EcGeometry(d=d, p=p, large_block=4096, small_block=512)
 
 
 def test_vif_seals_codec_and_streamed_equals_whole(tmp_path):
@@ -252,34 +260,39 @@ def test_vif_seals_codec_and_streamed_equals_whole(tmp_path):
         assert par[j].tobytes() == orig[D + j], f"parity {j}"
 
 
-@pytest.mark.parametrize("lost", [1, D, D + 1])
-def test_rebuild_single_loss_ranged_at_cutset(tmp_path, lost):
-    pm = ProductMatrixCoder(D, P)
-    base, orig = _encode(tmp_path, pm, seed=2 + lost)
+@pytest.mark.parametrize("d,p,lost", [
+    (D, P, 1), (D, P, D), (D, P, D + 1),
+    (14, 2, 1), (14, 2, 15), (10, 4, 1), (10, 4, 11)])
+def test_rebuild_single_loss_ranged_at_cutset(tmp_path, d, p, lost):
+    geo, pm = _geo(d, p), ProductMatrixCoder(d, p)
+    base, orig = _encode(tmp_path, pm, seed=2 + lost, geo=geo)
     shard_size = len(orig[0])
     os.remove(base + ecf.shard_ext(lost))
     stats = {}
-    assert rebuild_shards(base, GEO, pm, stats=stats) == [lost]
+    counted = REPAIR_BYTES_READ.value("msr")
+    assert rebuild_shards(base, geo, pm, stats=stats) == [lost]
     assert open(base + ecf.shard_ext(lost), "rb").read() == orig[lost]
     assert stats["path"] == "ranged"
-    n = D + P
-    assert stats["bytes_read"] == (n - 1) * shard_size // P
+    assert stats["bytes_read"] == (d + p - 1) * shard_size // p
     assert stats["bytes_written"] == shard_size
+    # what operators graph is what the rebuild says it read
+    assert REPAIR_BYTES_READ.value("msr") - counted == stats["bytes_read"]
 
 
-def test_rebuild_multi_loss_reads_each_survivor_once(tmp_path):
-    pm = ProductMatrixCoder(D, P)
-    base, orig = _encode(tmp_path, pm, seed=9)
+@pytest.mark.parametrize("d,p", [(D, P), (14, 2), (10, 4)])
+def test_rebuild_multi_loss_reads_each_survivor_once(tmp_path, d, p):
+    geo, pm = _geo(d, p), ProductMatrixCoder(d, p)
+    base, orig = _encode(tmp_path, pm, seed=9, geo=geo)
     shard_size = len(orig[0])
-    for sid in (0, D + 1):
+    for sid in (0, d + 1):
         os.remove(base + ecf.shard_ext(sid))
     stats = {}
-    assert rebuild_shards(base, GEO, pm, stats=stats) == [0, D + 1]
-    for sid in (0, D + 1):
+    assert rebuild_shards(base, geo, pm, stats=stats) == [0, d + 1]
+    for sid in (0, d + 1):
         assert open(base + ecf.shard_ext(sid), "rb").read() == orig[sid]
     assert stats["path"] == "general"
     # exactly d survivors, each read exactly once — never once per loss
-    assert stats["bytes_read"] == D * shard_size
+    assert stats["bytes_read"] == d * shard_size
 
 
 def test_rebuild_remote_survivors_fetch_fragments(tmp_path):

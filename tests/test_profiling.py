@@ -331,21 +331,47 @@ def _get(url, timeout=10):
         return r.status, r.read().decode()
 
 
-def test_volume_debug_profile_gated_like_master(tmp_path):
+@pytest.mark.parametrize("white_list,want", [
+    (["203.0.113.9"], 401), (None, 200)], ids=["whitelisted-out", "open"])
+def test_volume_debug_profile_gated_like_master(tmp_path, monkeypatch,
+                                                white_list, want):
     """The satellite the tentpole rode in on: /debug/profile shipped
     UNGUARDED on the volume server. With an IP whitelist that excludes
-    localhost, profile AND flight must answer 401, and non-GET 405."""
+    localhost, profile AND flight must answer 401, and non-GET 405.
+    Open, a GET over the threshold lands in the ring with its stage
+    timeline and a trace id that /debug/traces on the same node
+    resolves."""
+    from seaweedfs_tpu.profiling.flight import FLIGHT
     from seaweedfs_tpu.security.guard import Guard
+    from seaweedfs_tpu.storage.needle import Needle
+    from seaweedfs_tpu.storage.types import file_id
+    monkeypatch.setattr(FLIGHT, "slow_ms", 0.0)  # every request is "slow"
     vs = _make_server(tmp_path, free_port(),
-                      guard=Guard(white_list=["203.0.113.9"]))
+                      guard=Guard(white_list=white_list) if white_list
+                      else None)
     try:
         wait_until(lambda: _probe(f"http://{vs.url}/status") == 200,
                    timeout=10, msg="volume http up")
         for path in ("/debug/profile?mode=summary", "/debug/flight"):
-            assert _probe(f"http://{vs.url}{path}") == 401, path
+            assert _probe(f"http://{vs.url}{path}") == want, path
         req = urllib.request.Request(
             f"http://{vs.url}/debug/profile", method="POST", data=b"")
         assert _probe_req(req) == 405
+        if white_list:
+            return
+        vs.store.add_volume(7)
+        vs.store.write_needle(7, Needle(id=1, cookie=9, data=b"flown"))
+        fid = file_id(7, 1, 9)
+        assert _get(f"http://{vs.url}/{fid}")[1] == "flown"
+        _, body = _get(f"http://{vs.url}/debug/flight?kind=volume.get")
+        (ent,) = [e for e in json.loads(body)["entries"]
+                  if e["node"] == vs.url and e["path"] == f"/{fid}"]
+        assert ent["why"] == "slow" and ent["status"] == 200
+        assert {"recv_parse", "queue_wait", "auth_admit", "store",
+                "serialize_flush"} <= set(ent["stages_ms"])
+        _, body = _get(f"http://{vs.url}/debug/traces"
+                       f"?trace_id={ent['trace_id']}")
+        assert json.loads(body)["count"] >= 1
     finally:
         vs.stop()
 

@@ -55,11 +55,14 @@ class TestElection:
                                for m in quorum), msg="followers learn leader")
 
     def test_leader_failover(self, quorum):
+        from seaweedfs_tpu.stats import RAFT_LEADER_CHANGES
         leader = _wait_for_leader(quorum)
+        changes = RAFT_LEADER_CHANGES.value()
         leader.stop()
         rest = [m for m in quorum if m is not leader]
         new_leader = _wait_for_leader(rest)
         assert new_leader is not leader
+        assert RAFT_LEADER_CHANGES.value() > changes
 
     def test_non_leader_rejects_assign(self, quorum):
         from seaweedfs_tpu.pb import master_pb2 as mpb
