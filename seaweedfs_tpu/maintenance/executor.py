@@ -52,9 +52,10 @@ class _InfoSweep:
     responses, so costing an item never re-issues the RPC its remount
     probe just made while the admin lock is held."""
 
-    def __init__(self, env):
+    def __init__(self, env, servers: "list | None" = None):
         self.env = env
-        self._servers: list = []
+        # a caller that has read the topology hands its read in
+        self._servers: list = list(servers or [])
         self._memo: dict = {}
 
     def servers(self) -> list:
@@ -83,10 +84,11 @@ class _InfoSweep:
         return resp
 
 
-def make_probes(env) -> tuple:
+def make_probes(env, servers: "list | None" = None) -> tuple:
     """(probe_remountable, probe_geometry) over ONE shared info sweep —
-    what build_plan call sites should use."""
-    sweep = _InfoSweep(env)
+    what build_plan call sites should use. `servers`: the caller's own
+    `collect_volume_servers()` read, so plan and probes see one topology."""
+    sweep = _InfoSweep(env, servers)
     return (make_remount_probe(env, sweep), make_geometry_probe(env, sweep))
 
 
@@ -342,9 +344,10 @@ class RepairExecutor:
     def _do_ec_rebuild(self, it: RepairItem) -> dict:
         """Delegate to the shell's ec.rebuild for one volume: reconstruct
         on the best holder with ranged survivor fetches, remount. The
-        shell command already handles settled-holder polling; its
-        byte totals flow into the repair.done journal event so the
-        codec's repair-traffic win is visible at /debug/events."""
+        shell command plans from one topology read and re-plans once
+        if the rebuild RPC fails; its byte totals flow into the
+        repair.done journal event so the codec's repair-traffic win is
+        visible at /debug/events."""
         from ..shell.ec_commands import cmd_ec_rebuild
         res = cmd_ec_rebuild(self.env, ["-volumeId", str(it.vid)]) or {}
         return {"shards": it.shard_ids,

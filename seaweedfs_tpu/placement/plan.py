@@ -301,8 +301,7 @@ def build_ec_balance_plan(
 
     All moves of one stripe between one (src, dst) pair are grouped
     into a single Move — the executor issues one VolumeEcShardsMove per
-    pair (the satellite fix: the old loop re-ran the settled-holder
-    poll and a full topology collect per single shard).
+    pair, from the one topology read the verb made.
 
     `costs` (geo LinkCostModel; defaults when None) orders candidate
     destinations cheapest-link-first within the evenness/rack caps, so

@@ -406,7 +406,11 @@ class VolumeServer:
         state change made before this call (or timeout). Admin RPCs that
         mutate volume/EC registration call this so topology reads anywhere
         in the cluster see the change once the RPC returns — closing the
-        assemble-send-ingest race the old fire-and-forget trigger left."""
+        assemble-send-ingest race the old fire-and-forget trigger left.
+        The shell's admin verbs plan from ONE topology read on the strength
+        of it (shell/ec_commands.py:_ec_volumes), so a timeout is logged:
+        the RPC still answers (the change is made, the next pulse carries
+        it), but until then the master's view is behind this server."""
         if self._stop.is_set() or self._leave.is_set():
             return False  # no heartbeat loop to ack (leave/decommission)
         with self._hb_cond:
@@ -417,8 +421,14 @@ class VolumeServer:
         with self._hb_cond:
             while self._hb_acked_seq < target:
                 remaining = deadline - time.monotonic()
-                if remaining <= 0 or self._stop.is_set() \
-                        or self._leave.is_set():
+                if self._stop.is_set() or self._leave.is_set():
+                    return False
+                if remaining <= 0:
+                    log.warning(
+                        "master %s did not acknowledge a heartbeat within "
+                        "%.1fs of a registration change: topology reads "
+                        "may miss it until the next pulse",
+                        self.current_leader, timeout)
                     return False
                 self._hb_cond.wait(min(remaining, 0.25))
         return True
@@ -2843,7 +2853,9 @@ class VolumeServer:
         def ec_copy(req, context):
             """Pull shard files FROM source_data_node to this server.
             All of a volume's shard files stay in ONE location: prefer
-            the location already holding its .ecx."""
+            the location already holding its .ecx. Files only: nothing is
+            mounted, so there is no registration change to flush — the
+            caller's VolumeEcShardsMount makes and flushes it."""
             failpoints.check("ec.shard.copy")
             src = Stub(req.source_data_node, VOLUME_SERVICE)
             loc = next((l for l in store.locations
@@ -2884,6 +2896,8 @@ class VolumeServer:
                    vpb.VolumeEcShardsCopyByRebuildResponse)
         @_maintenance_tagged
         def ec_copy_by_rebuild(req, context):
+            # like ec_copy, writes shard files and mounts none: the
+            # caller's VolumeEcShardsMount registers them, and flushes
             loc = store._location_for(None)
             base = loc.base_name(req.collection, req.volume_id)
             # the tiny .vif sidecar still copies whole (it carries the
@@ -2949,6 +2963,11 @@ class VolumeServer:
                 if os.path.exists(base + ".vif"):
                     ec_files.drop_remote_claims(base + ".vif",
                                                 list(req.shard_ids))
+            # a shard still mounted when the delete came was unmounted
+            # above: flush. After a VolumeEcShardsUnmount (it flushed) this
+            # one has nothing to report and stays all the same: a heartbeat
+            # round trip is ~2 ms, the unlinking beside it 70 ms a volume
+            # (the seal's timing lines, PERF.md §6, PR 32)
             vs.flush_heartbeat()
             return vpb.VolumeEcShardsDeleteResponse()
 

@@ -12,6 +12,9 @@ from __future__ import annotations
 import argparse
 import time
 
+import grpc
+
+from ..ec import shard_ids
 from ..pb import volume_server_pb2 as vpb
 from ..utils.rpc import Stub, VOLUME_SERVICE
 from .commands import CommandEnv, command
@@ -34,35 +37,26 @@ def parse_ec_shards(spec: str) -> tuple[int, int]:
     return d, p
 
 
-def _ec_holders(env: CommandEnv, vid: int) -> dict[int, list[dict]]:
-    """shard id -> servers holding it."""
-    out: dict[int, list[dict]] = {}
-    for srv in env.collect_volume_servers():
+def _ec_volumes(servers: list[dict]
+                ) -> dict[int, tuple[str, dict[int, list[dict]]]]:
+    """vid -> (collection, shard id -> servers holding it), from ONE
+    `env.collect_volume_servers()` read. A verb that holds the admin lock
+    plans from a single read and never polls for a second that agrees:
+    every RPC that changes EC registration (VolumeEcShardsMount, Unmount,
+    Delete, Rebuild, Move, ToVolume) returns only after the master has
+    ingested a heartbeat carrying the change (VolumeServer.flush_heartbeat),
+    and the lock keeps every other admin verb out. Only a disk dying with
+    no RPC can outdate the read, and a second read within a pulse would
+    miss that too: such a plan fails at the RPC that meets it, and the
+    caller re-plans there (cmd_ec_rebuild, _gather_shards)."""
+    vols: dict[int, tuple[str, dict[int, list[dict]]]] = {}
+    for srv in servers:
         for disk in srv["disks"].values():
             for s in disk.ec_shard_infos:
-                if s.id == vid:
-                    for sid in range(32):
-                        if s.ec_index_bits >> sid & 1:
-                            out.setdefault(sid, []).append(srv)
-    return out
-
-
-def _settled_ec_holders(env: CommandEnv, vid: int,
-                        tries: int = 20, interval: float = 0.2
-                        ) -> dict[int, list[dict]]:
-    """Master topology is heartbeat-propagated (eventually consistent); after
-    mount/unmount RPCs the view lags by up to a pulse. Poll until two
-    consecutive reads agree before acting on it."""
-    prev = None
-    holders = _ec_holders(env, vid)
-    for _ in range(tries):
-        cur = {sid: sorted(h["id"] for h in hs) for sid, hs in holders.items()}
-        if prev is not None and cur == prev:
-            break
-        prev = cur
-        time.sleep(interval)
-        holders = _ec_holders(env, vid)
-    return holders
+                holders = vols.setdefault(s.id, (s.collection, {}))[1]
+                for sid in shard_ids(s.ec_index_bits):
+                    holders.setdefault(sid, []).append(srv)
+    return vols
 
 
 def _free_slots(srv: dict) -> int:
@@ -283,52 +277,34 @@ def cmd_ec_rebuild(env: CommandEnv, args):
     p.add_argument("-byRebuild", action="store_true",
                    help="use the fork's CopyByRebuild RPC on a fresh server")
     opt = p.parse_args(args)
-    # find all ec volumes and their shard coverage
-    vols: dict[int, tuple[str, dict[int, list[dict]]]] = {}
-    for srv in env.collect_volume_servers():
-        for disk in srv["disks"].values():
-            for s in disk.ec_shard_infos:
-                if opt.volumeId and s.id != opt.volumeId:
-                    continue
-                vols.setdefault(s.id, (s.collection, {}))
+    # ONE topology read plans the whole verb (_ec_volumes says why it may):
+    # volumes share no shards, so this verb's own rebuild + mount of one
+    # volume moves no other volume's holders
+    vols = _ec_volumes(env.collect_volume_servers())
     summary = {"rebuilt": 0, "bytes_read": 0, "bytes_written": 0}
-    for vid, (collection, _) in sorted(vols.items()):
-        holders = _settled_ec_holders(env, vid)
-        if not holders:
+    for vid in sorted(vols):
+        if opt.volumeId and vid != opt.volumeId:
             continue
-        # geometry: n = max(shard ids)+1 is unreliable; read from a holder
-        have = sorted(holders)
-        any_srv = holders[have[0]][0]
-        n = _probe_n_shards(env, any_srv, vid, collection)
-        missing = [s for s in range(n) if s not in holders]
-        if not missing:
+        collection, holders = vols[vid]
+        try:
+            done = _rebuild_missing(env, vid, collection, holders,
+                                    opt.byRebuild)
+        except grpc.RpcError as e:
+            # a reaction, not a delay: the read named a host or a survivor
+            # that is not there (a server died with no RPC to flush it).
+            # Read once more, plan this volume again; a second failure
+            # raises — never "rebuilt 0 shards" over a damaged stripe
+            env.println(f"  ec volume {vid}: rebuild failed ({e}); "
+                        f"re-plan from a fresh topology read")
+            fresh = _ec_volumes(env.collect_volume_servers()).get(vid)
+            if fresh is None:
+                raise
+            collection, holders = fresh
+            done = _rebuild_missing(env, vid, collection, holders,
+                                    opt.byRebuild)
+        if done is None:
             continue
-        env.println(f"  ec volume {vid}: missing shards {missing}")
-        if opt.byRebuild:
-            # fork path: rebuild directly onto the least-loaded server
-            target = balanced_ec_distribution(
-                env.collect_volume_servers(), 1)[0]
-            resp = _stub(env, target).call(
-                "VolumeEcShardsCopyByRebuild",
-                vpb.VolumeEcShardsCopyByRebuildRequest(
-                    volume_id=vid, collection=collection, shard_ids=missing),
-                vpb.VolumeEcShardsCopyByRebuildResponse, timeout=3600)
-            host = target
-        else:
-            # default: rebuild on the holder with the most local shards
-            # (fewest remote ranges to pull); deterministic on ties
-            counts: dict[str, list] = {}
-            for _sid, hs in holders.items():
-                for h in hs:
-                    counts.setdefault(h["id"], [0, h])
-                    counts[h["id"]][0] += 1
-            host = sorted(counts.items(),
-                          key=lambda kv: (-kv[1][0], kv[0]))[0][1][1]
-            resp = _stub(env, host).call(
-                "VolumeEcShardsRebuild",
-                vpb.VolumeEcShardsRebuildRequest(volume_id=vid,
-                                                 collection=collection),
-                vpb.VolumeEcShardsRebuildResponse, timeout=3600)
+        host, resp = done
         if resp.rebuilt_shard_ids:
             _stub(env, host).call(
                 "VolumeEcShardsMount",
@@ -347,12 +323,48 @@ def cmd_ec_rebuild(env: CommandEnv, args):
     return summary
 
 
+def _rebuild_missing(env: CommandEnv, vid: int, collection: str,
+                     holders: dict[int, list[dict]], by_rebuild: bool):
+    """Plan one volume from its holders and run the rebuild RPC: (host,
+    response), or None when no shard is missing. Mounting is the caller's."""
+    if not holders:
+        return None
+    # geometry: n = max(shard ids)+1 is unreliable; read from a holder
+    any_srv = holders[min(holders)][0]
+    n = _probe_n_shards(env, any_srv, vid, collection)
+    missing = [s for s in range(n) if s not in holders]
+    if not missing:
+        return None
+    env.println(f"  ec volume {vid}: missing shards {missing}")
+    if by_rebuild:
+        # fork path: rebuild directly onto the least-loaded server. Load,
+        # unlike holders, moves with this verb's own rebuilds: read it here
+        host = balanced_ec_distribution(env.collect_volume_servers(), 1)[0]
+        return host, _stub(env, host).call(
+            "VolumeEcShardsCopyByRebuild",
+            vpb.VolumeEcShardsCopyByRebuildRequest(
+                volume_id=vid, collection=collection, shard_ids=missing),
+            vpb.VolumeEcShardsCopyByRebuildResponse, timeout=3600)
+    # default: rebuild on the holder with the most local shards
+    # (fewest remote ranges to pull); deterministic on ties
+    counts: dict[str, list] = {}
+    for hs in holders.values():
+        for h in hs:
+            counts.setdefault(h["id"], [0, h])[0] += 1
+    host = min(counts.items(), key=lambda kv: (-kv[1][0], kv[0]))[1][1]
+    return host, _stub(env, host).call(
+        "VolumeEcShardsRebuild",
+        vpb.VolumeEcShardsRebuildRequest(volume_id=vid,
+                                         collection=collection),
+        vpb.VolumeEcShardsRebuildResponse, timeout=3600)
+
+
 def _gather_shards(env: CommandEnv, host_stub: Stub, vid: int, collection: str,
                    fetch: list[int], holders: dict[int, list[dict]]) -> None:
     """Copy each shard in `fetch` onto the host from a server that actually
-    holds it (per-shard source), including the index sidecars. Holders come
-    from eventually-consistent master state, so try every listed holder and
-    refresh the view on failure."""
+    holds it (per-shard source), including the index sidecars. A holder
+    can die after the verb's one topology read: try every listed holder and,
+    as a reaction to a failed copy, read the view again."""
     first = True
     for sid in fetch:
         hs = list(holders.get(sid) or [])
@@ -379,7 +391,8 @@ def _gather_shards(env: CommandEnv, host_stub: Stub, vid: int, collection: str,
                 break
             if not copied:
                 time.sleep(0.3)
-                hs = list(_ec_holders(env, vid).get(sid) or [])
+                fresh = _ec_volumes(env.collect_volume_servers())
+                hs = list(fresh.get(vid, ("", {}))[1].get(sid) or [])
         if not copied:
             if last_err is None:
                 continue  # no holder anywhere: leave it to rebuild
@@ -408,8 +421,7 @@ def _probe_n_shards(env: CommandEnv, srv: dict, vid: int, collection: str) -> in
          "evenly across servers, rack-safety-capped")
 def cmd_ec_balance(env: CommandEnv, args):
     """Thin shell over the placement plane (seaweedfs_tpu/placement/):
-    ONE topology snapshot plans every move (the old loop re-ran the
-    settled-holder poll + a full cluster collect per single shard), all
+    ONE topology read plans every move (_ec_volumes says why it may), all
     shards of a stripe moving between one (src, dst) pair ride ONE
     VolumeEcShardsMove RPC, no rack ends up holding more than the
     stripe's parity count, and every hop is maintenance-class through
@@ -433,18 +445,11 @@ def cmd_ec_balance(env: CommandEnv, args):
                         "overrides the master's")
     opt = p.parse_args(args)
 
-    # stripes can drift for a pulse after encode/rebuild RPCs; settle
-    # one stripe's holder view (two consecutive identical reads) before
-    # snapshotting so the plan isn't built mid-heartbeat — ONCE, not
-    # once per move like the old loop
-    any_vid = next((s.id for srv in env.collect_volume_servers()
-                    for disk in srv["disks"].values()
-                    for s in disk.ec_shard_infos), None)
-    if any_vid is None:
+    servers = env.collect_volume_servers()
+    if not _ec_volumes(servers):
         env.println("no ec shards to balance")
         return
-    _settled_ec_holders(env, any_vid, tries=5)
-    _remount_probe, geometry_probe = make_probes(env)
+    _remount_probe, geometry_probe = make_probes(env, servers)
 
     def parity_of(vid: int, collection: str) -> "int | None":
         g = geometry_probe(vid, collection)
@@ -456,7 +461,7 @@ def cmd_ec_balance(env: CommandEnv, args):
 
     limit_mb = env.mc.volume_list().volume_size_limit_mb or 30_000
     snap = snapshot_from_servers(
-        env.collect_volume_servers(), shard_bytes_of=shard_bytes_of,
+        servers, shard_bytes_of=shard_bytes_of,
         default_shard_bytes=(limit_mb << 20) // 10)
     from .health_util import fetch_link_costs
     plan = build_ec_balance_plan(snap, collection=opt.collection,
@@ -495,19 +500,14 @@ def cmd_ec_decode(env: CommandEnv, args):
     p.add_argument("-volumeId", type=int, required=True)
     opt = p.parse_args(args)
     vid = opt.volumeId
-    holders = _settled_ec_holders(env, vid)
+    collection, holders = _ec_volumes(env.collect_volume_servers()).get(
+        vid, ("", {}))
     if not holders:
         env.println(f"no ec shards for volume {vid}")
         return
     # gather all shards onto one holder then ShardsToVolume
     servers = {h["id"]: h for hs in holders.values() for h in hs}
     host = next(iter(servers.values()))
-    collection = ""
-    for srv in env.collect_volume_servers():
-        for disk in srv["disks"].values():
-            for s in disk.ec_shard_infos:
-                if s.id == vid:
-                    collection = s.collection
     host_stub = _stub(env, host)
     host_sids = {s for s, hs in holders.items()
                  if any(h["id"] == host["id"] for h in hs)}
