@@ -2443,7 +2443,8 @@ class VolumeServer:
                         mode=r.mode, walk_s=round(r.walk_s, 4),
                         pack_s=round(r.pack_s, 4),
                         device_s=round(r.device_s, 4),
-                        compare_s=round(r.compare_s, 4))
+                        compare_s=round(r.compare_s, 4),
+                        device_busy_s=round(r.device_busy_s, 4))
                 except Exception as e:  # noqa: BLE001 — isolate per volume
                     resp.results.add(volume_id=v.id, mode="error",
                                      error=str(e))

@@ -24,11 +24,22 @@ program: one product over all of them, then a fold whose depth alone
 follows L. The raw device states are corrected for the zero prefix with
 crc32c.finalize(lengths).
 
-Where the time goes is kept per volume in a tracing.StageAccount:
-`walk` (the record walk and body reads between two dispatches), `pack`
-(zero-fill and copy into the [B, L] block), `device` (the jitted call
-through np.asarray: H2D, the loop-free CRC program, D2H) and `compare`
-(finalize + the CRC compare). In a process with jax loaded each is a
+A block is on the chip while the next is walked and packed: the sweep
+owns one device thread for the length of a volume, hands it each packed
+block and compares the results in dispatch order as they come back, with
+at most _IN_FLIGHT blocks handed over and not yet compared. The sweep's
+thread waits only at that bound and at the volume's end. The host loop
+starts no thread.
+
+Where the time goes is kept per volume in a tracing.StageAccount. On the
+sweep's thread, exclusive, so that they partition `elapsed_s`: `walk`
+(the record walk and body reads between two dispatches), `pack`
+(zero-fill and copy into the [B, L] block), `wait` (blocked on the device
+thread: `device_s` of the result) and `compare` (finalize + the CRC
+compare). On the device thread, beside them: `device` (the jitted call
+through np.asarray: H2D, the loop-free CRC program, D2H), `device_busy_s`
+of the result; 1 - device_s / device_busy_s is the share of the device
+stage that host work hid. In a process with jax loaded each is a
 `swtpu/scrub.<stage>` annotation in a live profiler trace; `scrub.device`
 carries `needed` (needle bytes in the block), `dispatched` (B x L) and
 `L`. The host loop has the `walk` alone.
@@ -36,9 +47,12 @@ carries `needed` (needle bytes in the block), `dispatched` (B x L) and
 
 from __future__ import annotations
 
+import contextvars
 import functools
 import struct
 import time
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -56,6 +70,9 @@ _CHUNK = 512
 # padded bytes in one device dispatch; each length bucket L runs at the
 # fixed shape [_DISPATCH_BYTES // L, L] (one row for longer needles)
 _DISPATCH_BYTES = 8 << 20
+# blocks handed to the device thread and not yet compared while the next is
+# walked and packed: 24 MiB of blocks alive at most
+_IN_FLIGHT = 2
 
 
 @dataclass
@@ -67,12 +84,16 @@ class ScrubResult:
     elapsed_s: float = 0.0
     mode: str = "cpu"
     error: str = ""  # volume-level trouble (torn walk, tiered skip, ...)
-    # exclusive stage seconds (they partition elapsed_s), device blocks
-    # dispatched and their padded bytes (>= bytes_checked)
+    # exclusive stage seconds of the sweep's thread (they partition
+    # elapsed_s; device_s is its wait for the device thread), the device
+    # thread's own seconds inside the jitted call through np.asarray
+    # beside them, device blocks dispatched and their padded bytes
+    # (>= bytes_checked)
     walk_s: float = 0.0
     pack_s: float = 0.0
     device_s: float = 0.0
     compare_s: float = 0.0
+    device_busy_s: float = 0.0
     blocks: int = 0
     bytes_dispatched: int = 0
 
@@ -141,23 +162,26 @@ def _iter_needles(v: Volume, res: ScrubResult):
                      f"(header rot or torn write)")
 
 
-def _device_crcs(shape: "tuple[int, int]", datas: "list[bytes]",
-                 acct) -> np.ndarray:
-    """CRCs of up to B needles through one fixed [B, L] dispatch, as the
-    stages `pack`, `device` and `compare` of the scrub's account."""
+def _pack(shape: "tuple[int, int]",
+          datas: "list[bytes]") -> "tuple[np.ndarray, np.ndarray]":
+    """Up to B needles LEFT-zero-padded into one fixed [B, L] block, and
+    their lengths."""
     rows, pad_l = shape
-    with acct.stage("pack"):
-        blocks = np.zeros((rows, pad_l), dtype=np.uint8)
-        lengths = np.zeros(rows, dtype=np.int64)
-        for i, d in enumerate(datas):
-            lengths[i] = len(d)
-            if d:
-                blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
-    with acct.stage("device", needed=int(lengths.sum()),
-                    dispatched=rows * pad_l, L=pad_l):
-        raw = np.asarray(_crc_jit()(blocks))
-    with acct.stage("compare"):
-        return crcmod.finalize(raw.astype(np.uint32), lengths)[:len(datas)]
+    blocks = np.zeros((rows, pad_l), dtype=np.uint8)
+    lengths = np.zeros(rows, dtype=np.int64)
+    for i, d in enumerate(datas):
+        lengths[i] = len(d)
+        if d:
+            blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
+    return blocks, lengths
+
+
+def _device_stage(acct, blocks: np.ndarray, needed: int) -> np.ndarray:
+    """The raw CRC states of one block: the `device` stage, whole on the
+    thread that calls it, so its annotation encloses the program's run."""
+    with acct.stage("device", needed=needed, dispatched=blocks.size,
+                    L=blocks.shape[1]):
+        return np.asarray(_crc_jit()(blocks))
 
 
 def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
@@ -185,17 +209,40 @@ def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
         res.mode = "device" if backend.platform == "tpu" \
             else f"xla-{backend.platform}"
     from ..tracing import StageAccount
-    acct = StageAccount("scrub", ("walk", "pack", "device", "compare"))
+    acct = StageAccount("scrub", ("walk", "pack", "wait", "device", "compare"))
     t0 = time.monotonic()
     pending: "dict[tuple[int, int], tuple[list, list, list]]" = {}
+    # the sweep's own device thread, none in the host loop, and the blocks
+    # it holds, oldest first
+    device_thread = None if backend is None else ThreadPoolExecutor(
+        max_workers=1, thread_name_prefix="scrub-device")
+    flying: deque = deque()
+
+    def collect(leave: int) -> None:
+        """Compare, in dispatch order, the blocks whose states are back,
+        waiting for the oldest while more than `leave` are in flight."""
+        while flying and (len(flying) > leave or flying[0][0].done()):
+            fut, ids, lengths, stored = flying.popleft()
+            if not fut.done():
+                with acct.stage("wait"):
+                    wait((fut,))
+            raw = fut.result()  # or what the device stage raised
+            with acct.stage("compare"):
+                got = crcmod.finalize(raw.astype(np.uint32),
+                                      lengths)[:len(ids)]
+                bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
+                res.corrupt.extend(ids[int(i)] for i in bad)
 
     def dispatch(shape, ids, datas, stored) -> None:
-        got = _device_crcs(shape, datas, acct)
-        with acct.stage("compare"):
-            bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
-            res.corrupt.extend(ids[int(i)] for i in bad)
+        with acct.stage("pack"):
+            blocks, lengths = _pack(shape, datas)
+        # under a copy of this thread's context, as every executor hop
+        flying.append((device_thread.submit(
+            contextvars.copy_context().run, _device_stage, acct, blocks,
+            int(lengths.sum())), ids, lengths, stored))
         res.blocks += 1
         res.bytes_dispatched += shape[0] * shape[1]
+        collect(_IN_FLIGHT)
 
     needles = _iter_needles(v, res)
 
@@ -218,17 +265,24 @@ def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
                 return (shape, *pending.pop(shape))
         return None
 
-    while True:
-        with acct.stage("walk"):  # from one dispatch to the next
-            block = next_block()
-        if block is None:
-            break
-        dispatch(*block)
-    for shape, batch in pending.items():
-        dispatch(shape, *batch)
+    try:
+        while True:
+            with acct.stage("walk"):  # from one dispatch to the next
+                block = next_block()
+            if block is None:
+                break
+            dispatch(*block)
+        for shape, batch in pending.items():
+            dispatch(shape, *batch)
+        collect(0)
+    finally:
+        if device_thread is not None:
+            # joined on every way out: no thread outlives the sweep
+            device_thread.shutdown(wait=True, cancel_futures=True)
     res.elapsed_s = time.monotonic() - t0
-    res.walk_s, res.pack_s, res.device_s, res.compare_s = (
-        acct.seconds(k) for k in ("walk", "pack", "device", "compare"))
+    (res.walk_s, res.pack_s, res.device_s, res.compare_s,
+     res.device_busy_s) = (acct.seconds(k) for k in (
+         "walk", "pack", "wait", "compare", "device"))
     if res.corrupt:
         log.warning("scrub volume %d: %d/%d needles corrupt: %s",
                     v.id, len(res.corrupt), res.scanned,

@@ -215,23 +215,33 @@ def test_scrub_dispatch_stays_under_byte_bound(tmp_path, monkeypatch):
     for i, size in enumerate(sizes, start=1):
         v.write_needle(Needle(id=i, cookie=1, data=rng.integers(
             0, 256, size, dtype=np.uint8).tobytes()))
-    shapes = []
-    real_jit, real_block = scrub._crc_jit(), scrub._device_crcs
+    shapes, packed, crcs = [], [], []
+    real_jit, real_pack = scrub._crc_jit(), scrub._pack
+    real_finalize = scrub.crcmod.finalize
 
     def spy_jit(blocks):
         shapes.append(blocks.shape)
         return real_jit(blocks)
 
-    def spy_block(shape, datas, acct):
-        out = real_block(shape, datas, acct)
-        assert [int(c) for c in out] == [crc32c.crc32c(d) for d in datas]
-        return out
+    def spy_pack(shape, datas):
+        packed.append(datas)
+        return real_pack(shape, datas)
+
+    def spy_finalize(raw, lengths):
+        crcs.append(real_finalize(raw, lengths))
+        return crcs[-1]
     monkeypatch.setattr(scrub, "_crc_jit", lambda: spy_jit)
-    monkeypatch.setattr(scrub, "_device_crcs", spy_block)
+    monkeypatch.setattr(scrub, "_pack", spy_pack)
+    monkeypatch.setattr(scrub.crcmod, "finalize", spy_finalize)
     res = scrub.scrub_volume(v, device="auto")
     v.close()
     assert res.scanned == len(sizes)
     assert res.corrupt == [] and res.mode == "xla-cpu"
+    # blocks are compared in the order they were packed
+    assert len(packed) == len(crcs) == len(shapes) == res.blocks
+    for datas, out in zip(packed, crcs):
+        assert [int(c) for c in out[:len(datas)]] == [
+            crc32c.crc32c(d) for d in datas]
     assert max(b * l for b, l in shapes) <= scrub._DISPATCH_BYTES
     assert set(shapes) == {scrub._block_shape(n) for n in set(sizes)}
 

@@ -276,7 +276,7 @@ def test_scrub_writes_one_event_a_volume_and_prints_as_before(
         assert set(ev) == {"vid", "node", "scanned", "corrupt",
                            "bytes_checked", "bytes_dispatched", "blocks",
                            "elapsed_s", "mode", "walk_s", "pack_s",
-                           "device_s", "compare_s"}
+                           "device_s", "compare_s", "device_busy_s"}
         assert (ev["vid"], ev["scanned"]) == (int(m.group(1)),
                                               int(m.group(2)))
         # the host loop, or the kernel on the CPU backend where an
