@@ -134,17 +134,20 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
 
 
 # a rebuild's stages (tracing.StageAccount, annotated `swtpu/rebuild.*`):
-# `read` loads survivors (local preads or remote ranged fetches) into the
-# batch, `dispatch` is coder.reconstruct (H2D + launch; a host coder
-# computes here), `drain` blocks on the result (device + D2H), `write`
-# stores into the rebuilt shard files and flushes them. The codecs' own
-# executors (ranged / general, ec/repair.py) are one coarser stage
-# `codec` (decode + pwrite) with their survivor reads taken out as `read`.
+# `read` loads survivor ranges (local preads or remote ranged fetches)
+# into the batch, `dispatch` is the coder's matrix apply (H2D + launch; a
+# host coder computes here), `drain` blocks on the result (device + D2H),
+# `write` stores into the rebuilt shard files and flushes them. Plain RS
+# and every codec repair that is one matrix (`repair_linear`: piggyback's
+# single data shard) run these four in `_rebuild_batched`. The codecs'
+# other executors (ec/repair.py: piggyback's `general`, msr's `ranged` and
+# `general`) are one coarser stage `codec` (decode + pwrite) with their
+# survivor reads taken out as `read`.
 _REBUILD_STAGES = ("read", "dispatch", "drain", "write")
-# the plain-RS path loads a batch's survivors side by side: `read` stays
-# the stage's wall on the rebuild's thread, and the loads' own seconds are
-# summed beside it, all of them and by kind of survivor (never stages:
-# they overlap, so they partition nothing). read_busy_s / read_s is the
+# a batch's survivor ranges load side by side: `read` stays the stage's
+# wall on the rebuild's thread, and the loads' own seconds are summed
+# beside it, all of them and by kind of survivor (never stages: they
+# overlap, so they partition nothing). read_busy_s / read_s is the
 # overlap achieved.
 _READ_BUSY = ("read_busy", "read_local_busy", "read_remote_busy")
 
@@ -166,13 +169,27 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
                       frag_readers: dict, shard_size: int, chunk: int,
                       batch: int, counter, acct, fold_planner=None,
                       local_sids: frozenset = frozenset()) -> str:
-    """Pick the cheapest reconstruction the codec supports — resolved
-    through the repair.REBUILDERS registry, so a new codec plugs in its
-    executors without touching this dispatch. Returns the path taken
-    ("ranged" | "general" | "full" | "ranged-folded") for stats/traces."""
+    """Pick the cheapest reconstruction the codec supports. A repair that
+    is one matrix over the ranges it reads (plain RS over d survivors; a
+    codec's `repair_linear`) is a row source and a matrix for
+    `_rebuild_batched`; the rest resolve through the repair.REBUILDERS
+    registry, so a new codec plugs in its executors without touching this
+    dispatch. Returns the path taken ("ranged" | "general" | "full" |
+    "ranged-folded") for stats/traces."""
     from . import repair
     ranged, general = repair.REBUILDERS.get(coder.codec, (None, None))
     plan = coder.repair_plan(present, tuple(missing), shard_size)
+    linear = (coder.repair_linear(tuple(missing), shard_size)
+              if plan is not None else None)
+    if linear is not None:
+        matrix, targets, engine = linear
+        (extent,) = {ln for _, _, ln in plan}
+        _rebuild_batched(base, type(coder).__name__,
+                         [(sid, off) for sid, off, _ in plan], extent,
+                         targets, lambda arr: engine.apply_matrix(matrix, arr),
+                         readers, shard_size, chunk, batch, counter, acct,
+                         local_sids)
+        return "ranged"
     if (plan is not None and ranged is not None) or general is not None:
         readers = {s: acct.timed("read", r) for s, r in readers.items()}
         frag_readers = {s: acct.timed("read", r)
@@ -197,43 +214,55 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
             general(base, coder, present, missing, readers, frag_readers,
                     shard_size, counter)
         return "general"
-    _rebuild_positional(base, geo, coder, present, missing, readers,
-                        shard_size, chunk, batch, counter, acct, local_sids)
+    # plain RS: positional reconstruct over the first d survivors, whole
+    use = tuple(sorted(present)[:geo.d])
+    wanted = tuple(missing)
+    _rebuild_batched(base, type(coder).__name__, [(sid, 0) for sid in use],
+                     shard_size, [(m, 0) for m in missing],
+                     lambda arr: coder.reconstruct(arr, use, wanted),
+                     readers, shard_size, chunk, batch, counter, acct,
+                     local_sids)
     return "full"
 
 
-def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
-                        present: tuple, missing: list[int], readers: dict,
-                        shard_size: int, chunk: int, batch: int,
-                        counter, acct, local_sids: frozenset) -> None:
-    """Plain-RS path: positional reconstruct over [batch, d, chunk] slabs
-    of the first d survivors (device-batched like encode). A batch's
-    survivors load side by side, one task each: the batch waits for its
-    slowest survivor, not for their sum."""
-    use = sorted(present)[:geo.d]
+def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
+                     targets: list, apply, readers: dict, shard_size: int,
+                     chunk: int, batch: int, counter, acct,
+                     local_sids: frozenset) -> None:
+    """One matrix over [batch, len(sources), chunk] slabs (device-batched
+    like encode). Row r of a slab is `extent` bytes of survivor
+    sources[r] = (shard_id, offset); `apply(slab)` gives one row per
+    target = (shard_id, offset), `extent` bytes of a rebuilt shard file
+    from that offset on. Plain RS walks d whole survivors into the lost
+    shards; a codec's ranged repair walks the ranges of its plan. A
+    batch's rows load side by side, one task each: the batch waits for
+    its slowest survivor, not for their sum. The last batch is dispatched
+    whole and never padded from a survivor: its last slab is filled up
+    with zeros made here, and the slabs past it hold what the buffer held
+    (zeros, or an earlier batch's rows), are computed and dropped — every
+    column is on its own, so nothing of them reaches a shard file."""
     for name in _READ_BUSY:  # the fields exist though a kind has no load
         acct.add(name, 0.0, n=0)
     outs = {}
     with acct.stage("write"):
-        for m in missing:
+        for m in {m for m, _ in targets}:
             p = base + files.shard_ext(m)
             with open(p, "wb") as f:
                 f.truncate(shard_size)
             outs[m] = np.memmap(p, dtype=np.uint8, mode="r+",
                                 shape=(shard_size,))
 
-    present_t = tuple(use)
-    wanted_t = tuple(missing)
     from ..stats import EC_REBUILD_BYTES
     from .stream import AsyncPipe
-    pipe = AsyncPipe((batch, geo.d, chunk), acct)
+    pipe = AsyncPipe((batch, len(sources), chunk), acct)
 
     def drain(rebuilt: np.ndarray, ctx) -> None:
         off, span, nb = ctx
         with acct.stage("write"):
-            for k, m in enumerate(missing):
-                outs[m][off:off + span] = rebuilt[:nb, k].reshape(-1)[:span]
-        counter.wrote(span * len(missing))
+            for k, (m, at) in enumerate(targets):
+                outs[m][at + off:at + off + span] = \
+                    rebuilt[:nb, k].reshape(-1)[:span]
+        counter.wrote(span * len(targets))
 
     def load(arr: np.ndarray, r: int, sid: int, off: int, span: int,
              nb: int) -> None:
@@ -253,11 +282,11 @@ def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
         acct.add("read_local_busy" if sid in local_sids
                  else "read_remote_busy", busy)
 
-    loaders = ThreadPoolExecutor(max_workers=len(use),
+    loaders = ThreadPoolExecutor(max_workers=len(sources),
                                  thread_name_prefix="ec-rebuild-read")
     try:
-        for n, off in enumerate(range(0, shard_size, chunk * batch)):
-            span = min(chunk * batch, shard_size - off)
+        for n, off in enumerate(range(0, extent, chunk * batch)):
+            span = min(chunk * batch, extent - off)
             nb = (span + chunk - 1) // chunk
             arr = pipe.next_buffer()
             with acct.stage("read", batch=n):
@@ -265,15 +294,13 @@ def _rebuild_positional(base: str, geo: EcGeometry, coder: ErasureCoder,
                 # QoS class rides a remote read to its holder, and the
                 # fetch spans keep `ec.rebuild` as their parent
                 loads = [loaders.submit(contextvars.copy_context().run, load,
-                                        arr, r, sid, off, span, nb)
-                         for r, sid in enumerate(use)]
-                if nb < batch:
-                    arr[nb:] = 0
+                                        arr, r, sid, at + off, span, nb)
+                         for r, (sid, at) in enumerate(sources)]
                 for task in loads:
                     task.result()
-            EC_REBUILD_BYTES.inc(type(coder).__name__, amount=arr.nbytes)
+            EC_REBUILD_BYTES.inc(coder_name, amount=arr.nbytes)
             with acct.stage("dispatch", batch=n):
-                fut = coder.reconstruct(arr, present_t, wanted_t)
+                fut = apply(arr)
             pipe.submit(fut, (off, span, nb), drain)
     finally:
         # waits: after an error too every load has ended before the

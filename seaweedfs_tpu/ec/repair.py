@@ -11,6 +11,14 @@ ranged-COMPUTE mode, one wire fragment per survivor per window), counts
 every survivor byte into `SeaweedFS_repair_bytes_read_total` /
 `_written_total`, and streams in bounded windows so a 30 GB stripe
 never needs d shards of RAM.
+
+A plan whose repair is ONE matrix over the ranges it reads (piggyback's
+single data shard: `PiggybackCoder.repair_linear`) has no executor here:
+ec/encoder.py runs it as a row source and a matrix under the rebuild's
+own loaders, pipe and stages, through this module's readers and counter.
+What is left here runs window by window under the stage `codec`:
+piggyback's `general` (several shards lost, or a parity), msr's `ranged`
+and `general`.
 """
 
 from __future__ import annotations
@@ -175,49 +183,6 @@ def _pwrite(fd: int, arr: np.ndarray, off: int) -> None:
         mv = mv[n:]
         off += n
         n = os.pwrite(fd, mv, off)
-
-
-# ---------------------------------------------------------------------------
-# Hitchhiker single-data-shard repair: execute the coder's ranged plan.
-# ---------------------------------------------------------------------------
-
-def rebuild_piggyback_single(base: str, pb: PiggybackCoder, f: int,
-                             readers: dict, shard_size: int,
-                             counter: RepairCounter,
-                             window: int = REPAIR_WINDOW) -> None:
-    """Rebuild data shard f from byte ranges of survivors (the plan
-    ops/piggyback.py:repair_plan describes): (d-1) b-halves + parity 0's
-    b-half decode b_f; the piggybacked parity's b-half plus the group's
-    a-halves release a_f. Reads (d + |S_g|) / 2 shard-equivalents."""
-    d = pb.d
-    g, grp = pb.group_of(f)
-    half = shard_size // 2
-    present_b = tuple(sorted([i for i in range(d) if i != f] + [d]))
-    outs = _open_outputs(base, [f], shard_size)
-    try:
-        for w in range(0, half, window):
-            wl = min(window, half - w)
-            b_rows = np.stack([readers[s](half + w, wl) for s in present_b])
-            b_f = np.asarray(pb.inner.reconstruct(b_rows, present_b, (f,)),
-                             dtype=np.uint8)[0]
-            # full b substripe of the data shards, in id order
-            all_b = np.empty((d, wl), dtype=np.uint8)
-            for idx, s in enumerate(present_b[:-1]):
-                all_b[s] = b_rows[idx]
-            all_b[f] = b_f
-            p_g = np.asarray(pb.inner.reconstruct(
-                all_b, tuple(range(d)), (d + g,)), dtype=np.uint8)[0]
-            a_f = readers[d + g](half + w, wl) ^ p_g
-            for i in grp:
-                if i != f:
-                    a_f = a_f ^ readers[i](w, wl)
-            _pwrite(outs[f], a_f, w)
-            _pwrite(outs[f], b_f, half + w)
-            counter.wrote(2 * wl)
-    finally:
-        for fd in outs.values():
-            os.fsync(fd)
-            os.close(fd)
 
 
 # ---------------------------------------------------------------------------
@@ -542,17 +507,14 @@ def reconstruct_interval(pb: PiggybackCoder, gathered: "dict[int, np.ndarray]",
 
 # ---------------------------------------------------------------------------
 # Codec dispatch: how encoder.rebuild_shards executes each codec's
-# cheapest path. Uniform signatures:
+# cheapest path where that is not one matrix (`coder.repair_linear`,
+# which ec/encoder.py batches itself). Uniform signatures:
 #   ranged(base, coder, f, readers, frag_readers, shard_size, counter)
 #   general(base, coder, present, missing, readers, frag_readers,
 #           shard_size, counter)
 # A codec registered here never falls through to the positional plain-RS
 # rebuild (which would decode its parities as if they were RS).
 # ---------------------------------------------------------------------------
-
-def _pb_single(base, coder, f, readers, frag_readers, shard_size, counter):
-    rebuild_piggyback_single(base, coder, f, readers, shard_size, counter)
-
 
 def _pb_general(base, coder, present, missing, readers, frag_readers,
                 shard_size, counter):
@@ -561,7 +523,7 @@ def _pb_general(base, coder, present, missing, readers, frag_readers,
 
 
 REBUILDERS = {
-    "piggyback": (_pb_single, _pb_general),
+    "piggyback": (None, _pb_general),  # its ranged repair is linear
     "msr": (rebuild_msr_single, rebuild_msr_general),
 }
 

@@ -62,6 +62,26 @@ class ErasureCoder(abc.ABC):
         codecs (ops/piggyback.py) override."""
         return None
 
+    def repair_linear(self, wanted: "tuple[int, ...]", shard_size: int):
+        """Where `repair_plan`'s ranges rebuild `wanted` by ONE GF(2^8)
+        matrix: (matrix [rows_out, len(plan)] over the plan's ranges in
+        the plan's order, targets [(shard_id, offset), ...] saying where
+        each output row lands, engine: the coder whose `apply_matrix`
+        runs it). The rebuild then loads, dispatches, drains and writes
+        it like a plain-RS batch (ec/encoder.py). None where the repair
+        is not one matrix apply over equal-length ranges."""
+        return None
+
+    def apply_matrix(self, mat: np.ndarray, rows: np.ndarray) -> np.ndarray:
+        """Any GF(2^8) matrix `mat` [m, k] over rows [..., k, L] uint8 ->
+        [..., m, L]: what encode and reconstruct are instances of, for
+        codecs whose repair is a matrix plain RS has no name for. The
+        base runs the numpy tables; backends override."""
+        rows = np.asarray(rows, dtype=np.uint8)
+        if rows.ndim == 2:
+            return gf8.np_gf_apply(mat, rows)
+        return np.stack([gf8.np_gf_apply(mat, b) for b in rows])
+
     def verify(self, shards: np.ndarray) -> bool:
         """shards [..., n, L]: recompute parity from data rows and compare."""
         data = shards[..., : self.d, :]
@@ -71,17 +91,11 @@ class ErasureCoder(abc.ABC):
 
 class NumpyCoder(ErasureCoder):
     def encode(self, data: np.ndarray) -> np.ndarray:
-        data = np.asarray(data, dtype=np.uint8)
-        if data.ndim == 2:
-            return gf8.np_encode(data, self.p)
-        return np.stack([gf8.np_encode(b, self.p) for b in data])
+        return self.apply_matrix(gf8.parity_matrix(self.d, self.p), data)
 
     def reconstruct(self, survivors, present, wanted):
-        survivors = np.asarray(survivors, dtype=np.uint8)
         rec = gf8.decode_matrix(self.d, self.p, list(present))[list(wanted), :]
-        if survivors.ndim == 2:
-            return gf8.np_gf_apply(rec, survivors)
-        return np.stack([gf8.np_gf_apply(rec, b) for b in survivors])
+        return self.apply_matrix(rec, survivors)
 
 
 class JaxCoder(ErasureCoder):
@@ -123,6 +137,18 @@ class JaxCoder(ErasureCoder):
         from . import rs_jax
         return rs_jax.reconstruct_jit(
             survivors, tuple(sorted(present)), tuple(wanted), self.d, self.p)
+
+    def apply_matrix(self, mat, rows):
+        """The matrix rides as an OPERAND: one program per shape
+        [B, k, C] -> [B, m, C], whatever the matrix says."""
+        if self.use_pallas:
+            from . import rs_pallas
+            x, squeeze = _as_batch(rows)
+            out = rs_pallas.matrix_apply_jit(
+                x, rs_pallas.matrix_operand(mat), interpret=self._interpret)
+            return out[0] if squeeze else out
+        from . import rs_jax
+        return rs_jax.matrix_apply_jit(rs_jax.matrix_operand(mat), rows)
 
 
 def _as_batch(arr):

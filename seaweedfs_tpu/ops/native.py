@@ -120,5 +120,8 @@ class NativeCoder(ErasureCoder):
         rec = gf8.decode_matrix(self.d, self.p, list(present))[list(wanted), :]
         return _apply(rec, survivors)
 
+    def apply_matrix(self, mat, rows):
+        return _apply(mat, rows)
+
 
 register_coder("native", NativeCoder)

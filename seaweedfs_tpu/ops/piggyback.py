@@ -30,6 +30,14 @@ Single data-shard repair (shard f in group S_g) reads *byte ranges*:
      -> a_f = pb_g XOR P_g(b) XOR (XOR_{i in S_g, i != f} a_i),
      where P_g(b) is recomputed from the now-complete b substripe.
 
+Both steps are linear over GF(2^8) in the (d + |S_g|) half-shard ranges
+read, so the repair is ONE matrix: [a_f; b_f] = M . x (`repair_matrix`).
+b_f is row f of the RS decode matrix over the b-halves of step 1; P_g(b)
+is row d+g of the encode matrix over the b substripe, whose one unknown
+b_f is that same row again, and the XOR terms are coefficients 1. A
+rebuild applies M to [batch, d + |S_g|, chunk] slabs like any plain-RS
+batch (ec/encoder.py), one program a batch and nothing between the steps.
+
 Total: (d + |S_g|) half-shards = (d + |S_g|) / (2d) of the plain-RS
 cost. With RS(10, 4) and groups of ceil(10/3): 0.65-0.70x. With p = 2
 the only group is all of [d] and the plan degenerates to the trivial
@@ -39,13 +47,15 @@ codec "rs" unless asked.
 
 All heavy GF(2^8) math rides the *inner* coder (numpy / jax / pallas /
 native), so the piggyback layer works on every backend: it only adds
-XORs and bookkeeping on top of the existing bit-matmul kernels.
+XORs and bookkeeping on top of the existing bit-matmul kernels, and the
+single-shard repair not even those (`inner.apply_matrix`).
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from . import gf8
 from .coder import ErasureCoder, get_coder, register_coder
 
 
@@ -173,6 +183,30 @@ class PiggybackCoder(ErasureCoder):
         half = shard_size // 2
         return ([(s, half, half) for s in need_b]
                 + [(s, 0, half) for s in need_a])
+
+    def repair_matrix(self, f: int) -> np.ndarray:
+        """[2, d + |S_g|] over `repair_plan`'s ranges in the plan's order
+        (b-halves of the other data shards, of parity 0 and of the
+        piggybacked parity d+g, then a-halves of the group's others):
+        row 0 gives a_f, row 1 gives b_f."""
+        d = self.d
+        g, grp = self.group_of(f)
+        present_b = [i for i in range(d) if i != f] + [d]
+        dec = gf8.decode_matrix(d, self.p, present_b)[f]      # b_f over step 1
+        par = gf8.encode_matrix(d, self.p)[d + g]             # P_g over b
+        m = np.zeros((2, d + len(grp)), dtype=np.uint8)
+        m[1, :d] = dec
+        # P_g(b) = sum_{i != f} par[i] b_i + par[f] b_f, b_f = dec . x
+        m[0, :d] = gf8.GF_MUL[par[f], dec]
+        m[0, :d - 1] ^= par[present_b[:-1]]
+        m[0, d:] = 1      # pb_g's b-half and the group's a-halves
+        m.setflags(write=False)
+        return m
+
+    def repair_linear(self, wanted, shard_size: int):
+        f = wanted[0]
+        half = shard_size // 2
+        return self.repair_matrix(f), [(f, 0), (f, half)], self.inner
 
 
 def _register():

@@ -78,6 +78,11 @@ def _decode_bitmatrix(d: int, p: int, present: tuple[int, ...], wanted: tuple[in
     return m
 
 
+def matrix_operand(mat: np.ndarray) -> np.ndarray:
+    """A GF(2^8) matrix [m, k] as `matrix_apply_jit`'s bit-matrix operand."""
+    return gf8.expand_to_bits(mat).astype(np.int8)
+
+
 def encode(data: jax.Array, d: int, p: int) -> jax.Array:
     """data [..., d, L] uint8 -> parity [..., p, L] uint8."""
     if data.shape[-2] != d:
@@ -110,3 +115,11 @@ def encode_jit(data: jax.Array, d: int, p: int) -> jax.Array:
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
 def reconstruct_jit(survivors, present, wanted, d, p):
     return reconstruct(survivors, present, wanted, d, p)
+
+
+@jax.jit
+def matrix_apply_jit(bmat: jax.Array, data: jax.Array) -> jax.Array:
+    """`apply_bitmatrix` with the matrix as an operand (`matrix_operand`):
+    one program a shape, for repairs that are a matrix plain RS does not
+    name (ops/piggyback.py)."""
+    return apply_bitmatrix(bmat, data)

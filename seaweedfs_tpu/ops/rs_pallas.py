@@ -63,27 +63,41 @@ def available() -> bool:
     return device.info().platform == "tpu"
 
 
-@functools.lru_cache(maxsize=512)
-def _plane_major_bitmatrix(key: tuple) -> np.ndarray:
-    """Permute a [8m, 8d] byte-major bit-matrix to plane-major padded cols.
-
-    key = (kind, d, p, present, wanted); column s*dp + r takes byte-major
-    column r*8 + s (dp = d rounded up to 4 for the sublane bitcast).
-    """
-    kind, d, p, present, wanted = key
-    if kind == "enc":
-        bm = gf8.expand_to_bits(gf8.parity_matrix(d, p)).astype(np.int8)
-    else:
-        rec = gf8.decode_matrix(d, p, list(present))
-        bm = gf8.expand_to_bits(rec[list(wanted), :]).astype(np.int8)
-    m8 = bm.shape[0]
-    dp = (d + 3) // 4 * 4
-    out = np.zeros((m8, 8 * dp), dtype=np.int8)
-    for r in range(d):
+def _plane_major(mat: np.ndarray) -> np.ndarray:
+    """A GF(2^8) matrix [m, k] as the kernel's bit-matrix [8m, 8*kp]:
+    byte-major bit column r*8 + s goes to plane-major column s*kp + r
+    (kp = k rounded up to 4 for the sublane bitcast; the columns of the
+    padding rows stay zero)."""
+    bm = gf8.expand_to_bits(mat).astype(np.int8)
+    k = mat.shape[1]
+    kp = (k + 3) // 4 * 4
+    out = np.zeros((bm.shape[0], 8 * kp), dtype=np.int8)
+    for r in range(k):
         for s in range(8):
-            out[:, s * dp + r] = bm[:, r * 8 + s]
+            out[:, s * kp + r] = bm[:, r * 8 + s]
     out.setflags(write=False)
     return out
+
+
+@functools.lru_cache(maxsize=512)
+def _plane_major_bitmatrix(key: tuple) -> np.ndarray:
+    """The bit-matrix of key = (kind, d, p, present, wanted): the parity
+    rows, or the decode rows of `wanted` over the survivors `present`."""
+    kind, d, p, present, wanted = key
+    if kind == "enc":
+        return _plane_major(gf8.parity_matrix(d, p))
+    return _plane_major(gf8.decode_matrix(d, p, list(present))[list(wanted), :])
+
+
+def matrix_operand(mat: np.ndarray) -> np.ndarray:
+    """Any GF(2^8) matrix [m, k] as `matrix_apply_jit`'s operand."""
+    mat = np.ascontiguousarray(mat, dtype=np.uint8)
+    return _matrix_operand(mat.shape, mat.tobytes())
+
+
+@functools.lru_cache(maxsize=64)
+def _matrix_operand(shape: tuple, raw: bytes) -> np.ndarray:
+    return _plane_major(np.frombuffer(raw, dtype=np.uint8).reshape(shape))
 
 
 @functools.lru_cache(maxsize=64)
@@ -124,10 +138,12 @@ def _make_kernel(d: int, dp: int, tile: int):
     return kernel
 
 
-def _apply(bmat_key: tuple, data: jax.Array, seed: jax.Array, tile: int,
+def _apply(bmat, data: jax.Array, seed: jax.Array, tile: int,
            interpret: bool) -> jax.Array:
+    """The kernel under the plane-major bit-matrix `bmat`: a numpy array
+    is baked into the program as a constant (one program per matrix and
+    shape), a traced array rides as an operand."""
     b, d, c = data.shape
-    bmat = _plane_major_bitmatrix(bmat_key)
     m = bmat.shape[0] // 8
     packm = _pack_matrix(m)
     dp = (d + 3) // 4 * 4
@@ -164,8 +180,8 @@ def _apply(bmat_key: tuple, data: jax.Array, seed: jax.Array, tile: int,
 def encode_jit(data: jax.Array, d: int, p: int, tile: int = DEFAULT_TILE,
                interpret: bool = False) -> jax.Array:
     """data [B, d, C] uint8 -> parity [B, p, C] uint8 (Pallas kernel)."""
-    return _apply(("enc", d, p, (), ()), data, jnp.zeros(1, jnp.int32),
-                  tile, interpret)
+    return _apply(_plane_major_bitmatrix(("enc", d, p, (), ())), data,
+                  jnp.zeros(1, jnp.int32), tile, interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
@@ -174,4 +190,17 @@ def reconstruct_jit(survivors: jax.Array, present: tuple, wanted: tuple,
                     interpret: bool = False) -> jax.Array:
     """survivors [B, d, C] (rows = sorted(present)[:d]) -> [B, |wanted|, C]."""
     key = ("rec", d, p, tuple(sorted(present)[:d]), tuple(wanted))
-    return _apply(key, survivors, jnp.zeros(1, jnp.int32), tile, interpret)
+    return _apply(_plane_major_bitmatrix(key), survivors,
+                  jnp.zeros(1, jnp.int32), tile, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def matrix_apply_jit(rows: jax.Array, bmat: jax.Array,
+                     tile: int = DEFAULT_TILE,
+                     interpret: bool = False) -> jax.Array:
+    """rows [B, k, C] under the matrix `bmat` (`matrix_operand` of an
+    [m, k] GF(2^8) matrix) -> [B, m, C]: the same kernel with the matrix
+    as an OPERAND, so one program serves every matrix of a shape (a
+    codec's repair matrices, ops/piggyback.py); the plain-RS entries
+    above keep their matrices baked in and their programs."""
+    return _apply(bmat, rows, jnp.zeros(1, jnp.int32), tile, interpret)

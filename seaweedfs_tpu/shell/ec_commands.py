@@ -261,15 +261,21 @@ def _spread_and_clean(env: CommandEnv, vid: int, collection: str, srv: dict,
 
 @command("ec.rebuild", "[-volumeId N] [-byRebuild]: restore missing ec "
          "shards (geometry and codec follow each volume's sealed .vif, "
-         "never a fixed RS default)", needs_lock=True)
+         "never a fixed RS default; a piggyback volume's single lost data "
+         "shard is rebuilt from the byte ranges of its repair plan, batched "
+         "through the rebuild host's coder like a plain-RS rebuild)",
+         needs_lock=True)
 def cmd_ec_rebuild(env: CommandEnv, args):
     """Rebuild runs ON a holder; remote survivors stream in by RANGE —
     or as packed computed fragments through VolumeEcShardRead's
     ranged-compute mode — following the volume's codec repair plan: a
-    piggybacked stripe moves ~(d+|group|)/2 half-shards for a single
-    data-shard loss, an msr stripe (n-1)/p shard-equivalents for ANY
-    single loss, where the old gather-then-rebuild flow copied d full
-    shard files before reconstructing anything. Returns
+    piggybacked stripe moves (d+|group|) half-shards for a single
+    data-shard loss and rebuilds from them as ONE GF(2^8) matrix apply
+    over [batch, d+|group|, chunk] slabs, under the same loaders, pipe
+    and stages (read / dispatch / drain / write) as a plain-RS rebuild;
+    an msr stripe moves (n-1)/p shard-equivalents for ANY single loss,
+    where the old gather-then-rebuild flow copied d full shard files
+    before reconstructing anything. Returns
     {rebuilt, bytes_read, bytes_written} so callers (cluster.repair)
     can journal the traffic."""
     p = argparse.ArgumentParser(prog="ec.rebuild")

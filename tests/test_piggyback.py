@@ -534,7 +534,20 @@ def test_ranged_rebuild_rpc_end_to_end(tmp_path_factory):
         fins = [e for e in events.JOURNAL.snapshot(
             since=since, etype="ec.rebuild.finish")]
         assert fins and fins[-1]["attrs"]["bytes_read"] == resp.bytes_read
-        assert fins[-1]["attrs"]["codec"] == "piggyback"
+        fin = fins[-1]["attrs"]
+        assert fin["codec"] == "piggyback" and fin["repair_path"] == "ranged"
+        # the rebuild's own stage account, as a plain-RS rebuild's: the
+        # four stages partition the RPC, the loads are booked beside
+        # `read_s` by kind, and nothing ran under a stage `codec`
+        for key in ("read_s", "dispatch_s", "drain_s", "write_s",
+                    "read_busy_s", "read_local_busy_s",
+                    "read_remote_busy_s", "batches", "duration_ms"):
+            assert key in fin, key
+        assert "codec_s" not in fin and fin["batches"] >= 1
+        four = (fin["read_s"] + fin["dispatch_s"] + fin["drain_s"]
+                + fin["write_s"])
+        assert 0 < four <= fin["duration_ms"] / 1e3 + 0.002
+        assert fin["read_local_busy_s"] > 0 < fin["read_remote_busy_s"]
 
         # -- degraded reads through a piggybacked parity --------------------
         # lose data shard 3 AND the unpiggybacked parity 4 (both on
