@@ -23,7 +23,7 @@ from typing import Sequence
 import numpy as np
 
 from ..ops.coder import ErasureCoder
-from . import files
+from . import buffers, files
 from .locate import EcGeometry
 
 DEFAULT_CHUNK = 1 << 20   # device slab length per stripe row
@@ -71,7 +71,9 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
     Survivors may live elsewhere: `shard_reader(sid, offset, length)`
     (ec/volume.py contract -> VolumeEcShardRead) serves the ids listed in
     `remote_shards` by RANGE, so a repair-efficient codec's plan fetches
-    byte ranges off the network instead of d full shards;
+    byte ranges off the network instead of d full shards (a reader that
+    also has `readinto`, ec/repair.py, lands them straight in the batched
+    rebuild's rows);
     `fragment_reader(sid, ranges)` additionally lets a survivor holder
     gather scattered ranges server-side and ship ONE computed fragment
     (the MSR codec's beta-fragments ride this). `fold_planner(coder, f)
@@ -109,27 +111,29 @@ def rebuild_shards(base: str, geo: EcGeometry, coder: ErasureCoder,
         from . import repair
         counter = repair.RepairCounter(coder.codec)
         acct = tracing.StageAccount("rebuild", _REBUILD_STAGES)
-        readers, frag_readers, close = repair.make_readers(
+        readers, loaders, frag_readers, close = repair.make_readers(
             base, present_local, shard_reader, remote, counter,
             fragment_reader=fragment_reader)
         try:
-            path = _dispatch_rebuild(base, geo, coder, tuple(sorted(present)),
-                                     missing, readers, frag_readers,
-                                     shard_size, chunk, batch, counter, acct,
-                                     fold_planner=fold_planner,
-                                     local_sids=frozenset(present_local))
+            path, warm = _dispatch_rebuild(
+                base, geo, coder, tuple(sorted(present)), missing, readers,
+                loaders, frag_readers, shard_size, chunk, batch, counter,
+                acct, fold_planner=fold_planner,
+                local_sids=frozenset(present_local))
         finally:
             close()
         sp.set_attr("bytes_read", counter.bytes_read)
         sp.set_attr("bytes_written", counter.bytes_written)
         sp.set_attr("path", path)
+        sp.set_attr("warm_batches", warm)
         acct.publish(sp)
         if stats is not None:
             stats.update(bytes_read=counter.bytes_read,
                          bytes_written=counter.bytes_written,
                          codec=coder.codec, path=path,
                          shard_size=shard_size,
-                         batches=acct.count("dispatch"), **acct.fields())
+                         batches=acct.count("dispatch"), warm_batches=warm,
+                         **acct.fields())
         return missing
 
 
@@ -166,16 +170,19 @@ def _shard_size(base: str, geo: EcGeometry,
 
 def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
                       present: tuple, missing: list[int], readers: dict,
-                      frag_readers: dict, shard_size: int, chunk: int,
-                      batch: int, counter, acct, fold_planner=None,
-                      local_sids: frozenset = frozenset()) -> str:
+                      loaders: dict, frag_readers: dict, shard_size: int,
+                      chunk: int, batch: int, counter, acct,
+                      fold_planner=None,
+                      local_sids: frozenset = frozenset()) -> "tuple[str, int]":
     """Pick the cheapest reconstruction the codec supports. A repair that
     is one matrix over the ranges it reads (plain RS over d survivors; a
     codec's `repair_linear`) is a row source and a matrix for
-    `_rebuild_batched`; the rest resolve through the repair.REBUILDERS
-    registry, so a new codec plugs in its executors without touching this
+    `_rebuild_batched`, which takes the survivors' `loaders`; the rest
+    resolve through the repair.REBUILDERS registry and take `readers`,
+    so a new codec plugs in its executors without touching this
     dispatch. Returns the path taken ("ranged" | "general" | "full" |
-    "ranged-folded") for stats/traces."""
+    "ranged-folded") and the batches staged in warm buffers, for
+    stats/traces."""
     from . import repair
     ranged, general = repair.REBUILDERS.get(coder.codec, (None, None))
     plan = coder.repair_plan(present, tuple(missing), shard_size)
@@ -184,12 +191,12 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
     if linear is not None:
         matrix, targets, engine = linear
         (extent,) = {ln for _, _, ln in plan}
-        _rebuild_batched(base, type(coder).__name__,
-                         [(sid, off) for sid, off, _ in plan], extent,
-                         targets, lambda arr: engine.apply_matrix(matrix, arr),
-                         readers, shard_size, chunk, batch, counter, acct,
-                         local_sids)
-        return "ranged"
+        warm = _rebuild_batched(
+            base, type(coder).__name__,
+            [(sid, off) for sid, off, _ in plan], extent, targets,
+            lambda arr: engine.apply_matrix(matrix, arr), loaders,
+            shard_size, chunk, batch, counter, acct, local_sids)
+        return "ranged", warm
     if (plan is not None and ranged is not None) or general is not None:
         readers = {s: acct.timed("read", r) for s, r in readers.items()}
         frag_readers = {s: acct.timed("read", r)
@@ -205,30 +212,30 @@ def _dispatch_rebuild(base: str, geo: EcGeometry, coder: ErasureCoder,
             if folds:
                 ranged(base, coder, missing[0], readers, frag_readers,
                        shard_size, counter, folds=folds)
-                return "ranged-folded"
+                return "ranged-folded", 0
             ranged(base, coder, missing[0], readers, frag_readers,
                    shard_size, counter)
-            return "ranged"
+            return "ranged", 0
     if general is not None:
         with acct.stage("codec"):
             general(base, coder, present, missing, readers, frag_readers,
                     shard_size, counter)
-        return "general"
+        return "general", 0
     # plain RS: positional reconstruct over the first d survivors, whole
     use = tuple(sorted(present)[:geo.d])
     wanted = tuple(missing)
-    _rebuild_batched(base, type(coder).__name__, [(sid, 0) for sid in use],
-                     shard_size, [(m, 0) for m in missing],
-                     lambda arr: coder.reconstruct(arr, use, wanted),
-                     readers, shard_size, chunk, batch, counter, acct,
-                     local_sids)
-    return "full"
+    warm = _rebuild_batched(
+        base, type(coder).__name__, [(sid, 0) for sid in use], shard_size,
+        [(m, 0) for m in missing],
+        lambda arr: coder.reconstruct(arr, use, wanted), loaders,
+        shard_size, chunk, batch, counter, acct, local_sids)
+    return "full", warm
 
 
 def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
-                     targets: list, apply, readers: dict, shard_size: int,
+                     targets: list, apply, loaders: dict, shard_size: int,
                      chunk: int, batch: int, counter, acct,
-                     local_sids: frozenset) -> None:
+                     local_sids: frozenset) -> int:
     """One matrix over [batch, len(sources), chunk] slabs (device-batched
     like encode). Row r of a slab is `extent` bytes of survivor
     sources[r] = (shard_id, offset); `apply(slab)` gives one row per
@@ -236,11 +243,20 @@ def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
     from that offset on. Plain RS walks d whole survivors into the lost
     shards; a codec's ranged repair walks the ranges of its plan. A
     batch's rows load side by side, one task each: the batch waits for
-    its slowest survivor, not for their sum. The last batch is dispatched
-    whole and never padded from a survivor: its last slab is filled up
-    with zeros made here, and the slabs past it hold what the buffer held
-    (zeros, or an earlier batch's rows), are computed and dropped — every
-    column is on its own, so nothing of them reaches a shard file."""
+    its slowest survivor, not for their sum.
+
+    Whose the buffers are: this rebuild's for as long as it runs, leased
+    from `buffers.REBUILD`, which keeps them for the next rebuild (of any
+    shape they have the bytes for) and drops them when none has come for
+    `buffers.IDLE_DROP_S`. They arrive DIRTY, and the read path allocates
+    nothing a batch: each loader puts its survivor's bytes straight into
+    its rows (`loaders[sid](offset, length, rows)`, ec/repair.py). The
+    last batch is dispatched whole and never padded from a survivor: the
+    tail of its last slab is zeroed here, and the slabs past it hold what
+    the buffer held (an earlier batch's or an earlier rebuild's rows),
+    are computed and dropped — every column is on its own, so nothing of
+    them reaches a shard file. Returns how many batches went through
+    memory an earlier rebuild had already written (`warm_batches`)."""
     for name in _READ_BUSY:  # the fields exist though a kind has no load
         acct.add(name, 0.0, n=0)
     outs = {}
@@ -253,8 +269,7 @@ def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
                                 shape=(shard_size,))
 
     from ..stats import EC_REBUILD_BYTES
-    from .stream import AsyncPipe
-    pipe = AsyncPipe((batch, len(sources), chunk), acct)
+    from .stream import DEFAULT_DEPTH, AsyncPipe
 
     def drain(rebuilt: np.ndarray, ctx) -> None:
         off, span, nb = ctx
@@ -267,14 +282,13 @@ def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
     def load(arr: np.ndarray, r: int, sid: int, off: int, span: int,
              nb: int) -> None:
         """One survivor's range into its rows of the batch (disjoint from
-        every other task's): one strided copy."""
+        every other task's), and zeros behind a range that ends inside
+        its last row."""
         t0 = time.perf_counter()
-        row = readers[sid](off, span)
+        rows = arr[:nb, r]
+        loaders[sid](off, span, rows)
         if span < nb * chunk:
-            padded = np.zeros(nb * chunk, dtype=np.uint8)
-            padded[:span] = row
-            row = padded
-        arr[:nb, r] = row.reshape(nb, chunk)
+            rows[nb - 1, span - (nb - 1) * chunk:] = 0
         # booked from the worker: no stage is open on this thread, so
         # nothing is taken out of the caller's `read`
         busy = time.perf_counter() - t0
@@ -282,34 +296,47 @@ def _rebuild_batched(base: str, coder_name: str, sources: list, extent: int,
         acct.add("read_local_busy" if sid in local_sids
                  else "read_remote_busy", busy)
 
-    loaders = ThreadPoolExecutor(max_workers=len(sources),
-                                 thread_name_prefix="ec-rebuild-read")
+    shape = (batch, len(sources), chunk)
+    slab = len(sources) * chunk
+    warm = 0
+    pool = ThreadPoolExecutor(max_workers=len(sources),
+                              thread_name_prefix="ec-rebuild-read")
     try:
-        for n, off in enumerate(range(0, extent, chunk * batch)):
-            span = min(chunk * batch, extent - off)
-            nb = (span + chunk - 1) // chunk
-            arr = pipe.next_buffer()
-            with acct.stage("read", batch=n):
-                # each task under a copy of this thread's context: the
-                # QoS class rides a remote read to its holder, and the
-                # fetch spans keep `ec.rebuild` as their parent
-                loads = [loaders.submit(contextvars.copy_context().run, load,
-                                        arr, r, sid, at + off, span, nb)
-                         for r, (sid, at) in enumerate(sources)]
-                for task in loads:
-                    task.result()
-            EC_REBUILD_BYTES.inc(coder_name, amount=arr.nbytes)
-            with acct.stage("dispatch", batch=n):
-                fut = apply(arr)
-            pipe.submit(fut, (off, span, nb), drain)
+        with buffers.REBUILD.lease(shape, DEFAULT_DEPTH + 2) as held:
+            was_warm = list(held.touched)
+            pipe = AsyncPipe(shape, acct, buffers=held.views(shape))
+            for n, off in enumerate(range(0, extent, chunk * batch)):
+                span = min(chunk * batch, extent - off)
+                nb = (span + chunk - 1) // chunk
+                arr = pipe.next_buffer()
+                slot = n % len(was_warm)  # the pipe hands them out in turn
+                warm += int(nb * slab <= was_warm[slot])
+                held.touched[slot] = max(held.touched[slot], nb * slab)
+                with acct.stage("read", batch=n):
+                    # each task under a copy of this thread's context: the
+                    # QoS class rides a remote read to its holder, and the
+                    # fetch spans keep `ec.rebuild` as their parent
+                    loads = [pool.submit(contextvars.copy_context().run,
+                                         load, arr, r, sid, at + off, span,
+                                         nb)
+                             for r, (sid, at) in enumerate(sources)]
+                    for task in loads:
+                        task.result()
+                EC_REBUILD_BYTES.inc(coder_name, amount=arr.nbytes)
+                with acct.stage("dispatch", batch=n):
+                    fut = apply(arr)
+                pipe.submit(fut, (off, span, nb), drain)
+            # every batch drained: nothing reads the buffers any more
+            pipe.flush()
     finally:
         # waits: after an error too every load has ended before the
-        # caller closes the survivors' fds
-        loaders.shutdown()
-    pipe.flush()
+        # caller closes the survivors' fds (and the set, never handed
+        # back then, goes with the last load that wrote into it)
+        pool.shutdown()
     with acct.stage("write"):
         for o in outs.values():
             o.flush()
+    return warm
 
 
 def decode_volume(base: str, dat_out: str, geo: EcGeometry,

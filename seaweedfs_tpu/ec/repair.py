@@ -42,6 +42,14 @@ REPAIR_WINDOW = 4 << 20
 # shard_reader(shard_id, offset, length) -> bytes (ec/volume.py contract)
 ShardReader = Callable[[int, int, int], bytes]
 
+# A shard_reader may also have the landing form, as a file has `readinto`
+# beside `read`: shard_reader.readinto(shard_id, offset, length, rows) ->
+# bytes landed, where `rows` is a writable [n, width] uint8 array whose
+# rows are each contiguous (one survivor's rows of a rebuild's batch) and
+# the range goes to rows.reshape(-1)[:length] as `land` puts it there. A
+# rebuild then takes a remote survivor's bytes off the wire straight into
+# its batch; a reader without it is asked for `bytes` and those are copied.
+
 # fragment_reader(shard_id, [(offset, length), ...]) -> bytes: the
 # ranged-compute shard read — the holder gathers the scattered ranges
 # server-side and ships ONE packed fragment (VolumeEcShardRead with
@@ -78,17 +86,45 @@ class RepairCounter:
             pass
 
 
+def land(rows: np.ndarray, pos: int, data, limit: int) -> int:
+    """Copy `data` to byte `pos` of the range that fills `rows` ([n,
+    width], each row contiguous) row after row, whatever `data`'s length
+    is to a row's; bytes from `limit` on are dropped. Returns the position
+    after `data`, dropped bytes counted too, so a stream that is too long
+    shows as one."""
+    width = rows.shape[1]
+    src = np.frombuffer(data, dtype=np.uint8)
+    end = pos + len(src)
+    src = src[:max(0, limit - pos)]
+    while len(src):
+        k, at = divmod(pos, width)
+        n = min(width - at, len(src))
+        rows[k, at:at + n] = src[:n]
+        src = src[n:]
+        pos += n
+    return end
+
+
 def make_readers(base: str, present_local: "dict[int, str]",
                  shard_reader: "ShardReader | None",
                  remote_sids, counter: RepairCounter,
                  fragment_reader: "FragmentReader | None" = None,
-                 ) -> "tuple[dict[int, Callable[[int, int], np.ndarray]], dict[int, Callable], Callable[[], None]]":
-    """(readers, frag_readers, close): per-shard `read(offset, length)`
-    and `frag(ranges) -> concatenated uint8 array` over local files and
-    remote fetches, every byte counted. Fragment reads of local shards
-    are gathered preads; remote ones go through the holder's ranged-
-    compute mode when the caller wires `fragment_reader`, else degrade
-    to one ranged fetch per run."""
+                 ) -> "tuple[dict[int, Callable[[int, int], np.ndarray]], dict[int, Callable], dict[int, Callable], Callable[[], None]]":
+    """(readers, loaders, frag_readers, close): per-shard `read(offset,
+    length) -> uint8 array`, `load(offset, length, rows)` and
+    `frag(ranges) -> concatenated uint8 array` over local files and
+    remote fetches, every byte counted once. Fragment reads of local
+    shards are gathered preads; remote ones go through the holder's
+    ranged-compute mode when the caller wires `fragment_reader`, else
+    degrade to one ranged fetch per run.
+
+    `read` returns memory of its own (the degraded read, the codecs'
+    windowed executors). `load` is the batched rebuild's: the range lands
+    in `rows`, the caller's [n, width] view of one survivor's rows of a
+    batch, as `land` lays it out, and nothing else is allocated: a local
+    survivor by one `preadv` over the rows, a remote one through the
+    reader's `readinto` where it has one. The rows stay the caller's;
+    a load that fails may have written any part of them."""
     fds: dict[int, int] = {}
 
     def local(sid: int):
@@ -99,6 +135,17 @@ def make_readers(base: str, present_local: "dict[int, str]",
             counter.read(ln)
             return np.frombuffer(buf, dtype=np.uint8)
         return read
+
+    def local_load(sid: int):
+        def load(off: int, ln: int, rows: np.ndarray) -> None:
+            full, rest = divmod(ln, rows.shape[1])
+            into = [rows[k] for k in range(full)]
+            if rest:
+                into.append(rows[full, :rest])
+            if os.preadv(fds[sid], into, off) != ln:
+                raise OSError(f"short read of shard {sid} at {off}")
+            counter.read(ln)
+        return load
 
     def local_frag(sid: int):
         def frag(ranges) -> np.ndarray:
@@ -123,6 +170,19 @@ def make_readers(base: str, present_local: "dict[int, str]",
             return np.frombuffer(buf, dtype=np.uint8)
         return read
 
+    def remote_load(sid: int):
+        readinto = getattr(shard_reader, "readinto", None)
+
+        def load(off: int, ln: int, rows: np.ndarray) -> None:
+            if readinto is not None:
+                got = readinto(sid, off, ln, rows)
+            else:
+                got = land(rows, 0, shard_reader(sid, off, ln), ln)
+            if got != ln:
+                raise OSError(f"short remote read of shard {sid} at {off}")
+            counter.read(ln)
+        return load
+
     def remote_frag(sid: int):
         def frag(ranges) -> np.ndarray:
             want = sum(ln for _, ln in ranges)
@@ -146,14 +206,17 @@ def make_readers(base: str, present_local: "dict[int, str]",
         return frag
 
     readers: dict[int, Callable] = {}
+    loaders: dict[int, Callable] = {}
     frag_readers: dict[int, Callable] = {}
     for sid, path in present_local.items():
         fds[sid] = os.open(path, os.O_RDONLY)
         readers[sid] = local(sid)
+        loaders[sid] = local_load(sid)
         frag_readers[sid] = local_frag(sid)
     for sid in remote_sids or ():
         if sid not in readers and shard_reader is not None:
             readers[sid] = remote(sid)
+            loaders[sid] = remote_load(sid)
             frag_readers[sid] = remote_frag(sid)
 
     def close() -> None:
@@ -163,7 +226,7 @@ def make_readers(base: str, present_local: "dict[int, str]",
             except OSError:
                 log.debug("closing survivor fd under %s failed", base,
                           exc_info=True)
-    return readers, frag_readers, close
+    return readers, loaders, frag_readers, close
 
 
 def _open_outputs(base: str, missing, shard_size: int) -> "dict[int, int]":

@@ -308,17 +308,31 @@ class AsyncPipe:
     completion callback `release(buf)`s it; `next_buffer` blocks until the
     slot's hold count is zero. That blocking is booked as `write_block` —
     it shows up as writer backpressure in the pipeline stats.
+
+    Who owns the buffers: the pipe makes its own `depth + 2`, zeroed, and
+    they live as long as it does (the seal's feed). A caller that keeps
+    buffers longer than one pipe (the rebuild: ec/buffers.py) passes its
+    own `buffers`, `depth + 2` arrays of `shape` holding whatever they
+    hold; the pipe cycles through them and never keeps them: they are the
+    caller's again once `flush` has returned.
     """
 
-    def __init__(self, shape: tuple, acct, depth: int = DEFAULT_DEPTH):
+    def __init__(self, shape: tuple, acct, depth: int = DEFAULT_DEPTH,
+                 buffers: "list[np.ndarray] | None" = None):
         # the operation's StageAccount: each blocking fetch is one stage
         # `drain` (annotated with the batch number), a wait for writers
         # still reading a buffer is booked as `write_block`
         self._acct = acct
         self._drained = 0
         self.depth = depth
-        self.pool = [np.zeros(shape, dtype=np.uint8)
-                     for _ in range(depth + 2)]
+        if buffers is None:
+            buffers = [np.zeros(shape, dtype=np.uint8)
+                       for _ in range(depth + 2)]
+        if len(buffers) != depth + 2 or \
+                any(b.shape != tuple(shape) for b in buffers):
+            raise ValueError(f"AsyncPipe needs {depth + 2} buffers of "
+                             f"{tuple(shape)}")
+        self.pool = buffers
         self.pending: deque = deque()
         self._slot = 0
         self._holds = [0] * len(self.pool)

@@ -16,6 +16,7 @@ import time
 import urllib.parse
 
 from ..ec import files as ec_files
+from ..ec import repair as ec_repair
 from ..ec.encoder import rebuild_shards
 from ..ec.locate import EcGeometry
 from ..pb import master_pb2 as mpb
@@ -1779,7 +1780,12 @@ class VolumeServer:
     # -- EC shard reader: remote fetch + degraded reconstruct ---------------
     def _fetch_remote_shard(self, vid: int, sid: int, offset: int,
                             length: int, holders: "list[str]",
-                            include_open: bool = False) -> bytes | None:
+                            include_open: bool = False,
+                            into=None) -> "bytes | int | None":
+        """The range as `bytes`, or, with `into` (the [n, width] rows of
+        ec/repair.py's `readinto` contract), landed there message by
+        message and returned as the count of bytes the stream carried.
+        None: no holder served it."""
         # one span per shard fetch: a degraded read's trace shows every
         # attempted shard as a child, INCLUDING the failed/missing ones
         # (status=error with the per-holder failures as events)
@@ -1789,7 +1795,8 @@ class VolumeServer:
                 attrs={"vid": vid, "shard": sid, "offset": offset,
                        "length": length, "holders": len(holders)}) as sp:
             data = self._fetch_remote_shard_inner(vid, sid, offset, length,
-                                                  holders, include_open, sp)
+                                                  holders, include_open, sp,
+                                                  into)
             if data is None:
                 sp.set_error("no holder served shard"
                              if holders else "shard has no holders")
@@ -1798,7 +1805,7 @@ class VolumeServer:
     def _fetch_remote_shard_inner(self, vid: int, sid: int, offset: int,
                                   length: int, holders: "list[str]",
                                   include_open: bool,
-                                  sp) -> bytes | None:
+                                  sp, into=None) -> "bytes | int | None":
         try:
             # failpoint: shard fetch failure -> the caller's degraded
             # reconstruct-from-d-others path, without destroying a shard
@@ -1827,18 +1834,34 @@ class VolumeServer:
             br = retry.breaker(addr)
             try:
                 stub = Stub(addr, VOLUME_SERVICE)
-                parts = [r.data for r in stub.call_stream(
+                stream = stub.call_stream(
                     "VolumeEcShardRead",
                     vpb.VolumeEcShardReadRequest(
                         volume_id=vid, shard_id=sid,
                         offset=offset, size=length),
-                    vpb.VolumeEcShardReadResponse)]
+                    vpb.VolumeEcShardReadResponse)
+                if into is None:
+                    data = b"".join([r.data for r in stream])
+                else:
+                    # each message goes to its place in the caller's rows
+                    # as it arrives: no list of parts, no joined copy. A
+                    # holder that failed part way is overwritten from the
+                    # range's start by the next one
+                    got = 0
+                    for r in stream:
+                        got = ec_repair.land(into, got, r.data, length)
                 br.record_success()
                 sp.set_attr("holder", addr)
                 # corrupt site: bit-flips on the shard wire — the needle
                 # CRC downstream must catch what reconstruction produces
-                return failpoints.corrupt("ec.shard.read.data",
-                                          b"".join(parts))
+                if into is None:
+                    return failpoints.corrupt("ec.shard.read.data", data)
+                if failpoints.armed("ec.shard.read.data"):
+                    n = min(got, length)
+                    ec_repair.land(into, 0, failpoints.corrupt(
+                        "ec.shard.read.data",
+                        into.reshape(-1)[:n].tobytes()), n)
+                return got
             except Exception as e:  # noqa: BLE001
                 br.record_failure()
                 sp.add_event("holder_failed", peer=addr,
@@ -2159,6 +2182,16 @@ class VolumeServer:
                 _book(link, len(data))
             return data
 
+        def readinto(sid: int, offset: int, length: int, rows) -> int:
+            got = self._fetch_range_or_raise(vid, sid, offset, length,
+                                             peers.get(sid, []), into=rows)
+            link = _link_of(sid)
+            if link:
+                _book(link, got)
+            return got
+        # the landing form of ec/repair.py's shard_reader contract
+        reader.readinto = readinto
+
         def fragment_reader(sid: int, ranges) -> bytes:
             buf = self._fetch_fragment_or_raise(vid, sid, ranges,
                                                 peers.get(sid, []))
@@ -2251,14 +2284,18 @@ class VolumeServer:
         return reader, fragment_reader, remote, fold_planner
 
     def _fetch_range_or_raise(self, vid: int, sid: int, offset: int,
-                              length: int, holders: "list[str]") -> bytes:
+                              length: int, holders: "list[str]",
+                              into=None) -> "bytes | int":
         """One ranged fetch with the shared fallback discipline: healthy
         holders first, then circuit-open ones as a last resort (latency
-        beats failing a repair or a recoverable read), else OSError."""
-        data = self._fetch_remote_shard(vid, sid, offset, length, holders)
+        beats failing a repair or a recoverable read), else OSError.
+        `into`: as `_fetch_remote_shard`."""
+        data = self._fetch_remote_shard(vid, sid, offset, length, holders,
+                                        into=into)
         if data is None:
             data = self._fetch_remote_shard(vid, sid, offset, length,
-                                            holders, include_open=True)
+                                            holders, include_open=True,
+                                            into=into)
         if data is None:
             raise OSError(f"shard {vid}.{sid} range [{offset}, +{length}) "
                           "unreachable")
@@ -2840,6 +2877,9 @@ class VolumeServer:
                         duration_ms=round((time.perf_counter() - t0) * 1e3, 1),
                         # the rebuild's stage sums (ec/encoder.py)
                         batches=stats.get("batches", 0),
+                        # of them, staged in buffers an earlier rebuild
+                        # had filled (ec/buffers.py)
+                        warm_batches=stats.get("warm_batches", 0),
                         **{k: round(v, 3) for k, v in stats.items()
                            if k.endswith("_s")})
             vs.flush_heartbeat()

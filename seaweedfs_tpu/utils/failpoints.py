@@ -178,6 +178,15 @@ def _take(name: str) -> _Armed | None:
     return armed
 
 
+def armed(name: str) -> bool:
+    """Whether site `name` is armed, without taking a firing: for a data
+    site whose payload has to be gathered before `data_fault` can see
+    it."""
+    if not _env_loaded:
+        _load_env()
+    return name in _armed
+
+
 def check(name: str) -> None:
     """The standard hook: raises or delays when the site is armed."""
     if not _armed and _env_loaded:  # fast path

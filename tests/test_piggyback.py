@@ -541,13 +541,17 @@ def test_ranged_rebuild_rpc_end_to_end(tmp_path_factory):
         # `read_s` by kind, and nothing ran under a stage `codec`
         for key in ("read_s", "dispatch_s", "drain_s", "write_s",
                     "read_busy_s", "read_local_busy_s",
-                    "read_remote_busy_s", "batches", "duration_ms"):
+                    "read_remote_busy_s", "batches", "warm_batches",
+                    "duration_ms"):
             assert key in fin, key
+        assert 0 <= fin["warm_batches"] <= fin["batches"]
         assert "codec_s" not in fin and fin["batches"] >= 1
         four = (fin["read_s"] + fin["dispatch_s"] + fin["drain_s"]
                 + fin["write_s"])
         assert 0 < four <= fin["duration_ms"] / 1e3 + 0.002
-        assert fin["read_local_busy_s"] > 0 < fin["read_remote_busy_s"]
+        # (a local load of a few KB is one preadv into the batch: its
+        # seconds may round to 0.000 in the event)
+        assert fin["read_local_busy_s"] >= 0 < fin["read_remote_busy_s"]
 
         # -- degraded reads through a piggybacked parity --------------------
         # lose data shard 3 AND the unpiggybacked parity 4 (both on
