@@ -23,6 +23,12 @@ fill, compute, write — genuinely overlap:
   9.75 s of coder under 43.66 s of serial writes). A writer failure (ENOSPC, bad disk)
   poisons the pool: the job fails cleanly, threads join, partial shard files
   are removed.
+* **Shards placed elsewhere leave as they are written.** A volume whose
+  job names other holders (ec/push.py `Holder`) streams their shards to
+  them from the same writer threads (`ShardSink.write` in place of the
+  pwrite): they never touch this disk. Each holder seals its part when
+  the volume's `finish` sends it the sidecars and the `.vif`, under the
+  encode of the next volume; the job returns once every holder replied.
 * **Writer-gated double buffering.** `ErasureCoder.encode` on the JAX path
   is an async dispatch; the pipeline keeps `depth` batches in flight and
   only blocks when fetching parity bytes for batch N while N+1..N+depth
@@ -204,12 +210,15 @@ class WriterPool:
             if item is None:
                 q.task_done()
                 return
-            fd, off, arr, on_done = item
+            shard_id, fd, off, arr, on_done, sink = item
             item = None
             if self._err is None:
                 t0 = time.perf_counter()
                 try:
-                    _write_run(fd, off, arr)
+                    if fd is not None:
+                        _write_run(fd, off, arr)
+                    if sink is not None:
+                        sink.write(shard_id, off, arr)
                     self._busy[i] += time.perf_counter() - t0
                 except BaseException as e:  # noqa: BLE001 — surfaced via submit/drain
                     with self._err_lock:
@@ -228,14 +237,15 @@ class WriterPool:
             q.task_done()
 
     # -- submitting side ----------------------------------------------------
-    def submit(self, shard_id: int, fd: int, off: int, arr: np.ndarray,
-               on_done=None) -> None:
-        """Queue one batch-contiguous run for shard_id's writer thread."""
+    def submit(self, shard_id: int, fd: "int | None", off: int,
+               arr: np.ndarray, on_done=None, sink=None) -> None:
+        """Queue one batch-contiguous run for shard_id's writer thread:
+        pwritten to `fd`, streamed to `sink` (ec/push.py), or both."""
         if self._err is not None:
             raise self._err
         from ..stats import EC_WRITER_QUEUE_DEPTH
         q = self._queues[shard_id % self.writers]
-        item = (fd, off, arr, on_done)
+        item = (shard_id, fd, off, arr, on_done, sink)
         t0 = time.perf_counter()
         # delta, not an absolute set: concurrent encodes each run their own
         # pool but share the gauge, and absolutes would clobber each other.
@@ -284,7 +294,8 @@ class WriterPool:
 
     @property
     def busy_s(self) -> float:
-        """Aggregate seconds writer threads spent inside pwrite."""
+        """Aggregate seconds writer threads spent inside pwrite (and
+        handing runs to sinks)."""
         return sum(self._busy)
 
     @property
@@ -398,7 +409,9 @@ class _VolumePlan:
     chunk: int
     dat_size: int = 0
     shard_size: int = 0
-    fds: list[int] = field(default_factory=list)
+    # per shard id: the local file's fd, None where the shard is not
+    # written here
+    fds: "list[int | None]" = field(default_factory=list)
     inflight_runs: int = 0
     finished: bool = False
     # (view4d [rows, d, nch, C], shard_base, rows, nch) per region
@@ -409,6 +422,16 @@ class _VolumePlan:
     # piggyback XOR-folds, msr rewrites the parities — before the .vif
     # seal
     overlay: "object | None" = None
+    # the volume's other holders (ec/push.py Holder): their shards are
+    # streamed to them as written, through one sink a holder. An overlay
+    # reads the data shards back from this disk, so with one those are
+    # written here too (`scratch`, removed once the overlay is done) and
+    # the parities placed elsewhere are not streamed: they stay here for
+    # the caller to copy
+    holders: list = field(default_factory=list)
+    sinks: list = field(default_factory=list)
+    sink_of: list = field(default_factory=list)  # per shard id, or None
+    scratch: "list[int]" = field(default_factory=list)
     # iteration cursor: (region_idx, row, chunk)
     _pos: tuple[int, int, int] = (0, 0, 0)
     # source mapping ownership + outstanding writer-pool runs
@@ -421,18 +444,40 @@ class _VolumePlan:
         geo, chunk = self.geo, self.chunk
         self.dat_size = os.path.getsize(self.dat_path)
         self.shard_size = geo.shard_file_size(self.dat_size)
-        paths = [self.out_base + files.shard_ext(i) for i in range(geo.n)]
-        for p in paths:
-            with open(p, "wb") as f:
+        push = {sid: h for h in self.holders for sid in h.shard_ids
+                if self.overlay is None or sid < geo.d}
+        if self.overlay is not None:
+            self.scratch = sorted(push)
+        local = [i for i in range(geo.n)
+                 if i not in push or i in self.scratch]
+        for i in range(geo.n):
+            path = self.out_base + files.shard_ext(i)
+            if i not in local:
+                # one left by an earlier seal of this volume id (a decode
+                # keeps its shards) would be mounted beside the holder's
+                _unlink_quiet(path)
+                continue
+            with open(path, "wb") as f:
                 if self.shard_size:
                     f.truncate(self.shard_size)
+        self.fds = [None] * geo.n
+        self.sink_of = [None] * geo.n
+        if open_fds:
+            for h in self.holders:
+                sids = sorted(s for s, hh in push.items() if hh is h)
+                if sids:
+                    sink = h.open(sids, self.shard_size)
+                    self.sinks.append(sink)
+                    for sid in sids:
+                        self.sink_of[sid] = sink
         if self.dat_size == 0:
             return
         if open_fds:
-            # append as we go: a mid-list EMFILE must leave the already-
+            # assigned as we go: a mid-list EMFILE must leave the already-
             # opened fds visible to _close_fds/abort, not leak them
-            for p in paths:
-                self.fds.append(os.open(p, os.O_WRONLY))
+            for i in local:
+                self.fds[i] = os.open(self.out_base + files.shard_ext(i),
+                                      os.O_WRONLY)
         mm, raw = _populated_view(self.dat_path)
         self._arr, self._mm = mm, raw
 
@@ -527,6 +572,8 @@ class _VolumePlan:
     def _close_fds(self) -> None:
         fds, self.fds = self.fds, []
         for fd in fds:
+            if fd is None:
+                continue
             try:
                 os.close(fd)
             except OSError:
@@ -540,10 +587,13 @@ class _VolumePlan:
         (the pre-writeback encoder msync'd every output mapping here): a
         power loss must never leave a valid-looking .vif over shards
         still in page cache, because a "successfully" converted volume's
-        .dat may already be gone.
+        .dat may already be gone. Then each other holder gets the
+        sidecars and the `.vif` document, and seals its part the same
+        way; its reply is awaited after the pipeline (`_await_pushes`).
         """
         for fd in self.fds:
-            os.fsync(fd)
+            if fd is not None:
+                os.fsync(fd)
         self._close_fds()
         self._release_source()
         geo = self.geo
@@ -560,20 +610,32 @@ class _VolumePlan:
             # fsyncs above — re-pin them before the seal claims them
             for i in range(geo.d, geo.n):
                 fsutil.fsync_path(self.out_base + files.shard_ext(i))
+            # the data shards streamed elsewhere were here for it alone
+            for sid in self.scratch:
+                _unlink_quiet(self.out_base + files.shard_ext(sid))
         if self.idx_path and os.path.exists(self.idx_path):
             files.write_ecx_from_idx(self.idx_path, self.out_base + ".ecx")
             # the .ecx must be durable BEFORE the .vif seals the volume
             # for the same reason as the shard fsyncs: a sealed .vif
             # over a torn .ecx serves no needle at all
             fsutil.fsync_path(self.out_base + ".ecx")
-        files.write_vif(self.out_base + ".vif", version=3,
-                        dat_size=self.dat_size, d=geo.d, p=geo.p,
-                        large_block=geo.large_block,
-                        small_block=geo.small_block, codec=codec)
+        vif = dict(version=3, dat_size=self.dat_size, d=geo.d, p=geo.p,
+                   large_block=geo.large_block, small_block=geo.small_block,
+                   codec=codec)
+        files.write_vif(self.out_base + ".vif", **vif)
+        for sink in self.sinks:
+            sink.seal(self.out_base, vif)
         self.finished = True
 
+    def push_ok(self) -> bool:
+        """Every other holder replied that its part is sealed."""
+        return all(sink.ok() for sink in self.sinks)
+
     def abort(self) -> None:
-        """Failure path: close everything and remove partial outputs."""
+        """Failure path: close everything and remove partial outputs
+        (each holder removes what its cancelled stream wrote)."""
+        for sink in self.sinks:
+            sink.cancel()
         self._close_fds()
         self._release_source()
         for i in range(self.geo.n):
@@ -619,10 +681,15 @@ def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
                    stats: "dict | None" = None,
                    null_sink: bool = False,
                    writers: "int | None" = None,
+                   push: "dict[str, list] | None" = None,
                    ) -> "dict[str, list[str]]":
     """Encode many volumes through one shared device stream.
 
-    jobs: (dat_path, out_base, idx_path | None) per volume.
+    jobs: (dat_path, out_base, idx_path | None) per volume. `push` maps a
+    dat_path to the volume's other holders (ec/push.py `Holder`): their
+    shards are streamed to them and not written here (`_VolumePlan`
+    says which stay for an overlay codec), and the call returns once
+    each has sealed its part.
     Returns {dat_path: [shard paths]}. `chunk` is clamped to the largest
     value that divides both block sizes (fit_chunk). Pass a dict as `stats`
     to receive pipeline timings (wall_s, fill_s, write_s, write_block_s,
@@ -671,12 +738,16 @@ def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
         if not slab_coder.async_dispatch:
             res = _encode_volumes_sync(jobs, geo, slab_coder, chunk, batch,
                                        stats, acct, null_sink=null_sink,
-                                       writers=writers, pb=pb)
+                                       writers=writers, pb=pb,
+                                       push=push)
         else:
             res = _encode_volumes_async(jobs, geo, slab_coder, chunk, batch,
                                         depth, stats, acct, writers=writers,
-                                        pb=pb)
-        stats.update(wall_s=time.perf_counter() - t0, bytes=total,
+                                        pb=pb, push=push)
+        # the pipeline's wall, as without a push: the wait for the
+        # holders' seal replies after its last finish is push_wait_s
+        stats.update(wall_s=time.perf_counter() - t0
+                     - stats.get("push_wait_s", 0.0), bytes=total,
                      fill_s=acct.seconds("fill"),
                      write_block_s=acct.seconds("write_block"),
                      finish_s=acct.seconds("finish"))
@@ -688,6 +759,11 @@ def encode_volumes(jobs: "list[tuple[str, str, str | None]]", geo: EcGeometry,
 # of the wall is opening the plans (truncate n shard files, MAP_POPULATE
 # the .dat), queueing runs to the writers and joining their threads.
 _STAGES = ("fill", "dispatch", "drain", "write_block", "finish")
+# what `_await_pushes` books: bytes streamed to other holders, the bytes
+# of the shards placed off this server (streamed or left to the copy),
+# the writers' seconds inside the sinks, and the wait for the holders'
+# seal replies after the last finish
+PUSH_FIELDS = ("push_bytes", "remote_bytes", "push_s", "push_wait_s")
 
 
 def _publish_pipeline_stats(stats: dict, acct, span) -> None:
@@ -702,6 +778,9 @@ def _publish_pipeline_stats(stats: dict, acct, span) -> None:
     acct.publish(span)
     span.set_attr("write_s", round(stats.get("write_s", 0.0), 4))
     span.set_attr("wall_s", round(wall, 4))
+    for key in PUSH_FIELDS:
+        if key in stats:
+            span.set_attr(key, round(stats[key], 4))
     span.set_attr("writers", stats.get("writers", 0))
     if wall > 0:
         # fraction of writer busy time hidden behind fill/compute: 1 means
@@ -717,7 +796,7 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
                          chunk: int, batch: int, stats: dict, acct,
                          null_sink: bool = False,
                          writers: "int | None" = None,
-                         pb=None,
+                         pb=None, push: "dict | None" = None,
                          ) -> "dict[str, list[str]]":
     """Zero-copy streaming encode for synchronous host coders.
 
@@ -741,7 +820,8 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
     try:
         for dat_path, out_base, idx_path in jobs:
             plan = _VolumePlan(dat_path, out_base, idx_path, geo, chunk,
-                               overlay=pb)
+                               overlay=pb,
+                               holders=(push or {}).get(dat_path, []))
             created.append(plan)
             out[dat_path] = [out_base + files.shard_ext(i)
                              for i in range(geo.n)]
@@ -781,11 +861,12 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
                             # WriterPool is an io plane, not an executor:
                             # writer threads never read the trace context
                             pool.submit(i, plan.fds[i], shard_off, arr,  # swtpu-lint: disable=executor-no-context
-                                        plan.write_done)
+                                        plan.write_done, plan.sink_of[i])
                         for j in range(p):
                             plan.note_write()
                             pool.submit(d + j, plan.fds[d + j], shard_off,  # swtpu-lint: disable=executor-no-context
-                                        parity[:, j, :], plan.write_done)
+                                        parity[:, j, :], plan.write_done,
+                                        plan.sink_of[d + j])
                     r0 += k
             EC_ENCODE_BYTES.inc(type(coder).__name__, amount=plan.dat_size)
             if not plan.finished:
@@ -795,6 +876,7 @@ def _encode_volumes_sync(jobs, geo: EcGeometry, coder: ErasureCoder,
         if pool is not None:
             pool.drain()
         _reap(finishing, acct, force=True)
+        _await_pushes(created, stats)
     except BaseException:
         _abort(pool, created)
         raise
@@ -811,20 +893,36 @@ def _abort(pool: "WriterPool | None", created: "list[_VolumePlan]") -> None:
     """Shared failure path: stop the writeback plane (queued runs are
     skipped, callbacks still fire, threads join) and remove every
     unfinished volume's partial outputs. Completed volumes are kept —
-    their shards are whole and verified by construction."""
+    their shards are whole and verified by construction — where every
+    other holder has replied that its part is sealed too."""
     if pool is not None:
         pool.poison()
         pool.close()
     for plan in created:
-        if not plan.finished:
+        if not plan.finished or not plan.push_ok():
             plan.abort()
+
+
+def _await_pushes(plans: "list[_VolumePlan]", stats: dict) -> None:
+    """After the pipeline's last finish: wait for every holder's seal
+    reply (raising the first failure), and book the push in `stats`."""
+    t0 = time.perf_counter()
+    sinks = [sink for plan in plans for sink in plan.sinks]
+    for sink in sinks:
+        sink.result()
+    stats.update(
+        push_bytes=sum(sink.bytes for sink in sinks),
+        remote_bytes=sum(plan.shard_size * len(h.shard_ids)
+                         for plan in plans for h in plan.holders),
+        push_s=sum(sink.seconds for sink in sinks),
+        push_wait_s=time.perf_counter() - t0)
 
 
 def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                           chunk: int, batch: int, depth: int,
                           stats: dict, acct,
                           writers: "int | None" = None,
-                          pb=None,
+                          pb=None, push: "dict | None" = None,
                           ) -> "dict[str, list[str]]":
 
     from ..stats import EC_ENCODE_BYTES
@@ -832,7 +930,8 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
     todo = deque()
     for dat_path, out_base, idx_path in jobs:
         todo.append(_VolumePlan(dat_path, out_base, idx_path, geo, chunk,
-                                overlay=pb))
+                                overlay=pb,
+                                holders=(push or {}).get(dat_path, [])))
         out[dat_path] = [out_base + files.shard_ext(i) for i in range(geo.n)]
 
     d, p = geo.d, geo.p
@@ -850,7 +949,7 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                 plan.note_write()
                 pool.submit(d + j, plan.fds[d + j], run.shard_off,
                             parity[run.b0:run.b0 + run.k, j],
-                            plan.write_done)
+                            plan.write_done, plan.sink_of[d + j])
             plan.inflight_runs -= 1
             if plan.exhausted() and plan.inflight_runs == 0:
                 finishing.append(plan)
@@ -907,7 +1006,7 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
                     pipe.retain(buf)
                     plan.note_write()
                     pool.submit(i, plan.fds[i], shard_off,  # swtpu-lint: disable=executor-no-context
-                                buf[b0:b0 + k, i], done)
+                                buf[b0:b0 + k, i], done, plan.sink_of[i])
                 b0 += k
         return b0, runs
 
@@ -933,6 +1032,7 @@ def _encode_volumes_async(jobs, geo: EcGeometry, coder: ErasureCoder,
         pipe.flush()
         pool.drain()
         _reap(finishing, acct, force=True)
+        _await_pushes(created, stats)
     except BaseException:
         _abort(pool, created)
         raise

@@ -1175,6 +1175,8 @@ class MasterServer:
         if hb.max_file_key:
             self.sequencer.set_max(hb.max_file_key)
             node.max_file_key = hb.max_file_key
+        if hb.ec_data_shards:
+            node.ec_geometry = (hb.ec_data_shards, hb.ec_parity_shards)
 
         if hb.volumes or hb.has_no_volumes:
             vols = [VolumeInfo.from_pb(m) for m in hb.volumes]
@@ -1359,8 +1361,10 @@ class MasterServer:
                 for rack in dc.racks.values():
                     ri = dci.rack_infos.add(id=rack.id)
                     for node in rack.nodes.values():
-                        ni = ri.data_node_infos.add(id=node.id,
-                                                    grpc_port=node.grpc_port)
+                        ni = ri.data_node_infos.add(
+                            id=node.id, grpc_port=node.grpc_port,
+                            ec_data_shards=node.ec_geometry[0],
+                            ec_parity_shards=node.ec_geometry[1])
                         for dtype, disk in node.disks.items():
                             di = ni.disk_infos[dtype]
                             di.type = dtype

@@ -90,6 +90,9 @@ class DataNode:
         self.disks: dict[str, Disk] = {}
         self.last_seen = time.monotonic()  # staleness clock, not wall
         self.max_file_key = 0
+        # (d, p) the server seals when an ec.encode names no geometry;
+        # (0, 0) until a heartbeat says
+        self.ec_geometry = (0, 0)
 
     @property
     def id(self) -> str:

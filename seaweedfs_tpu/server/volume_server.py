@@ -15,7 +15,9 @@ import threading
 import time
 import urllib.parse
 
+from ..ec import MAX_SHARD_ID
 from ..ec import files as ec_files
+from ..ec import push as ec_push
 from ..ec import repair as ec_repair
 from ..ec.encoder import rebuild_shards
 from ..ec.locate import EcGeometry
@@ -95,11 +97,13 @@ def _ec_stage_fields(stats: dict) -> dict:
     fields = {}
     for key in ("fill_s", "dispatch_s", "first_dispatch_s", "coder_s",
                 "drain_block_s", "write_s", "write_block_s", "finish_s",
-                "wall_s"):
+                "wall_s", "push_s", "push_wait_s"):
         if key in stats:
             fields[key] = round(stats[key], 3)
+    # the shards streamed to other holders as they were written, beside
+    # the bytes of all shards placed off this server (ec/stream.py)
     for key in ("write_overlap", "writers", "batches", "mode", "bytes",
-                "batch_bytes_by_device"):
+                "batch_bytes_by_device", "push_bytes", "remote_bytes"):
         if key in stats:
             fields[key] = stats[key]
     return fields
@@ -336,7 +340,9 @@ class VolumeServer:
                 data_center=self.data_center, rack=self.rack,
                 max_volume_counts=hb["max_volume_counts"],
                 has_no_volumes=not hb["volumes"],
-                has_no_ec_shards=not hb["ec_shards"])
+                has_no_ec_shards=not hb["ec_shards"],
+                ec_data_shards=self.store.ec_geometry.d,
+                ec_parity_shards=self.store.ec_geometry.p)
             for v in hb["volumes"]:
                 msg.volumes.add(**v)
             for s in hb["ec_shards"]:
@@ -2416,6 +2422,14 @@ class VolumeServer:
         svc = RpcService(VOLUME_SERVICE)
         vs = self
         store = self.store
+        # a volume's push arriving here (VolumeEcShardsReceive) and a
+        # delete of its shards here take the volume's lock: a delete
+        # sent after a failed seal waits until the push ended, sealed or
+        # undone, so nothing the push wrote outlives it
+        receiving: dict = {}
+
+        def receive_lock(vid: int) -> threading.Lock:
+            return receiving.setdefault(vid, threading.Lock())
 
         @svc.unary("AllocateVolume", vpb.AllocateVolumeRequest,
                    vpb.AllocateVolumeResponse)
@@ -2780,11 +2794,15 @@ class VolumeServer:
             from ..ops import events
             t0 = time.perf_counter()
             stats: dict = {}
+            targets: dict = {}
+            for t in req.targets:
+                targets.setdefault(t.volume_id, []).append(
+                    (t.address, list(t.shard_ids)))
             try:
                 done = store.generate_ec_shards_batch(
                     list(req.volume_ids), req.collection,
                     req.data_shards or None, req.parity_shards or None,
-                    stats=stats, codec=req.codec or None)
+                    stats=stats, codec=req.codec or None, targets=targets)
             except Exception as e:  # noqa: BLE001
                 events.emit("ec.encode.finish", severity=events.ERROR,
                             node=vs.url, ok=False,
@@ -2798,7 +2816,10 @@ class VolumeServer:
                 encoded_volume_ids=done,
                 data_shards=req.data_shards or store.ec_geometry.d,
                 parity_shards=req.parity_shards or store.ec_geometry.p,
-                codec=req.codec or store.ec_codec)
+                codec=req.codec or store.ec_codec,
+                pushed=[vpb.EcShardTarget(volume_id=vid, address=addr,
+                                          shard_ids=sids)
+                        for vid, addr, sids in stats.get("pushed", [])])
 
         @svc.unary("VolumeEcShardsInfo", vpb.VolumeEcShardsInfoRequest,
                    vpb.VolumeEcShardsInfoResponse)
@@ -2931,6 +2952,26 @@ class VolumeServer:
                         f.write(pc)
             return vpb.VolumeEcShardsCopyResponse()
 
+        @svc.stream_unary("VolumeEcShardsReceive",
+                          vpb.VolumeEcShardsReceiveRequest,
+                          vpb.VolumeEcShardsReceiveResponse)
+        @_maintenance_tagged
+        def ec_receive(requests, context):
+            """The other end of a seal's push (ec/push.py): this server's
+            shards of a volume arrive as the encoder writes them, and are
+            fsynced with the sidecars before the .vif is written and the
+            reply sent. Files only, like VolumeEcShardsCopy: the caller's
+            VolumeEcShardsMount registers them."""
+            def base_for(collection: str, vid: int) -> str:
+                loc = next((l for l in store.locations
+                            if os.path.exists(
+                                l.base_name(collection, vid) + ".ecx")),
+                           None) or store._location_for(None)
+                return loc.base_name(collection, vid)
+            sids, got = ec_push.receive(requests, base_for, receive_lock)
+            return vpb.VolumeEcShardsReceiveResponse(bytes_received=got,
+                                                     shard_ids=sids)
+
         # fork RPC: rebuild shards directly onto this server from peers
         @svc.unary("VolumeEcShardsCopyByRebuild",
                    vpb.VolumeEcShardsCopyByRebuildRequest,
@@ -2976,9 +3017,7 @@ class VolumeServer:
             vs.flush_heartbeat()
             return vpb.VolumeEcShardsUnmountResponse()
 
-        @svc.unary("VolumeEcShardsDelete", vpb.VolumeEcShardsDeleteRequest,
-                   vpb.VolumeEcShardsDeleteResponse)
-        def ec_delete(req, context):
+        def _ec_delete(req) -> "str | None":
             ev = store.find_ec_volume(req.volume_id)
             base = None
             if ev is not None:
@@ -2987,8 +3026,9 @@ class VolumeServer:
             else:
                 for loc in store.locations:
                     cand = loc.base_name(req.collection, req.volume_id)
-                    if any(os.path.exists(cand + ec_files.shard_ext(s))
-                           for s in req.shard_ids):
+                    if any(os.path.exists(cand + ext) for ext in (
+                            *(ec_files.shard_ext(s) for s in req.shard_ids),
+                            ".ecx")):
                         base = cand
                         break
             if base:
@@ -3004,6 +3044,32 @@ class VolumeServer:
                 if os.path.exists(base + ".vif"):
                     ec_files.drop_remote_claims(base + ".vif",
                                                 list(req.shard_ids))
+            return base
+
+        def _drop_sidecars_of_no_shards(base: str) -> None:
+            """The volume's last shard here gone: its .ecx and .ecj go
+            too, and the .vif unless a normal volume's .dat or a remote
+            claim still needs it (upstream VolumeEcShardsDelete)."""
+            if os.path.exists(base + ".dat") or any(
+                    os.path.exists(base + ec_files.shard_ext(s))
+                    for s in range(MAX_SHARD_ID)):
+                return
+            if os.path.exists(base + ".vif") and \
+                    ec_files.read_vif(base + ".vif").get("remote_shards"):
+                return
+            for ext in (".ecx", ".ecj", ".vif"):
+                try:
+                    os.remove(base + ext)
+                except FileNotFoundError:
+                    pass
+
+        @svc.unary("VolumeEcShardsDelete", vpb.VolumeEcShardsDeleteRequest,
+                   vpb.VolumeEcShardsDeleteResponse)
+        def ec_delete(req, context):
+            with receive_lock(req.volume_id):
+                base = _ec_delete(req)
+                if base:
+                    _drop_sidecars_of_no_shards(base)
             # a shard still mounted when the delete came was unmounted
             # above: flush. After a VolumeEcShardsUnmount (it flushed) this
             # one has nothing to report and stays all the same: a heartbeat

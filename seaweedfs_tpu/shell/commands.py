@@ -100,7 +100,11 @@ class CommandEnv:
                 for node in rack.data_node_infos:
                     out.append({"id": node.id, "grpc_port": node.grpc_port,
                                 "dc": dc.id, "rack": rack.id,
-                                "disks": node.disk_infos})
+                                "disks": node.disk_infos,
+                                # what an ec.encode naming no geometry
+                                # seals there; (0, 0) if unreported
+                                "ec_geometry": (node.ec_data_shards,
+                                                node.ec_parity_shards)})
         return out
 
     def grpc_addr(self, node_id: str, grpc_port: int) -> str:

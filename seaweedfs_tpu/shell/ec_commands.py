@@ -123,7 +123,10 @@ def cmd_ec_encode(env: CommandEnv, args):
 
     limit = env.mc.volume_list().volume_size_limit_mb * (1 << 20)
     targets = []  # (vid, collection, srv)
-    for srv in env.collect_volume_servers():
+    # ONE topology read finds the volumes and places every volume's shards
+    # (_ec_volumes says why a verb under the lock may plan from one read)
+    servers = env.collect_volume_servers()
+    for srv in servers:
         for dtype, disk in srv["disks"].items():
             if opt.sourceDiskType and dtype != opt.sourceDiskType:
                 continue  # fork: EC source restricted by disk type
@@ -153,19 +156,48 @@ def cmd_ec_encode(env: CommandEnv, args):
                           (srv, []))[1].append((vid, collection))
     encoded = 0
     for srv, vols in by_src.values():
-        encoded += _encode_on_server(env, srv, vols, opt)
+        encoded += _encode_on_server(env, srv, vols, opt, servers)
     env.println(f"ec encoded {encoded} volumes")
 
 
+def _placement(servers: list[dict], vid: int, d: int, p: int
+               ) -> "dict[str, tuple[dict, list[int]]]":
+    """server id -> (server, shard ids) of one volume's d+p shards,
+    rack-capped at p a rack (command_ec_encode.go:187 spreadEcShards)."""
+    by_server: dict[str, tuple[dict, list[int]]] = {}
+    for sid, target in enumerate(balanced_ec_distribution(
+            servers, d + p, parity=p, vid=vid)):
+        by_server.setdefault(target["id"], (target, []))[1].append(sid)
+    return by_server
+
+
 def _encode_on_server(env: CommandEnv, srv: dict,
-                      vols: "list[tuple[int, str]]", opt) -> int:
-    """Freeze + batch-generate + spread one server's volumes. A failed
-    generate rolls the frozen volumes back to writable and raises: the
-    verb (and `shell -c`) must not report success over zero shards."""
+                      vols: "list[tuple[int, str]]", opt,
+                      servers: list[dict]) -> int:
+    """Freeze + batch-generate + spread one server's volumes. Each
+    volume's placement rides the generate: the shards of the other
+    holders are streamed to them as they are written, and only what the
+    server left (an overlay codec's parities) is copied after. A failed
+    generate removes what the holders received, rolls the frozen volumes
+    back to writable and raises: the verb (and `shell -c`) must not
+    report success over zero shards."""
     stub = _stub(env, srv)
     collection = vols[0][1]
     vids = [v for v, _ in vols]
     env.println(f"  ec.encode volumes {vids} on {srv['id']} (batched)")
+    # the geometry sealed there: the verb's, else the server's own
+    # default as its heartbeat reported it; unknown (a server that does
+    # not report it) leaves the shards to the copy after the generate
+    own_d, own_p = srv.get("ec_geometry", (0, 0))
+    d, p = opt.dataShards or own_d, opt.parityShards or own_p
+    plans = {}
+    if d and p:
+        plans = {vid: _placement(servers, vid, d, p) for vid in vids}
+    push = [vpb.EcShardTarget(volume_id=vid,
+                              address=env.grpc_addr(t["id"], t["grpc_port"]),
+                              shard_ids=sids)
+            for vid, plan in plans.items()
+            for tid, (t, sids) in plan.items() if tid != srv["id"]]
     frozen = []
     for vid, _c in vols:  # freeze writes (command_ec_encode.go:147)
         stub.call("VolumeMarkReadonly",
@@ -177,12 +209,25 @@ def _encode_on_server(env: CommandEnv, srv: dict,
         gen = stub.call("VolumeEcShardsGenerateBatch",
                         vpb.VolumeEcShardsGenerateBatchRequest(
                             volume_ids=vids, collection=collection,
-                            data_shards=opt.dataShards,
-                            parity_shards=opt.parityShards,
-                            codec=getattr(opt, "codec", "")),
+                            data_shards=d, parity_shards=p,
+                            codec=getattr(opt, "codec", ""), targets=push),
                         vpb.VolumeEcShardsGenerateBatchResponse,
                         timeout=3600 * len(vids))
         done = list(gen.encoded_volume_ids)
+    except Exception:
+        # a holder may have sealed its part of one volume before the
+        # batch failed on another: none of it may outlive the rollback
+        for t in push:
+            try:
+                Stub(t.address, VOLUME_SERVICE).call(
+                    "VolumeEcShardsDelete",
+                    vpb.VolumeEcShardsDeleteRequest(
+                        volume_id=t.volume_id, collection=collection,
+                        shard_ids=t.shard_ids),
+                    vpb.VolumeEcShardsDeleteResponse)
+            except Exception:  # noqa: BLE001  # swtpu-lint: disable=silent-except (best-effort cleanup; the generate's error is what the verb reports)
+                pass
+        raise
     finally:
         for vid in frozen:
             if vid not in done:  # rollback: un-encoded volumes take writes
@@ -196,15 +241,21 @@ def _encode_on_server(env: CommandEnv, srv: dict,
     if gen.codec:
         env.println(f"    codec {gen.codec} RS({d},{p})")
     coll_by_vid = dict(vols)
+    pushed: dict[tuple[int, str], list[int]] = {
+        (t.volume_id, t.address): list(t.shard_ids) for t in gen.pushed}
     for vid in done:
-        _spread_and_clean(env, vid, coll_by_vid.get(vid, collection), srv, d, p)
+        _spread_and_clean(env, vid, coll_by_vid.get(vid, collection), srv, d,
+                          p, servers, plans.get(vid), pushed)
     return len(done)
 
 
 def _spread_and_clean(env: CommandEnv, vid: int, collection: str, srv: dict,
-                      d: int, p: int) -> None:
-    """Distribute generated shards and delete the source volume
-    (reference command_ec_encode.go:187 spreadEcShards)."""
+                      d: int, p: int, servers: list[dict],
+                      by_server: "dict | None" = None,
+                      pushed: "dict | None" = None) -> None:
+    """Copy to each holder what the generate did not stream to it, mount
+    every holder's shards, then delete what moved off the source and the
+    source volume (reference command_ec_encode.go:187 spreadEcShards)."""
     stub = _stub(env, srv)
     if not d or not p:
         # the batch response didn't carry the geometry (pre-geometry
@@ -221,22 +272,26 @@ def _spread_and_clean(env: CommandEnv, vid: int, collection: str, srv: dict,
     n_shards = (d or 10) + (p or 4)
     # 3. spread (command_ec_encode.go:187): copy to targets, mount, clean
     # src — rack-capped at p shards per rack so rack loss != data loss
-    servers = env.collect_volume_servers()
-    placement = balanced_ec_distribution(servers, n_shards,
-                                         parity=(p or 4), vid=vid)
-    by_server: dict[str, tuple[dict, list[int]]] = {}
-    for sid, target in enumerate(placement):
-        by_server.setdefault(target["id"], (target, []))[1].append(sid)
+    if by_server is None:
+        by_server = _placement(servers, vid, d or 10, p or 4)
     src_grpc = env.grpc_addr(srv["id"], srv["grpc_port"])
+    streamed: list[int] = []  # never written on the source
     for tid, (target, sids) in by_server.items():
         if tid != srv["id"]:
-            _stub(env, target).call(
-                "VolumeEcShardsCopy",
-                vpb.VolumeEcShardsCopyRequest(
-                    volume_id=vid, collection=collection, shard_ids=sids,
-                    copy_ecx_file=True, copy_ecj_file=True, copy_vif_file=True,
-                    source_data_node=src_grpc),
-                vpb.VolumeEcShardsCopyResponse, timeout=3600)
+            got = (pushed or {}).get(
+                (vid, env.grpc_addr(tid, target["grpc_port"])), [])
+            streamed += got
+            copy = [s for s in sids if s not in got]
+            if copy:
+                # a holder that was streamed shards has the sidecars too
+                _stub(env, target).call(
+                    "VolumeEcShardsCopy",
+                    vpb.VolumeEcShardsCopyRequest(
+                        volume_id=vid, collection=collection,
+                        shard_ids=copy, copy_ecx_file=not got,
+                        copy_ecj_file=not got, copy_vif_file=not got,
+                        source_data_node=src_grpc),
+                    vpb.VolumeEcShardsCopyResponse, timeout=3600)
         _stub(env, target).call(
             "VolumeEcShardsMount",
             vpb.VolumeEcShardsMountRequest(volume_id=vid, collection=collection,
@@ -245,7 +300,7 @@ def _spread_and_clean(env: CommandEnv, vid: int, collection: str, srv: dict,
         env.println(f"    shards {sids} -> {tid}")
     # 4. delete shards that moved away from source + the original volume
     keep = by_server.get(srv["id"], (None, []))[1]
-    moved = [s for s in range(n_shards) if s not in keep]
+    moved = [s for s in range(n_shards) if s not in keep + streamed]
     if moved:
         stub.call("VolumeEcShardsUnmount",
                   vpb.VolumeEcShardsUnmountRequest(volume_id=vid, shard_ids=moved),

@@ -328,6 +328,7 @@ class Store:
                                  d: int | None = None, p: int | None = None,
                                  stats: "dict | None" = None,
                                  codec: str | None = None,
+                                 targets: "dict | None" = None,
                                  ) -> "list[int]":
         """Encode many local volumes through ONE shared device stream.
 
@@ -335,11 +336,17 @@ class Store:
         (volume_grpc_erasure_coding.go:39): slabs from all volumes are batched
         into fixed-shape [B, d, C] device calls so the MXU never idles on a
         volume boundary (ec/stream.py). Returns the vids encoded.
+
+        `targets` maps a vid to its other holders, [(grpc address, shard
+        ids)]: those shards are streamed to them as they are written
+        (ec/push.py) and sealed there before this returns; `stats["pushed"]`
+        then lists (vid, address, shard ids) of what went.
         """
-        from ..ec import stream
+        from ..ec import push, stream
         geo = EcGeometry(d or self.ec_geometry.d, p or self.ec_geometry.p,
                          self.ec_geometry.large_block,
                          self.ec_geometry.small_block)
+        holders: dict = {}
         jobs, done = [], []
         for vid in vids:
             v = self.find_volume(vid)
@@ -352,10 +359,17 @@ class Store:
             base = v.file_name()
             jobs.append((base + ".dat", base, base + ".idx"))
             done.append(vid)
+            holders[base + ".dat"] = [
+                push.Holder(addr, vid, collection, list(sids))
+                for addr, sids in (targets or {}).get(vid, [])]
         if jobs:
             stream.encode_volumes(jobs, geo,
                                   self.coder(geo.d, geo.p, codec=codec),
-                                  stats=stats)
+                                  stats=stats, push=holders)
+        if stats is not None:
+            stats["pushed"] = [(h.vid, h.address, h.pushed)
+                               for hs in holders.values() for h in hs
+                               if h.pushed]
         return done
 
     def mount_ec_shards(self, vid: int, collection: str = "") -> EcVolume:

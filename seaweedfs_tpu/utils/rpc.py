@@ -304,6 +304,17 @@ class RpcService:
             return fn
         return deco
 
+    def stream_unary(self, method: str, req_cls, resp_cls):
+        """A client-streaming handler, `fn(request_iterator, context)`:
+        bounded by its one reply, so spanned like a unary one."""
+        def deco(fn: Callable):
+            self._handlers[method] = grpc.stream_unary_rpc_method_handler(
+                self._traced_unary(method, fn),
+                request_deserializer=req_cls.FromString,
+                response_serializer=resp_cls.SerializeToString)
+            return fn
+        return deco
+
     def stream_stream(self, method: str, req_cls, resp_cls):
         def deco(fn: Callable):
             self._handlers[method] = grpc.stream_stream_rpc_method_handler(
@@ -495,6 +506,30 @@ class Stub:
                     metadata=_outgoing_metadata(sp))
         return _SpannedStream(stream, sp, acct, method,
                               time.perf_counter() - t0)
+
+    def stream_unary_future(self, method: str, request_iter, resp_cls,
+                            timeout: float = 3600.0):
+        """Start a client-streaming call whose requests `request_iter`
+        yields already serialized (bytes), and return its future: grpc
+        consumes the iterator on a thread of its own, and the call's
+        client span ends when the reply (or the error) arrives."""
+        fn = self._ch.stream_unary(f"/{self.service}/{method}",
+                                   request_serializer=None,
+                                   response_deserializer=resp_cls.FromString)
+        sp, acct = _client_span(method, self.address)
+        fut = fn.future(request_iter, timeout=timeout,
+                        metadata=_outgoing_metadata(sp))
+        if sp is not None:
+            t0 = time.perf_counter()
+
+            def done(f):
+                if f.cancelled():
+                    sp.status = "cancelled"
+                elif f.exception() is not None:
+                    sp.set_error(f.exception())
+                _end_client_span(sp, acct, method, time.perf_counter() - t0)
+            fut.add_done_callback(done)
+        return fut
 
     def stream_stream(self, method: str, request_iter, req_cls, resp_cls):
         fn = self._ch.stream_stream(

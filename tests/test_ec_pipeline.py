@@ -148,8 +148,8 @@ def test_writer_pool_error_skips_queued_runs_and_callbacks_fire(tmp_path):
         # (mirror submit()'s gauge increment — the writer decrements per
         # dequeued item, and the gauge is global delta accounting)
         EC_WRITER_QUEUE_DEPTH.add(amount=1)
-        pool._queues[0].put((fd, 8, np.full(8, 2, np.uint8),
-                             lambda: fired.append(2)))
+        pool._queues[0].put((0, fd, 8, np.full(8, 2, np.uint8),
+                             lambda: fired.append(2), None))
         pool._queues[0].join()
     finally:
         pool.close()

@@ -137,9 +137,13 @@ def test_each_command_writes_one_timing_line(sealed):
     assert [ln["verb"] for ln in lines] == ["lock", "ec.encode"]
     lock, encode = lines
     assert set(lock["methods"]) == {"LeaseAdminToken"}
-    assert {"VolumeEcShardsGenerateBatch", "VolumeEcShardsCopy",
-            "VolumeEcShardsMount", "VolumeDelete"} <= set(encode["methods"])
+    assert {"VolumeEcShardsGenerateBatch", "VolumeEcShardsMount",
+            "VolumeDelete"} <= set(encode["methods"])
     assert encode["methods"]["VolumeEcShardsGenerateBatch"][1] == 1
+    # B's shards rode the generate: nothing is copied after it, and A
+    # has none of B's to unmount or delete
+    assert not {"VolumeEcShardsCopy", "VolumeEcShardsUnmount",
+                "VolumeEcShardsDelete"} & set(encode["methods"])
     for ln in lines:
         # the shell is sequential: the methods partition `rpc`, inside
         # the command's wall, most seconds first
@@ -167,8 +171,17 @@ def test_one_trace_from_the_shell_to_the_servers(sealed):
     (encode,) = [s for s in trace if s["name"] == "ec.encode"]
     assert {"fill_s", "dispatch_s", "drain_s", "write_block_s", "finish_s",
             "write_s", "wall_s"} <= set(encode["attrs"])
-    # B's pulls from A are client spans of B's VolumeEcShardsCopy
-    assert any(s["name"] == "rpc.client/CopyFile" for s in trace)
+    # A streams B's shards to it from inside the pipeline: client spans
+    # under the encode's own span, each met by B's server span
+    pushes = [s for s in trace
+              if s["name"] == "rpc.client/VolumeEcShardsReceive"]
+    assert pushes and all(s["parent_id"] == encode["span_id"]
+                          for s in pushes)
+    received = [s for s in trace if s["name"] == "rpc/VolumeEcShardsReceive"]
+    assert ({s["parent_id"] for s in received}
+            == {s["span_id"] for s in pushes})
+    assert encode["attrs"]["push_bytes"] == encode["attrs"]["remote_bytes"] > 0
+    assert not any(s["name"] == "rpc.client/CopyFile" for s in trace)
     # stage intervals stay out of the ring
     assert not any(s["name"].startswith("swtpu/") for s in sealed["spans"])
 
@@ -384,3 +397,38 @@ def test_a_stream_outside_a_trace_is_grpcs_own_call(streamed):
     stream = _copy_file(server, vid)
     assert type(stream).__module__.startswith("grpc")
     stream.cancel()
+
+
+def test_overlay_parities_are_still_copied_after_the_generate(cluster):
+    """The copy path stays for what a seal leaves on A: a piggyback
+    volume's parities are rewritten by the overlay after the writers, so
+    the holder pulls those (`VolumeEcShardsCopy`, `CopyFile` under the
+    verb's trace) while its data shards rode the generate."""
+    import contextlib
+    master, servers, mc, env = cluster
+    rng = np.random.default_rng(9)
+    fids = [operation.submit(mc, rng.integers(0, 256, 1 << 18,
+                                              dtype=np.uint8).tobytes(),
+                             collection="pbacct").fid for _ in range(12)]
+    vid = int(fids[0].split(",")[0])
+    err = io.StringIO()
+    tracing.BUFFER.clear()
+    env.out = io.StringIO()
+    with contextlib.redirect_stderr(err):
+        run_command(env, "lock")
+        run_command(env, f"ec.encode -volumeId {vid} -ecShards 4,2 "
+                         "-codec piggyback")
+        run_command(env, "unlock")
+    assert "ec encoded 1 volumes" in env.out.getvalue()
+    (encode,) = [ln for ln in parse(err.getvalue())
+                 if ln["verb"] == "ec.encode"]
+    assert encode["methods"]["VolumeEcShardsCopy"][1] == 1
+    spans = tracing.BUFFER.snapshot(limit=5000)
+    (root,) = [s for s in spans if s["name"] == "shell/ec.encode"]
+    trace = [s for s in spans if s["trace_id"] == root["trace_id"]]
+    assert any(s["name"] == "rpc.client/VolumeEcShardsReceive"
+               for s in trace)
+    pulled = [s for s in trace if s["name"] == "rpc.client/CopyFile"]
+    assert pulled  # the parities that B holds, and nothing else
+    wait_until(lambda: sorted(master.topo.lookup_ec(vid)) == list(range(6)),
+               msg="piggyback shards registered")
