@@ -30,7 +30,7 @@ def _apply_stream(v: Volume, stream) -> int:
     import struct
 
     from ..storage import types as t
-    from ..storage.needle import record_size_from_header
+    from ..storage.types import record_size_from_header
 
     carry = b""
     applied = 0

@@ -120,7 +120,7 @@ def write_idx_from_ecx(ecx_path: str, ecj_path: str, idx_path: str) -> None:
 
 def max_ecx_extent(ecx_path: str) -> int:
     """Logical .dat size implied by the highest needle end (ec_decoder.go:48)."""
-    from ..storage.needle import record_size_from_header
+    from ..storage.types import record_size_from_header
     keys, offs, sizes = idx_entries_numpy(ecx_path)
     live = sizes != np.uint32(t.TOMBSTONE_SIZE)
     if not live.any():

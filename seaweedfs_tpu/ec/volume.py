@@ -18,7 +18,8 @@ from typing import Callable, Optional
 import numpy as np
 
 from ..storage import types as t
-from ..storage.needle import Needle, record_size_from_header
+from ..storage.needle import Needle
+from ..storage.types import record_size_from_header
 from ..utils.log import logger
 from . import files
 from .locate import EcGeometry, locate

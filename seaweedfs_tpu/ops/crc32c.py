@@ -158,6 +158,17 @@ def _matvec_u32(colmask: np.ndarray, vec: np.ndarray) -> np.ndarray:
     return out
 
 
+@functools.lru_cache(maxsize=1)
+def _a_pow2_tables() -> np.ndarray:
+    """A^(2^j) for j in 0..47 as byte tables, [48, 4, 256] uint32: row
+    (j, b) holds the matrix applied to each value of byte b of a state, so
+    one application is four lookups and three XORs."""
+    byte = np.arange(256, dtype=np.uint32)
+    return np.stack([
+        np.stack([_matvec_u32(m, byte << np.uint32(8 * b)) for b in range(4)])
+        for m in _a_pow2()])
+
+
 def zero_prefix_correction(lengths: np.ndarray) -> np.ndarray:
     """A^len @ INIT for a batch of lengths -> uint32 raw-state corrections.
 
@@ -165,13 +176,16 @@ def zero_prefix_correction(lengths: np.ndarray) -> np.ndarray:
     """
     lengths = np.asarray(lengths, dtype=np.int64)
     vec = np.full(lengths.shape, _INIT, dtype=np.uint32)
-    mats = _a_pow2()
-    for j in range(48):
-        bit = (lengths >> j) & 1
-        if not bit.any():
+    tables = _a_pow2_tables()
+    # only the bits some length has set: a block of 1 KB needles, one
+    present = int(np.bitwise_or.reduce(lengths, axis=None)) if vec.size else 0
+    for j in range(present.bit_length()):
+        if not (present >> j) & 1:
             continue
-        applied = _matvec_u32(mats[j], vec)
-        vec = np.where(bit.astype(bool), applied, vec)
+        t = tables[j]
+        applied = (t[0][vec & 0xFF] ^ t[1][(vec >> 8) & 0xFF]
+                   ^ t[2][(vec >> 16) & 0xFF] ^ t[3][vec >> 24])
+        vec = np.where((lengths >> j) & 1 == 1, applied, vec)
     return vec
 
 

@@ -2495,7 +2495,10 @@ class VolumeServer:
                         pack_s=round(r.pack_s, 4),
                         device_s=round(r.device_s, 4),
                         compare_s=round(r.compare_s, 4),
-                        device_busy_s=round(r.device_busy_s, 4))
+                        device_busy_s=round(r.device_busy_s, 4),
+                        recv_s=round(r.recv_s, 4),
+                        spawn_s=round(r.spawn_s, 4),
+                        walker_busy_s=round(r.walker_busy_s, 4))
                 except Exception as e:  # noqa: BLE001 — isolate per volume
                     resp.results.add(volume_id=v.id, mode="error",
                                      error=str(e))

@@ -187,12 +187,3 @@ class Needle:
     def disk_size(self) -> int:
         """Size of the padded record this needle would occupy."""
         return len(self.to_bytes(now_ns=self.append_at_ns or 1))
-
-
-def record_size_from_header(size: int) -> int:
-    """Padded record length given the header's size field."""
-    if size == t.TOMBSTONE_SIZE:
-        body = 0
-    else:
-        body = size
-    return t.actual_record_size(body)

@@ -24,32 +24,49 @@ program: one product over all of them, then a fold whose depth alone
 follows L. The raw device states are corrected for the zero prefix with
 crc32c.finalize(lengths).
 
-A block is on the chip while the next is walked and packed: the sweep
-owns one device thread for the length of a volume, hands it each packed
-block and compares the results in dispatch order as they come back, with
-at most _IN_FLIGHT blocks handed over and not yet compared. The sweep's
-thread waits only at that bound and at the volume's end. The host loop
-starts no thread.
+The device path walks and packs outside this interpreter: each volume's
+sweep starts a walker process (`storage/scrub_walk.py`, spawned fresh with
+`python -S -m`, the standard library alone: no numpy, no jax) that walks
+the `.dat` and packs each `[B, L]` block into one of _IN_FLIGHT + 1 slots
+of a memory file the two share (where none can be made, as under a file
+size limit smaller than the slots, it streams each block down its stdout
+into this process's own slots), and lives as long as the volume's sweep.
+What only this process can do stays here: the sweep's own device thread
+(the chip is this process's), `finalize` and the CRC compare. The sweep's
+thread takes each block the walker sends, hands it to the device thread,
+compares the results in dispatch order as they come back and gives the
+slot back, with at most _IN_FLIGHT blocks handed over and not yet
+compared. The host loop (`device="off"`, or no backend) walks in-process
+and starts no thread and no process.
 
 Where the time goes is kept per volume in a tracing.StageAccount. On the
-sweep's thread, exclusive, so that they partition `elapsed_s`: `walk`
-(the record walk and body reads between two dispatches), `pack`
-(zero-fill and copy into the [B, L] block), `wait` (blocked on the device
-thread: `device_s` of the result) and `compare` (finalize + the CRC
-compare). On the device thread, beside them: `device` (the jitted call
-through np.asarray: H2D, the loop-free CRC program, D2H), `device_busy_s`
-of the result; 1 - device_s / device_busy_s is the share of the device
-stage that host work hid. In a process with jax loaded each is a
-`swtpu/scrub.<stage>` annotation in a live profiler trace; `scrub.device`
-carries `needed` (needle bytes in the block), `dispatched` (B x L) and
-`L`. The host loop has the `walk` alone.
+device path the sweep's thread has three exclusive stages that partition
+`elapsed_s` by construction, `recv` enclosing the other two: `wait`
+(blocked on the device thread: `device_s` of the result), `compare`
+(finalize + the CRC compare) and `recv`, the rest: the walker's start, the
+waits for its next message, the hand-overs to the device thread and the
+joins at the end. On the device thread, beside them: `device` (the jitted
+call through np.asarray: H2D, the loop-free CRC program, D2H),
+`device_busy_s` of the result; 1 - device_s / device_busy_s is the share
+of the device stage that host work hid. The walker's own seconds come
+back with its last message, beside this process's: `walk_s` (the record walk and
+body reads), `pack_s` (zero-fill and copy into a slot) and
+`walker_busy_s` (its whole run less its waits for a free slot); `spawn_s`
+is from its start to its first message. In a process with jax loaded each
+stage of this process is a `swtpu/scrub.<stage>` annotation in a live
+profiler trace; `scrub.device` carries `needed` (needle bytes in the
+block), `dispatched` (B x L) and `L`. The host loop has the `walk` alone.
 """
 
 from __future__ import annotations
 
 import contextvars
 import functools
-import struct
+import json
+import mmap
+import os
+import subprocess
+import sys
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor, wait
@@ -60,19 +77,29 @@ import numpy as np
 from ..ops import crc32c as crcmod
 from ..ops import device as devgate
 from ..utils.log import logger
+from . import scrub_walk
 from . import types as t
-from .needle import record_size_from_header
 from .volume import Volume
 
 log = logger("scrub")
 
-_CHUNK = 512
+_CHUNK = scrub_walk.CHUNK
 # padded bytes in one device dispatch; each length bucket L runs at the
 # fixed shape [_DISPATCH_BYTES // L, L] (one row for longer needles)
 _DISPATCH_BYTES = 8 << 20
-# blocks handed to the device thread and not yet compared while the next is
-# walked and packed: 24 MiB of blocks alive at most
+# blocks handed to the device thread and not yet compared while the
+# walker packs the next: with it, _IN_FLIGHT + 1 slots of blocks alive
 _IN_FLIGHT = 2
+# the walker process's command after the interpreter, and the directory
+# that holds the package, for its import path (-S: no site-packages)
+_WALKER_CMD = ("-S", "-m", "seaweedfs_tpu.storage.scrub_walk")
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+# glibc's allocator in the walker keeps what it frees: a fresh process
+# that reads a volume through short-lived body buffers would otherwise
+# fault in new pages for each of them, which costs as much as the read
+_WALKER_MALLOC = {"MALLOC_MMAP_THRESHOLD_": str(32 << 20),
+                  "MALLOC_TRIM_THRESHOLD_": str(1 << 31)}
 
 
 @dataclass
@@ -84,16 +111,21 @@ class ScrubResult:
     elapsed_s: float = 0.0
     mode: str = "cpu"
     error: str = ""  # volume-level trouble (torn walk, tiered skip, ...)
-    # exclusive stage seconds of the sweep's thread (they partition
-    # elapsed_s; device_s is its wait for the device thread), the device
-    # thread's own seconds inside the jitted call through np.asarray
-    # beside them, device blocks dispatched and their padded bytes
-    # (>= bytes_checked)
+    # the sweep's thread: on the device path recv_s + device_s (its wait
+    # for the device thread) + compare_s add up to elapsed_s, in the host
+    # loop walk_s is elapsed_s; beside them the device thread's own seconds
+    # inside the jitted call through np.asarray, and the walker process's
+    # own walk_s, pack_s and walker_busy_s; spawn_s from the walker's start
+    # to its first message; device blocks dispatched and their padded
+    # bytes (>= bytes_checked)
     walk_s: float = 0.0
     pack_s: float = 0.0
     device_s: float = 0.0
     compare_s: float = 0.0
     device_busy_s: float = 0.0
+    recv_s: float = 0.0
+    spawn_s: float = 0.0
+    walker_busy_s: float = 0.0
     blocks: int = 0
     bytes_dispatched: int = 0
 
@@ -110,70 +142,152 @@ def _crc_jit():
     return jax.jit(lambda x: crcmod.device_crc_states(x, chunk=_CHUNK))
 
 
-def _bucket(n: int) -> int:
-    out = _CHUNK
-    while out < n:
-        out *= 2
-    return out
-
-
 def _block_shape(length: int) -> "tuple[int, int]":
     """(B, L) of the fixed device block a needle of `length` rides in."""
-    pad_l = _bucket(length)
-    return max(1, _DISPATCH_BYTES // pad_l), pad_l
+    return scrub_walk.block_shape(length, _DISPATCH_BYTES)
 
 
-def _iter_needles(v: Volume, res: ScrubResult):
-    """Yield (needle_id, data, stored_crc) for every LIVE needle, walking
-    the .dat through volume.iter_records (the single source of truth for
-    the on-disk record walk) on a private read-only handle — no lock
-    contention with writers. Garbage records (overwritten/tombstoned,
-    pre-vacuum) are skipped: rot in unreachable data must not alarm.
-    A walk that ends before the append offset (header rot desyncing the
-    record chain) is reported in res.error — the silent failure mode the
-    tool exists to catch."""
-    from .volume import iter_records
-    from .super_block import SUPER_BLOCK_SIZE
+def _iter_needles(v: Volume):
+    """The host loop's walk (scrub_walk.iter_needles) over the volume's
+    .dat on a private read-only handle, no lock contention with writers,
+    liveness from the needle map as it stands."""
     with v._lock:
         v._dat.flush()  # the private read handle must see buffered appends
         end = v._append_offset
-    last_end = SUPER_BLOCK_SIZE
+
+    def where(nid: int):
+        nv = v.nm.get(nid)
+        return None if nv is None else nv.offset
     with open(v.dat_path, "rb") as f:
-        for pos, nid, nsize in iter_records(f, SUPER_BLOCK_SIZE, end):
-            last_end = pos + record_size_from_header(nsize)
-            if t.is_tombstone(nsize):
-                continue
-            nv = v.nm.get(nid)
-            if nv is None or nv.offset != pos:
-                continue  # garbage: overwritten or tombstoned version
-            f.seek(pos + t.NEEDLE_HEADER_SIZE)
-            body = f.read(nsize + 4)
-            (dlen,) = struct.unpack_from("<I", body, 0)
-            if dlen + 4 > nsize:
-                # live record whose length field is itself rotted
-                res.corrupt.append(nid)
-                res.scanned += 1
-                continue
-            yield (nid, bytes(body[4:4 + dlen]),
-                   struct.unpack_from("<I", body, nsize)[0])
-    if last_end < end:
-        res.error = (f"record walk torn at offset {last_end}: "
-                     f"{end - last_end} trailing bytes unscanned "
-                     f"(header rot or torn write)")
+        yield from scrub_walk.iter_needles(f, end, where)
 
 
-def _pack(shape: "tuple[int, int]",
-          datas: "list[bytes]") -> "tuple[np.ndarray, np.ndarray]":
-    """Up to B needles LEFT-zero-padded into one fixed [B, L] block, and
-    their lengths."""
-    rows, pad_l = shape
-    blocks = np.zeros((rows, pad_l), dtype=np.uint8)
-    lengths = np.zeros(rows, dtype=np.int64)
-    for i, d in enumerate(datas):
-        lengths[i] = len(d)
-        if d:
-            blocks[i, pad_l - len(d):] = np.frombuffer(d, np.uint8)
-    return blocks, lengths
+def _shared_slots(size: int):
+    """(fd, mapping) of a memory file of `size` bytes for the walker to
+    inherit; (-1, a bytearray) where none can be made (no memfd, or a file
+    size limit under `size`), and then the walker streams its blocks."""
+    try:
+        fd = os.memfd_create("swtpu-scrub")
+    except (AttributeError, OSError):
+        return -1, bytearray(size)
+    try:
+        os.ftruncate(fd, size)
+        return fd, mmap.mmap(fd, size)
+    except OSError:
+        os.close(fd)
+        return -1, bytearray(size)
+
+
+class _Walker:
+    """One volume's walker process and the block slots it packs into, as
+    the sweep's thread sees them (the protocol: storage/scrub_walk.py)."""
+
+    def __init__(self, v: Volume):
+        # what the walk needs, taken as the host loop takes it: the .dat
+        # flushed, the append offset, and the needle map's live entries;
+        # and the .dat opened beside them, under the same lock, for the
+        # walker to inherit: a vacuum that swaps the file afterwards does
+        # not reach the walk
+        with v._lock:
+            v._dat.flush()
+            end = v._append_offset
+            keys, offsets, sizes = v.nm.map.items_arrays()
+            dat = os.open(v.dat_path, os.O_RDONLY)
+        self.slot_bytes = max(_DISPATCH_BYTES, scrub_walk.bucket(
+            int(sizes.max()) if sizes.size else 0))
+        self.rows = scrub_walk.meta_rows(_DISPATCH_BYTES)
+        self.stride = scrub_walk.slot_stride(self.slot_bytes,
+                                             _DISPATCH_BYTES)
+        slots = _IN_FLIGHT + 1
+        fd, self.mm = _shared_slots(self.stride * slots)
+        try:
+            path = os.environ.get("PYTHONPATH")
+            self.proc = subprocess.Popen(
+                [sys.executable, *_WALKER_CMD, str(fd), str(dat)],
+                stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+                pass_fds=(fd, dat) if fd >= 0 else (dat,),
+                env={**os.environ, **_WALKER_MALLOC, "PYTHONPATH":
+                     f"{_ROOT}{os.pathsep}{path}" if path else _ROOT})
+        finally:
+            os.close(dat)
+            if fd >= 0:
+                os.close(fd)
+        self.streamed = fd < 0
+        self.ended = False
+        self.send(json.dumps({
+            "end": end, "dispatch_bytes": _DISPATCH_BYTES,
+            "slots": slots, "slot_bytes": self.slot_bytes,
+            "live": int(keys.size)}).encode() + b"\n"
+            + keys.astype(np.uint64).tobytes()
+            + (offsets.astype(np.uint64) * t.NEEDLE_PADDING).tobytes())
+
+    def send(self, data: bytes) -> None:
+        """To the walker; one that has died shows it as the end of its
+        messages, so a broken pipe is not raised here."""
+        try:
+            self.proc.stdin.write(data)
+            self.proc.stdin.flush()
+        except BrokenPipeError:
+            pass
+
+    def messages(self):
+        """The walker's messages, up to its `end` or its death; a streamed
+        block's rows and arrays are read into its slot first."""
+        for line in self.proc.stdout:
+            msg = json.loads(line)
+            if "block" in msg and self.streamed and not self._read_in(msg):
+                return  # the walker died inside the block
+            yield msg
+            if msg.get("end"):
+                self.ended = True
+                return
+
+    def _read_in(self, msg: dict) -> bool:
+        at, n = msg["block"] * self.stride, msg["n"]
+        meta = at + self.slot_bytes
+        for off, size in ((at, n * msg["L"]), (meta, 8 * n),
+                          (meta + 8 * self.rows, 8 * n),
+                          (meta + 16 * self.rows, 4 * n)):
+            if self.proc.stdout.readinto(
+                    memoryview(self.mm)[off:off + size]) != size:
+                return False
+        return True
+
+    def block(self, msg: dict):
+        """(the [B, L] block in its slot, ids, lengths, stored CRCs)."""
+        rows, pad_l, n = msg["rows"], msg["L"], msg["n"]
+        at = msg["block"] * self.stride
+        blocks = np.frombuffer(self.mm, np.uint8, rows * pad_l,
+                               at).reshape(rows, pad_l)
+        at += self.slot_bytes
+        ids = np.frombuffer(self.mm, np.uint64, n, at).copy()
+        lengths = np.zeros(rows, dtype=np.int64)
+        lengths[:n] = np.frombuffer(self.mm, np.uint64, n, at + 8 * self.rows)
+        stored = np.frombuffer(self.mm, np.uint32, n,
+                               at + 16 * self.rows).copy()
+        return blocks, ids, lengths, stored
+
+    def close(self) -> int:
+        """Join the walker, killed first unless it has sent its end; its
+        exit code."""
+        if not self.ended:
+            self.proc.kill()
+        for pipe in (self.proc.stdin, self.proc.stdout):
+            try:
+                pipe.close()
+            except BrokenPipeError:
+                pass
+        try:
+            rc = self.proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:  # an end sent, and no exit
+            self.proc.kill()
+            rc = self.proc.wait()
+        if not self.streamed:
+            try:
+                self.mm.close()
+            except BufferError:  # a block view still alive: freed with it
+                pass
+        return rc
 
 
 def _device_stage(acct, blocks: np.ndarray, needed: int) -> np.ndarray:
@@ -182,6 +296,89 @@ def _device_stage(acct, blocks: np.ndarray, needed: int) -> np.ndarray:
     with acct.stage("device", needed=needed, dispatched=blocks.size,
                     L=blocks.shape[1]):
         return np.asarray(_crc_jit()(blocks))
+
+
+def _host_loop(v: Volume, res: ScrubResult, acct) -> None:
+    """Every live needle's CRC on the host, in this thread: `walk`."""
+    with acct.stage("walk"):
+        for ev in _iter_needles(v):
+            if ev[0] == "needle":
+                _, nid, data, crc = ev
+                res.scanned += 1
+                res.bytes_checked += len(data)
+                if crcmod.crc32c(data) != crc:
+                    res.corrupt.append(nid)
+            elif ev[0] == "rotted":
+                res.scanned += 1
+                res.corrupt.append(ev[1])
+            else:
+                res.error = ev[1]
+
+
+def _device_sweep(v: Volume, res: ScrubResult, acct) -> None:
+    """The walker's blocks through the sweep's own device thread, compared
+    in dispatch order; no thread and no process outlives it."""
+    device_thread = ThreadPoolExecutor(max_workers=1,
+                                       thread_name_prefix="scrub-device")
+    flying: deque = deque()  # (future, slot, ids, lengths, stored)
+    walker = None
+    last: dict = {}
+
+    def collect(leave: int) -> None:
+        """Compare, in dispatch order, the blocks whose states are back,
+        waiting for the oldest while more than `leave` are in flight, and
+        give their slots back to the walker."""
+        while flying and (len(flying) > leave or flying[0][0].done()):
+            fut, slot, ids, lengths, stored = flying.popleft()
+            if not fut.done():
+                with acct.stage("wait"):
+                    wait((fut,))
+            raw = fut.result()  # or what the device stage raised
+            with acct.stage("compare"):
+                got = crcmod.finalize(raw.astype(np.uint32),
+                                      lengths)[:len(ids)]
+                res.corrupt.extend(ids[got != stored].tolist())
+            walker.send(bytes([slot]))
+
+    with acct.stage("recv"):
+        t0 = time.perf_counter()
+        try:
+            walker = _Walker(v)
+            for msg in walker.messages():
+                if not res.spawn_s:
+                    res.spawn_s = time.perf_counter() - t0
+                if "block" in msg:
+                    blocks, ids, lengths, stored = walker.block(msg)
+                    res.scanned += len(ids)
+                    res.bytes_checked += int(lengths.sum())
+                    res.blocks += 1
+                    res.bytes_dispatched += blocks.size
+                    # under a copy of this thread's context, as every
+                    # executor hop
+                    flying.append((device_thread.submit(
+                        contextvars.copy_context().run, _device_stage, acct,
+                        blocks, int(lengths.sum())), msg["block"], ids,
+                        lengths, stored))
+                    del blocks
+                    collect(_IN_FLIGHT)
+                elif "rotted" in msg:
+                    res.scanned += 1
+                    res.corrupt.append(msg["rotted"])
+                else:
+                    last = msg
+            collect(0)
+        finally:
+            # joined on every way out: no thread or process outlives it
+            flying.clear()
+            device_thread.shutdown(wait=True, cancel_futures=True)
+            rc = walker.close() if walker is not None else None
+    if last:
+        res.error = last["error"]
+        res.walk_s, res.pack_s = last["walk_s"], last["pack_s"]
+        res.walker_busy_s = last["busy_s"]
+    else:
+        res.error = (f"scrub walker exited {rc} before the volume's end: "
+                     f"{res.scanned} needles scanned, the rest unscanned")
 
 
 def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
@@ -203,86 +400,22 @@ def scrub_volume(v: Volume, device: str = "auto") -> ScrubResult:
         raise devgate.DeviceError(
             "volume.scrub -device on needs a TPU; this process runs on "
             f"platform={backend.platform if backend else None!r}")
-    if backend is not None:
+    from ..tracing import StageAccount
+    if backend is None:
+        acct = StageAccount("scrub", ("walk",))
+        _host_loop(v, res, acct)
+        res.elapsed_s = res.walk_s = acct.seconds("walk")  # the whole loop
+    else:
         # "device" is a TPU's word; the same kernel on the CPU backend a
         # process was told to use says so
         res.mode = "device" if backend.platform == "tpu" \
             else f"xla-{backend.platform}"
-    from ..tracing import StageAccount
-    acct = StageAccount("scrub", ("walk", "pack", "wait", "device", "compare"))
-    t0 = time.monotonic()
-    pending: "dict[tuple[int, int], tuple[list, list, list]]" = {}
-    # the sweep's own device thread, none in the host loop, and the blocks
-    # it holds, oldest first
-    device_thread = None if backend is None else ThreadPoolExecutor(
-        max_workers=1, thread_name_prefix="scrub-device")
-    flying: deque = deque()
-
-    def collect(leave: int) -> None:
-        """Compare, in dispatch order, the blocks whose states are back,
-        waiting for the oldest while more than `leave` are in flight."""
-        while flying and (len(flying) > leave or flying[0][0].done()):
-            fut, ids, lengths, stored = flying.popleft()
-            if not fut.done():
-                with acct.stage("wait"):
-                    wait((fut,))
-            raw = fut.result()  # or what the device stage raised
-            with acct.stage("compare"):
-                got = crcmod.finalize(raw.astype(np.uint32),
-                                      lengths)[:len(ids)]
-                bad = np.nonzero(got != np.array(stored, dtype=np.uint32))[0]
-                res.corrupt.extend(ids[int(i)] for i in bad)
-
-    def dispatch(shape, ids, datas, stored) -> None:
-        with acct.stage("pack"):
-            blocks, lengths = _pack(shape, datas)
-        # under a copy of this thread's context, as every executor hop
-        flying.append((device_thread.submit(
-            contextvars.copy_context().run, _device_stage, acct, blocks,
-            int(lengths.sum())), ids, lengths, stored))
-        res.blocks += 1
-        res.bytes_dispatched += shape[0] * shape[1]
-        collect(_IN_FLIGHT)
-
-    needles = _iter_needles(v, res)
-
-    def next_block():
-        """Walk on to the next full [B, L] block (None: the walk is over),
-        checking on the host right here where no backend is up."""
-        for nid, data, crc in needles:
-            res.scanned += 1
-            res.bytes_checked += len(data)
-            if backend is None:
-                if crcmod.crc32c(data) != crc:
-                    res.corrupt.append(nid)
-                continue
-            shape = _block_shape(len(data))
-            ids, datas, stored = pending.setdefault(shape, ([], [], []))
-            ids.append(nid)
-            datas.append(data)
-            stored.append(crc)
-            if len(ids) == shape[0]:
-                return (shape, *pending.pop(shape))
-        return None
-
-    try:
-        while True:
-            with acct.stage("walk"):  # from one dispatch to the next
-                block = next_block()
-            if block is None:
-                break
-            dispatch(*block)
-        for shape, batch in pending.items():
-            dispatch(shape, *batch)
-        collect(0)
-    finally:
-        if device_thread is not None:
-            # joined on every way out: no thread outlives the sweep
-            device_thread.shutdown(wait=True, cancel_futures=True)
-    res.elapsed_s = time.monotonic() - t0
-    (res.walk_s, res.pack_s, res.device_s, res.compare_s,
-     res.device_busy_s) = (acct.seconds(k) for k in (
-         "walk", "pack", "wait", "compare", "device"))
+        acct = StageAccount("scrub", ("recv", "wait", "device", "compare"))
+        _device_sweep(v, res, acct)
+        (res.recv_s, res.device_s, res.compare_s, res.device_busy_s) = (
+            acct.seconds(k) for k in ("recv", "wait", "compare", "device"))
+        # the enclosing stage's wall, whole: its three parts add up to it
+        res.elapsed_s = res.recv_s + res.device_s + res.compare_s
     if res.corrupt:
         log.warning("scrub volume %d: %d/%d needles corrupt: %s",
                     v.id, len(res.corrupt), res.scanned,

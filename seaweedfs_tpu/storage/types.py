@@ -47,6 +47,30 @@ def actual_record_size(data_block_size: int) -> int:
     return raw + (NEEDLE_PADDING - rem if rem else 0)
 
 
+def record_size_from_header(size: int) -> int:
+    """Padded record length given the header's size field."""
+    return actual_record_size(0 if size == TOMBSTONE_SIZE else size)
+
+
+def iter_records(f, start: int, end: int):
+    """Walk whole needle records in [start, end): yields
+    (offset, needle_id, header_size). Stops at the first torn/partial record.
+    Single source of truth for the on-disk record walk (used by load-time
+    integrity check, by idx-rebuild repair and by the scrub)."""
+    pos = start
+    while pos + NEEDLE_HEADER_SIZE <= end:
+        f.seek(pos)
+        hdr = f.read(NEEDLE_HEADER_SIZE)
+        if len(hdr) < NEEDLE_HEADER_SIZE:
+            return
+        _, nid, nsize = struct.unpack("<IQI", hdr)
+        rec = record_size_from_header(nsize)
+        if pos + rec > end:
+            return
+        yield pos, nid, nsize
+        pos += rec
+
+
 class DiskType(str, enum.Enum):
     HDD = "hdd"
     SSD = "ssd"

@@ -21,10 +21,10 @@ from __future__ import annotations
 import os
 
 from . import types as t
-from .needle import record_size_from_header
+from .types import iter_records, record_size_from_header
 from .super_block import SUPER_BLOCK_SIZE, SuperBlock
 from .needle_map import write_idx_entries, _ENTRY
-from .volume import Volume, iter_records
+from .volume import Volume
 from ..utils import fsutil
 
 import numpy as np

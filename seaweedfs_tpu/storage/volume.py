@@ -11,11 +11,12 @@ from __future__ import annotations
 import os
 import threading
 
-from . import types as t
 from . import read_cache
+from . import types as t
+from .types import iter_records, record_size_from_header
 from ..utils import failpoints
 from ..utils.log import logger
-from .needle import Needle, record_size_from_header
+from .needle import Needle
 from .needle_map import NeedleMap, idx_entries_numpy
 from .super_block import SUPER_BLOCK_SIZE, SuperBlock
 
@@ -26,27 +27,6 @@ class VolumeClosedError(OSError):
     """A lock-free read raced this volume's close (vacuum commit swap,
     unmount). The volume OBJECT is dead but the volume usually is not —
     the store retries once through its fresh mapping."""
-
-
-def iter_records(f, start: int, end: int):
-    """Walk whole needle records in [start, end): yields
-    (offset, needle_id, header_size). Stops at the first torn/partial record.
-    Single source of truth for the on-disk record walk (used by load-time
-    integrity check and by idx-rebuild repair)."""
-    import struct
-
-    pos = start
-    while pos + t.NEEDLE_HEADER_SIZE <= end:
-        f.seek(pos)
-        hdr = f.read(t.NEEDLE_HEADER_SIZE)
-        if len(hdr) < t.NEEDLE_HEADER_SIZE:
-            return
-        _, nid, nsize = struct.unpack("<IQI", hdr)
-        rec = record_size_from_header(nsize)
-        if pos + rec > end:
-            return
-        yield pos, nid, nsize
-        pos += rec
 
 
 def rebuild_idx_from_dat(dat_path: str, idx_path: str) -> int:

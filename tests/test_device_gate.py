@@ -212,36 +212,38 @@ def test_scrub_dispatch_stays_under_byte_bound(tmp_path, monkeypatch):
     rng = np.random.default_rng(3)
     v = Volume(str(tmp_path), "", 1)
     sizes = [1024] * 2500 + [4 << 20] + [1024] * 500 + [700, 5000, 0]
+    datas = {}
     for i, size in enumerate(sizes, start=1):
-        v.write_needle(Needle(id=i, cookie=1, data=rng.integers(
-            0, 256, size, dtype=np.uint8).tobytes()))
-    shapes, packed, crcs = [], [], []
-    real_jit, real_pack = scrub._crc_jit(), scrub._pack
+        datas[i] = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+        v.write_needle(Needle(id=i, cookie=1, data=datas[i]))
+    shapes, received, crcs = [], [], []
+    real_jit, real_block = scrub._crc_jit(), scrub._Walker.block
     real_finalize = scrub.crcmod.finalize
 
     def spy_jit(blocks):
         shapes.append(blocks.shape)
         return real_jit(blocks)
 
-    def spy_pack(shape, datas):
-        packed.append(datas)
-        return real_pack(shape, datas)
+    def spy_block(self, msg):
+        out = real_block(self, msg)
+        received.append(out[1])  # the block's needle ids
+        return out
 
     def spy_finalize(raw, lengths):
         crcs.append(real_finalize(raw, lengths))
         return crcs[-1]
     monkeypatch.setattr(scrub, "_crc_jit", lambda: spy_jit)
-    monkeypatch.setattr(scrub, "_pack", spy_pack)
+    monkeypatch.setattr(scrub._Walker, "block", spy_block)
     monkeypatch.setattr(scrub.crcmod, "finalize", spy_finalize)
     res = scrub.scrub_volume(v, device="auto")
     v.close()
     assert res.scanned == len(sizes)
     assert res.corrupt == [] and res.mode == "xla-cpu"
-    # blocks are compared in the order they were packed
-    assert len(packed) == len(crcs) == len(shapes) == res.blocks
-    for datas, out in zip(packed, crcs):
-        assert [int(c) for c in out[:len(datas)]] == [
-            crc32c.crc32c(d) for d in datas]
+    # blocks are compared in the order the walker packed them
+    assert len(received) == len(crcs) == len(shapes) == res.blocks
+    for ids, out in zip(received, crcs):
+        assert [int(c) for c in out[:len(ids)]] == [
+            crc32c.crc32c(datas[i]) for i in ids]
     assert max(b * l for b, l in shapes) <= scrub._DISPATCH_BYTES
     assert set(shapes) == {scrub._block_shape(n) for n in set(sizes)}
 

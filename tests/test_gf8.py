@@ -121,6 +121,28 @@ def test_device_crc_batch():
         assert int(vals[i]) == crc32c.crc32c(m.tobytes()), f"len={lengths[i]}"
 
 
+@pytest.mark.parametrize("lengths", [
+    [1024] * 8000 + [0] * 192,  # a scrub block of 1 KB needles
+    list(range(0, 4097)),
+    [(1 << 47) + 5, (1 << 48) - 1, 123456789, 0],
+    [[3, 9, 0], [1 << 20, 7, 7]],
+    [],
+], ids=["1kb_block", "every_length_to_4k", "wide", "two_dims", "empty"])
+def test_zero_prefix_correction_matches_the_bitwise_matvec(lengths):
+    """The byte-table form against A^len @ INIT one length at a time, by
+    the bitwise matrix powers, whatever the lengths share or leave out."""
+    lengths = np.array(lengths, dtype=np.int64)
+    got = crc32c.zero_prefix_correction(lengths)
+    assert got.shape == lengths.shape and got.dtype == np.uint32
+    mats = crc32c._a_pow2()
+    for n, g in zip(lengths.ravel()[::37].tolist(), got.ravel()[::37]):
+        want = np.array([0xFFFFFFFF], dtype=np.uint32)
+        for j in range(48):
+            if (n >> j) & 1:
+                want = crc32c._matvec_u32(mats[j], want)
+        assert int(g) == int(want[0]), n
+
+
 @pytest.mark.parametrize("chunk", [64, 256, 512])
 @pytest.mark.parametrize("t", [0, 1, 2, 3, 5, 8, 13, 64])
 def test_device_crc_fold_matches_host(t, chunk):

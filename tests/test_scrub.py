@@ -184,6 +184,31 @@ def test_pipelined_sweep_agrees_with_host_loop_in_dispatch_order(seeded):
     assert host.device_s == host.device_busy_s == 0.0
 
 
+def test_iter_blocks_yields_the_dispatch_order(seeded):
+    """The walk the walker process runs, called in-process: the blocks in
+    the order `_dispatch_order` pins, each needle LEFT-zero-padded into
+    its row, with its length and stored CRC beside it."""
+    from seaweedfs_tpu.ops import crc32c
+    from seaweedfs_tpu.storage import scrub, scrub_walk
+    v, sizes = seeded
+    keys, offsets, _ = v.nm.map.items_arrays()
+    got = list(scrub_walk.iter_blocks(
+        v.dat_path, v._append_offset, keys, offsets.astype(int) * 8,
+        scrub._DISPATCH_BYTES))
+    assert [(shape, ids) for _, shape, _, ids, _, _ in got] == \
+        _dispatch_order(sizes)
+    bad = []
+    for _, (rows, pad_l), buf, ids, lengths, stored in got:
+        assert len(buf) == rows * pad_l == scrub._DISPATCH_BYTES
+        assert lengths == [sizes[nid] for nid in ids]
+        for row, (nid, n, crc) in enumerate(zip(ids, lengths, stored)):
+            data = bytes(buf[row * pad_l:(row + 1) * pad_l])
+            assert data[:pad_l - n] == bytes(pad_l - n)
+            if crc32c.crc32c(data[pad_l - n:]) != crc:
+                bad.append(nid)
+    assert sorted(bad) == sorted(_ROTTEN)
+
+
 def _boom(*_a, **_kw):
     raise RuntimeError("XLA said no")
 
@@ -198,16 +223,17 @@ def _device_fails_on_third_block(monkeypatch):
     monkeypatch.setattr(scrub, "_crc_jit", lambda: flaky)
 
 
-def _walk_fails_midway(monkeypatch):
+def _walker_dies_midway(monkeypatch):
+    """The walker process killed as its third block arrives."""
     from seaweedfs_tpu.storage import scrub
-    real = scrub._iter_needles
+    real, calls = scrub._Walker.block, []
 
-    def walk(v, res):
-        for n, needle in enumerate(real(v, res)):
-            if n == 300:
-                _boom()
-            yield needle
-    monkeypatch.setattr(scrub, "_iter_needles", walk)
+    def block(self, msg):
+        calls.append(1)
+        if len(calls) == 3:
+            self.proc.kill()
+        return real(self, msg)
+    monkeypatch.setattr(scrub._Walker, "block", block)
 
 
 def _tear_the_walk(v):
@@ -216,72 +242,96 @@ def _tear_the_walk(v):
         f.write(struct.pack("<I", 0x0FFFFFFF))
 
 
+def _children() -> "list[int]":
+    import psutil
+    return [p.pid for p in psutil.Process().children(recursive=True)]
+
+
 @pytest.mark.parametrize("way_out", ["device_error", "walk_error",
                                      "torn_walk", "clean"])
 def test_no_thread_outlives_a_sweep(seeded, monkeypatch, way_out):
     """What the device stage raises comes out of scrub_volume (the RPC's
-    per-volume `except` isolates it there), and on every way out the
-    sweep's device thread has been joined."""
+    per-volume `except` isolates it there); a walker that dies mid-volume
+    is the volume's error, never a clean result with fewer needles; and on
+    every way out the sweep's device thread and its walker process have
+    been joined."""
     import threading
-    v, _ = seeded
+    v, sizes = seeded
     if way_out == "device_error":
         _device_fails_on_third_block(monkeypatch)
     elif way_out == "walk_error":
-        _walk_fails_midway(monkeypatch)
+        _walker_dies_midway(monkeypatch)
     elif way_out == "torn_walk":
         _tear_the_walk(v)
-    before = threading.enumerate()
-    if way_out.endswith("_error"):
+    before, children = threading.enumerate(), _children()
+    if way_out == "device_error":
         with pytest.raises(RuntimeError, match="XLA said no"):
             scrub_volume(v, device="auto")
     else:
         res = scrub_volume(v, device="auto")
         assert ("torn" in res.error) == (way_out == "torn_walk")
+        assert ("before the volume's end" in res.error) == (
+            way_out == "walk_error")
         assert res.blocks > 0
-        assert set(res.corrupt) == {n for n in _ROTTEN
-                                    if way_out == "clean" or n < 400}
+        assert (res.scanned < len(sizes)) == (way_out != "clean")
+        if way_out != "walk_error":
+            assert set(res.corrupt) == {n for n in _ROTTEN
+                                        if way_out == "clean" or n < 400}
     assert threading.enumerate() == before
+    assert _children() == children
 
 
 def test_slow_device_stage_holds_the_sweep_at_its_bound(seeded, monkeypatch):
-    """With the device side the slower one, the sweep's thread runs ahead
-    by the bound and no further; its wait is `device_s`, under the device
-    thread's own `device_busy_s`, and its four stages still partition the
-    sweep."""
+    """With the device side the slower one, the walker and the sweep's
+    thread run ahead by the bound and no further: at most _IN_FLIGHT
+    blocks handed over and not yet compared beside the one just received,
+    which the walker's _IN_FLIGHT + 1 slots enforce. The device call of
+    block k waits until block k + _IN_FLIGHT has arrived (or the last), so
+    the bound is reached whatever the machine's speed. The sweep's wait is
+    `device_s`, the device thread's own seconds `device_busy_s`, and recv,
+    wait and compare partition the sweep."""
     import threading
-    import time
 
     from seaweedfs_tpu.storage import scrub
-    v, _ = seeded
-    real_jit, real_pack = scrub._crc_jit(), scrub._pack
+    v, sizes = seeded
+    total = len(_dispatch_order(sizes))
+    real_jit, real_block = scrub._crc_jit(), scrub._Walker.block
     real_finalize = scrub.crcmod.finalize
-    alive, packed, compared, threads = [], [], [], set()
+    alive, compared, threads = [], [], set()
+    received = threading.Condition()
+    got = [0, 0]  # blocks received, device calls made
 
     def slow(blocks):
         threads.add(threading.current_thread().name)
-        time.sleep(0.004)
+        with received:
+            got[1] += 1
+            assert received.wait_for(
+                lambda: got[0] >= min(got[1] + scrub._IN_FLIGHT, total),
+                timeout=60)
         return real_jit(blocks)
 
-    def pack(shape, datas):
-        packed.append(1)  # this block, and those not yet compared
-        alive.append(len(packed) - len(compared))
-        return real_pack(shape, datas)
+    def block(self, msg):
+        with received:
+            got[0] += 1
+            alive.append(got[0] - len(compared))  # this block and those
+            received.notify_all()                 # not yet compared
+        return real_block(self, msg)
 
     def finalize(raw, lengths):
         compared.append(1)
         return real_finalize(raw, lengths)
     monkeypatch.setattr(scrub, "_crc_jit", lambda: slow)
-    monkeypatch.setattr(scrub, "_pack", pack)
+    monkeypatch.setattr(scrub._Walker, "block", block)
     monkeypatch.setattr(scrub.crcmod, "finalize", finalize)
     res = scrub_volume(v, device="auto")
     assert set(res.corrupt) == set(_ROTTEN)
-    assert len(packed) == len(compared) == res.blocks
+    assert got == [len(compared), len(compared)] == [res.blocks] * 2
     assert max(alive) == scrub._IN_FLIGHT + 1  # reached, never passed
     assert len(threads) == 1 and threading.current_thread().name not in threads
-    assert res.device_busy_s >= 0.004 * res.blocks
-    assert 0 < res.device_s <= res.device_busy_s
-    assert (res.walk_s + res.pack_s + res.device_s
-            + res.compare_s) >= 0.9 * res.elapsed_s
+    assert res.device_busy_s > 0 and res.spawn_s > 0
+    assert res.recv_s + res.device_s + res.compare_s == pytest.approx(
+        res.elapsed_s, rel=1e-9)
+    assert res.walker_busy_s >= res.walk_s + res.pack_s > 0
 
 
 @pytest.mark.parametrize("device", ["off", "auto_without_backend"])
@@ -293,6 +343,7 @@ def test_host_loop_starts_no_thread(seeded, monkeypatch, device):
     if device != "off":
         monkeypatch.setattr(scrub.devgate, "current", lambda: None)
     monkeypatch.setattr(scrub, "ThreadPoolExecutor", _boom)
+    monkeypatch.setattr(scrub.subprocess, "Popen", _boom)
     started = []
     monkeypatch.setattr(threading.Thread, "start",
                         lambda th: started.append(th.name))
@@ -300,11 +351,13 @@ def test_host_loop_starts_no_thread(seeded, monkeypatch, device):
     assert not started
     assert res.mode == "cpu" and set(res.corrupt) == set(_ROTTEN)
     assert res.scanned == len(sizes)
+    assert res.recv_s == res.spawn_s == res.walker_busy_s == 0.0
 
 
 def test_concurrent_sweeps_do_not_mix_their_blocks(seeded):
-    """Each sweep owns its device thread and its account: four at once,
-    the interpreter switching threads every 10 us, report what one does."""
+    """Each sweep owns its device thread, its walker process with its
+    slots, and its account: four at once, the interpreter switching
+    threads every 10 us, report what one does."""
     import sys
     import threading
     v, _ = seeded
@@ -332,6 +385,7 @@ def test_concurrent_sweeps_do_not_mix_their_blocks(seeded):
             for r in got] == [(want.corrupt, want.scanned, want.blocks,
                                want.bytes_dispatched)] * 4
     assert threading.enumerate() == before
+    assert not _children()
 
 
 @pytest.mark.parametrize("n", [1, 512, 513, 64 << 10, 1 << 20, 4 << 20])

@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.storage import types as t
-from seaweedfs_tpu.storage.needle import Needle, record_size_from_header
+from seaweedfs_tpu.storage.needle import Needle
+from seaweedfs_tpu.storage.types import record_size_from_header
 from seaweedfs_tpu.storage.needle_map import CompactMap, NeedleMap, idx_entries_numpy
 from seaweedfs_tpu.storage.super_block import SuperBlock
 from seaweedfs_tpu.storage.vacuum import commit_compact, compact
