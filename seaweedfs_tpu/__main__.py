@@ -289,21 +289,14 @@ def run_server(argv):
 
 
 def run_shell(argv):
+    """The shell in this process: the REPL, and every `shell -c` that the
+    master's resident executor does not take (shell/executor.py)."""
     main_at = _MAIN_AT or time.perf_counter()
-    from .shell import (ec_commands, fs_commands,  # noqa: F401 (register)
-                        lifecycle_commands, mq_commands, qos_commands,
-                        remote_commands, telemetry_commands,
-                        volume_commands)
-    from .shell.commands import CommandEnv, process_line, repl, run_command
-    p = argparse.ArgumentParser(prog="shell")
-    p.add_argument("-master", default="127.0.0.1:9333")
-    p.add_argument("-filer", default="",
-                   help="default filer host:port for fs.* commands")
-    p.add_argument("-jwtSigningKey", default="",
-                   help="cluster signing key for gRPC auth")
-    p.add_argument("-c", dest="script", default="",
-                   help="run semicolon-separated commands and exit")
-    opt = p.parse_args(argv)
+    from .shell.client import process_line, shell_parser
+    from .shell.commands import CommandEnv, repl, run_command
+    from .shell.executor import load_commands
+    load_commands()
+    opt = shell_parser().parse_args(argv)
     if opt.jwtSigningKey:
         from .utils.rpc import set_cluster_key
         set_cluster_key(opt.jwtSigningKey)
@@ -327,6 +320,7 @@ def run_shell(argv):
             env.release_lock()
         finally:
             # the process's own phases, on every way out
+            print("shell via=process", file=sys.stderr)
             print(process_line(env, main_at), file=sys.stderr, flush=True)
         if failed:
             sys.exit(2)
@@ -1398,6 +1392,13 @@ def main():
             print(f"  {v}")
         return 0
     verb = sys.argv[1]
+    if verb == "shell":
+        # decided before _init_tls, which loads grpc: a `shell -c` against
+        # a local master runs in the master's resident executor
+        from .shell.client import client_main
+        rc = client_main(sys.argv[2:], _MAIN_AT)
+        if rc is not None:
+            return rc
     _init_tls()
     fn = VERBS.get(verb)
     if fn is None:

@@ -18,7 +18,8 @@ from ..pb import master_pb2 as pb
 from ..storage.types import TTL, ReplicaPlacement, file_id
 from ..utils import failpoints
 from ..utils.log import logger
-from ..utils.rpc import MASTER_SERVICE, RpcService, Stub, VOLUME_SERVICE, serve
+from ..utils.rpc import (MASTER_SERVICE, VOLUME_SERVICE, RpcService, Stub,
+                         serve, tls_enabled)
 from .sequencer import MemorySequencer, SnowflakeSequencer
 from .topology import EcShardInfo, Topology, VolumeInfo
 from .volume_growth import GrowRequest, VolumeGrowth
@@ -91,6 +92,7 @@ class MasterServer:
         # guessing its HTTP port from a gRPC hint
         self._leader_http_hint: tuple[str, str] = ("", "")
         self._grpc = None
+        self._shell = None  # the resident shell executor, once started
         self._http = None
         self._http_stop = None
         # profiling plane: loop-lag probe on the fastweb HTTP loop +
@@ -386,10 +388,25 @@ class MasterServer:
             self._metrics_push = start_push_loop(
                 self.metrics_gateway, f"master-{self.address}",
                 self.metrics_interval_s)
+        if not key and not tls_enabled():
+            # last: importing the command table delays no other server
+            self._start_shell_executor()
         log.info("master up at %s (leader)", self.address)
+
+    def _start_shell_executor(self) -> None:
+        """`shell -c` scripts of local operators run here, in the master
+        (shell/executor.py); a master that cannot listen for them leaves
+        every verb to its own process."""
+        from ..shell.executor import ShellExecutor
+        try:
+            self._shell = ShellExecutor(self.port).start()
+        except OSError as e:
+            log.warning("shell executor not started: %s", e)
 
     def stop(self) -> None:
         self._stop.set()
+        if self._shell is not None:
+            self._shell.stop()
         self.admin_cron.stop()
         self.telemetry.stop()
         if self._metrics_push is not None:

@@ -31,9 +31,13 @@ from ..storage.vacuum import commit_compact, compact
 from ..telemetry.hot import record as hot_record
 from ..utils import failpoints, fsutil, retry
 from ..utils.log import logger
-from ..utils.rpc import MASTER_SERVICE, RpcService, Stub, VOLUME_SERVICE, serve
+from ..utils.rpc import (MASTER_SERVICE, VOLUME_SERVICE, RpcService, Stub,
+                         drop_channel, serve)
 
 log = logger("volume")
+
+# the pause between attempts to reach a master not yet registered with
+_HB_FIRST_RETRY_S = 0.1
 
 
 def _observe_stages(kind: str, t_recv: float, t_parsed: float, t0: float,
@@ -358,6 +362,12 @@ class VolumeServer:
             self._hb_wake.clear()
 
     def _heartbeat_loop(self) -> None:
+        # until a master first answers, a failed stream is retried every
+        # _HB_FIRST_RETRY_S: a server started beside its master registers
+        # as soon as the master listens, not up to a pulse later; after
+        # that, a pulse (at most 2 s) apart
+        registered = False
+        failures = 0
         while not (self._stop.is_set() or self._leave.is_set()):
             try:
                 stub = Stub(self.current_leader, MASTER_SERVICE)
@@ -371,6 +381,7 @@ class VolumeServer:
                     stream.cancel()
                     return
                 for resp in stream:
+                    registered = True
                     # master answers 1:1 AFTER ingesting each heartbeat:
                     # the oldest in-flight snapshot is now master-visible
                     with self._hb_cond:
@@ -387,15 +398,23 @@ class VolumeServer:
                         return
             except Exception as e:  # noqa: BLE001
                 if not self._stop.is_set():
-                    log.warning("heartbeat to %s failed: %s; retrying",
-                                self.current_leader, e)
+                    failures += 1
+                    (log.warning if registered or failures == 1
+                     else log.debug)("heartbeat to %s failed: %s; retrying",
+                                     self.current_leader, e)
+                    if not registered:
+                        # a fresh channel dials at once; the failed one
+                        # would wait out gRPC's reconnect backoff (1 s,
+                        # then 1.6 s, ...) before it tried again
+                        drop_channel(self.current_leader)
                     if len(self.masters) > 1:
                         self._master_rr = ((self._master_rr + 1)
                                            % len(self.masters))
                         self.current_leader = self.masters[self._master_rr]
                     # interruptible wait: a stop() during the retry pause
                     # must not leave a zombie heartbeat thread behind
-                    self._stop.wait(min(self.pulse_seconds, 2.0))
+                    self._stop.wait(min(self.pulse_seconds, 2.0 if registered
+                                        else _HB_FIRST_RETRY_S))
             finally:
                 with self._hb_cond:
                     # unacked sends died with the stream; the next stream

@@ -18,6 +18,8 @@ from typing import Callable, TextIO
 from ..client.master_client import MasterClient
 from ..pb import master_pb2 as mpb
 from ..utils.rpc import MASTER_SERVICE, Stub
+# the `process` line, re-exported: a verb's process writes it on either path
+from .client import process_line  # noqa: F401
 
 COMMANDS: dict[str, "Command"] = {}
 
@@ -50,7 +52,12 @@ class CommandEnv:
     lock_token: int = 0
     lock_time: int = 0
     out: TextIO = None
+    err: TextIO = None  # the `timing` lines' stream; None: sys.stderr
     option: dict = field(default_factory=dict)
+    # the script's caller's working directory ("": this process's) and
+    # configuration search path (None: this process's)
+    cwd: str = ""
+    config_dirs: "list[str] | None" = None
     # the `process` line's marks (perf_counter): the first command's
     # start, the last one's end, and (total, trace id) of each command
     first_at: float = 0.0
@@ -65,6 +72,16 @@ class CommandEnv:
 
     def println(self, *args) -> None:
         print(*args, file=self.out)
+
+    def local_path(self, path: str) -> str:
+        """A local file a command names, where the script's caller meant
+        it: relative paths against the caller's working directory."""
+        return os.path.join(self.cwd, path) if self.cwd else path
+
+    def load_config(self, name: str) -> dict:
+        """`<name>.toml` on the script's caller's search path."""
+        from ..utils import config as cfg
+        return cfg.load_config(name, self.config_dirs)
 
     # -- exclusive lock (reference command_lock_unlock.go) ------------------
     def acquire_lock(self) -> None:
@@ -150,8 +167,8 @@ def _run_line(env: CommandEnv, line: str) -> bool:
             total = time.perf_counter() - t0
             env.ran.append((total, sp.context.trace_id))
             if rpcs is not None:
-                print(timing_line(name, total, rpcs), file=sys.stderr,
-                      flush=True)
+                print(timing_line(name, total, rpcs),
+                      file=env.err or sys.stderr, flush=True)
     return True
 
 
@@ -169,53 +186,6 @@ def timing_line(verb: str, total_s: float, rpcs) -> str:
              f"rpc={sum(secs.values()):.3f}"]
     parts += [f"{m}={s:.3f}/{rpcs.count(m)}" for m, s in secs.items()]
     return " ".join(parts)
-
-
-def process_line(env: CommandEnv, main_at: float) -> str:
-    """`process start=<s> imports=<s> commands=<s> between=<s> release=<s>
-    birth_ns=<wall ns> traces=<id>,...`: what one `shell -c` process did
-    besides its commands, printed once after the final release_lock
-    (`__main__.run_shell`). The five phases partition the process's life
-    up to the line: `start`, birth (the kernel's start time of the
-    process) to `main()` (`main_at`: interpreter, `site`, the package's
-    import); `imports`, to the first command's `run_command` (TLS, the
-    command modules, argparse, `CommandEnv`); `commands`, the sum of the
-    commands' `total`s as their `timing` lines give them; `between`, the
-    rest from the first command's start to the last one's end;
-    `release`, from there to this line. Teardown and the reap come after
-    the line: only the caller's wall sees them. `birth_ns` is the birth
-    on the wall clock; `traces` are the commands' `shell/<name>` trace
-    ids, in order, which their calls carry to the servers."""
-    now = time.perf_counter()
-    born, birth_ns = _birth(now, main_at)
-    first = env.first_at or now
-    last = env.last_at or first
-    commands = sum(total for total, _ in env.ran)
-    phases = {"start": main_at - born, "imports": first - main_at,
-              "commands": commands, "between": last - first - commands,
-              "release": now - last}
-    return ("process " + " ".join(f"{k}={max(0.0, v):.3f}"
-                                  for k, v in phases.items())
-            + f" birth_ns={birth_ns} traces="
-            + ",".join(trace for _, trace in env.ran))
-
-
-def _birth(now: float, main_at: float) -> "tuple[float, int]":
-    """The process's birth on perf_counter's clock and in wall-clock ns:
-    /proc/self/stat field 22 (clock ticks since boot) against
-    CLOCK_BOOTTIME. Where that cannot be read, or reads no sane age
-    (after `main_at`, or a minute before it), `main_at`."""
-    try:
-        with open("/proc/self/stat") as f:
-            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
-        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
-               - ticks / os.sysconf("SC_CLK_TCK"))
-    except (OSError, ValueError, IndexError, AttributeError):
-        age = now - main_at
-    if not 0.0 <= main_at - (now - age) < 60.0:
-        age = now - main_at
-    birth_ns = time.time_ns() - int(age * 1e9)  # swtpu-lint: disable=wallclock-duration (an instant on the wall clock, for the trace's)
-    return now - age, birth_ns
 
 
 def repl(env: CommandEnv) -> None:

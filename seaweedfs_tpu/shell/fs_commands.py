@@ -414,7 +414,7 @@ def cmd_fs_meta_save(env: CommandEnv, args):
     opt = p.parse_args(args)
     stub = _filer_stub(env, opt.filer)
     n = 0
-    with open(opt.output, "wb") as f:
+    with open(env.local_path(opt.output), "wb") as f:
         for path, e in _walk(stub, _abs(env, opt.path).rstrip("/") or "/"):
             d, _, _name = path.rpartition("/")
             fe = fpb.FullEntry(dir=d or "/", entry=e)
@@ -434,7 +434,7 @@ def cmd_fs_meta_load(env: CommandEnv, args):
     opt = p.parse_args(args)
     stub = _filer_stub(env, opt.filer)
     n = errors = 0
-    with open(opt.input, "rb") as f:
+    with open(env.local_path(opt.input), "rb") as f:
         while True:
             hdr = f.read(4)
             if len(hdr) < 4:
@@ -777,7 +777,7 @@ def cmd_fs_meta_change_volume_id(env: CommandEnv, args):
     opt = p.parse_args(args)
     mapping: dict[int, int] = {}
     if opt.mapping:
-        with open(opt.mapping) as f:
+        with open(env.local_path(opt.mapping)) as f:
             for line in f:
                 line = line.strip()
                 if not line or line.startswith("#"):
@@ -824,8 +824,7 @@ def cmd_fs_meta_notify(env: CommandEnv, args):
     opt = p.parse_args(args)
     spec = opt.queue
     if not spec:
-        from ..utils.config import load_config
-        spec = (load_config("notification") or {}).get("queue", "")
+        spec = env.load_config("notification").get("queue", "")
     if not spec:
         env.println("no queue: pass -queue or configure notification.toml")
         return

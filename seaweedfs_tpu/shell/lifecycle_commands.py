@@ -97,7 +97,7 @@ def cmd_lifecycle_apply(env: CommandEnv, args):
     p.add_argument("-maxTransitions", type=int, default=16)
     p.add_argument("-maxConcurrent", type=int, default=2)
     opt = p.parse_args(args)
-    policy = parse_policy(opt.policy)
+    policy = parse_policy(env.local_path(opt.policy))
     plan = build_lifecycle_plan(env, policy)
     plan.render(env.println)
     # ONE executor per CommandEnv: failure cooldowns and per-volume

@@ -15,7 +15,6 @@ tests and containers). Values are plain dicts; `get_dotted` resolves
 from __future__ import annotations
 
 import os
-import tomllib
 
 SEARCH_DIRS = [
     ".",
@@ -30,19 +29,21 @@ def search_dirs() -> list[str]:
     return ([extra] if extra else []) + SEARCH_DIRS
 
 
-def find_config(name: str) -> str | None:
-    for d in search_dirs():
+def find_config(name: str, dirs: "list[str] | None" = None) -> str | None:
+    for d in search_dirs() if dirs is None else dirs:
         path = os.path.join(d, f"{name}.toml")
         if os.path.isfile(path):
             return path
     return None
 
 
-def load_config(name: str) -> dict:
-    """Parse the first `<name>.toml` on the tier chain ({} if none)."""
-    path = find_config(name)
+def load_config(name: str, dirs: "list[str] | None" = None) -> dict:
+    """Parse the first `<name>.toml` on the tier chain, or on `dirs` in
+    its place ({} if none)."""
+    path = find_config(name, dirs)
     if path is None:
         return {}
+    import tomllib  # only where there is a file: a `shell -c` has none
     with open(path, "rb") as f:
         return tomllib.load(f)
 
@@ -58,3 +59,14 @@ def get_dotted(conf: dict, key: str, default=None):
             return default
         cur = cur[part]
     return cur
+
+
+def grpc_tls() -> "tuple[str, str, str, str]":
+    """security.toml [grpc] ca, cert, key and server_name on the tier
+    chain, "" where unset (server_name "swtpu"). Needs no grpc: a `shell
+    -c` client reads it to choose its path before anything loads grpc."""
+    sec = load_config("security")
+    return (get_dotted(sec, "grpc.ca", "") or "",
+            get_dotted(sec, "grpc.cert", "") or "",
+            get_dotted(sec, "grpc.key", "") or "",
+            get_dotted(sec, "grpc.server_name", "swtpu"))

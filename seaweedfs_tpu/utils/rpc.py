@@ -11,6 +11,7 @@ cached channels.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import threading
 import time
 from concurrent import futures
@@ -73,16 +74,16 @@ def set_tls_config(tls: "TlsConfig | None") -> None:
         _channel_cache.clear()
 
 
+def tls_enabled() -> bool:
+    return _tls_config is not None
+
+
 def load_tls_from_security_toml() -> "TlsConfig | None":
     """[grpc] ca / cert / key on the config tier chain (tls.go analogue).
     A PARTIAL [grpc] section raises rather than silently running plaintext
     (fail closed — the operator clearly intended TLS)."""
     from . import config as cfg
-    sec = cfg.load_config("security")
-    ca = cfg.get_dotted(sec, "grpc.ca", "")
-    cert = cfg.get_dotted(sec, "grpc.cert", "")
-    key = cfg.get_dotted(sec, "grpc.key", "")
-    name = cfg.get_dotted(sec, "grpc.server_name", "swtpu")
+    ca, cert, key, name = cfg.grpc_tls()
     if not (ca or cert or key):
         return None
     if not (ca and cert and key):
@@ -463,6 +464,24 @@ class _SpannedStream:
             self.cancel()
 
 
+# The calls of a shell script run for a client in another process
+# (shell/executor.py) carry an Event of that client's here: once the
+# client has gone, the script's next call raises instead of running on
+# under an admin lease that no one is there to give back.
+CALL_GATE: "contextvars.ContextVar[threading.Event | None]" = \
+    contextvars.ContextVar("swtpu_call_gate", default=None)
+
+
+class CallerGone(RuntimeError):
+    pass
+
+
+def _check_gate() -> None:
+    gate = CALL_GATE.get()
+    if gate is not None and gate.is_set():
+        raise CallerGone("the shell client that ran this script has gone")
+
+
 class Stub:
     """Thin client for one service on one address. A call made inside a
     trace runs under a child span `rpc.client/<Method>` (peer as attr)
@@ -474,6 +493,7 @@ class Stub:
         self._ch = channel(address)
 
     def call(self, method: str, request, resp_cls, timeout: float = 30.0):
+        _check_gate()
         fn = self._ch.unary_unary(
             f"/{self.service}/{method}",
             request_serializer=type(request).SerializeToString,
@@ -493,6 +513,7 @@ class Stub:
             _end_client_span(sp, acct, method, time.perf_counter() - t0)
 
     def call_stream(self, method: str, request, resp_cls, timeout: float = 300.0):
+        _check_gate()
         fn = self._ch.unary_stream(
             f"/{self.service}/{method}",
             request_serializer=type(request).SerializeToString,
@@ -513,6 +534,7 @@ class Stub:
         yields already serialized (bytes), and return its future: grpc
         consumes the iterator on a thread of its own, and the call's
         client span ends when the reply (or the error) arrives."""
+        _check_gate()
         fn = self._ch.stream_unary(f"/{self.service}/{method}",
                                    request_serializer=None,
                                    response_deserializer=resp_cls.FromString)
@@ -532,6 +554,7 @@ class Stub:
         return fut
 
     def stream_stream(self, method: str, request_iter, req_cls, resp_cls):
+        _check_gate()
         fn = self._ch.stream_stream(
             f"/{self.service}/{method}",
             request_serializer=req_cls.SerializeToString,

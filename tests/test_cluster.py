@@ -276,3 +276,32 @@ def test_ec_shard_location_cache_tiers(tmp_path):
     locs, fetched, complete = vs._ec_loc_cache[5]
     vs._ec_loc_cache[5] = (locs, fetched - 3000, complete)
     assert vs._lookup_ec_shards(5) == {0: ["a:1"], 1: ["b:1"]}
+
+
+def test_a_server_started_before_its_master_registers_once_it_listens(
+        tmp_path):
+    """A volume server whose master is not listening yet retries at once
+    when the master comes up, not a pulse later (a fresh channel, not
+    the failed one's reconnect backoff): a verb run right after both
+    started sees it."""
+    mport, port = free_port(), free_port()
+    store = Store("127.0.0.1", port, f"127.0.0.1:{port}",
+                  [DiskLocation(str(tmp_path), max_volume_count=4)],
+                  coder_name="numpy")
+    vs = VolumeServer(store, f"127.0.0.1:{mport}", port=port,
+                      grpc_port=free_port(), pulse_seconds=2.0)
+    vs.start()
+    master = MasterServer(port=mport, volume_size_limit_mb=64)
+    try:
+        # the first heartbeats fail, no master listening: the failed
+        # channel's reconnect backoff (1 s ± 20%, then 1.6 s ± 20%) and a
+        # pulse's pause (2 s) both end well after 0.4 s past the start
+        time.sleep(1.3)
+        master.start()
+        up = time.monotonic()
+        while not master.topo.nodes:
+            assert time.monotonic() - up < 0.4, "not registered"
+            time.sleep(0.005)
+    finally:
+        vs.stop()
+        master.stop()
