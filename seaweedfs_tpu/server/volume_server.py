@@ -16,6 +16,7 @@ import time
 import urllib.parse
 
 from ..ec import MAX_SHARD_ID
+from ..ec import degraded as ec_degraded
 from ..ec import files as ec_files
 from ..ec import push as ec_push
 from ..ec import repair as ec_repair
@@ -23,12 +24,14 @@ from ..ec.encoder import rebuild_shards
 from ..ec.locate import EcGeometry
 from ..pb import master_pb2 as mpb
 from ..pb import volume_server_pb2 as vpb
-from ..stats import VOLUME_STORE_READ_SECONDS
+from ..stats import (EC_RECONSTRUCT_BYTES, EC_RECONSTRUCT_SECONDS,
+                     VOLUME_STORE_READ_SECONDS)
 from ..storage.needle import Needle
 from ..storage.store import Store
 from ..storage.types import TTL, parse_file_id
 from ..storage.vacuum import commit_compact, compact
 from ..telemetry.hot import record as hot_record
+from ..tracing.stages import StageAccount
 from ..utils import failpoints, fsutil, retry
 from ..utils.log import logger
 from ..utils.rpc import (MASTER_SERVICE, VOLUME_SERVICE, RpcService, Stub,
@@ -1939,61 +1942,69 @@ class VolumeServer:
                 return _reconstruct_msr(ev, shard_id, offset, length,
                                         locs, sp)
             piggybacked = ev.codec == "piggyback"
-            gathered: dict[int, bytes] = {}
-            remote_sids = []
-            for sid in range(geo.n):
-                if sid == shard_id:
-                    continue
-                local = ev.shards.get(sid)
-                if local is not None and len(gathered) < geo.d:
-                    gathered[sid] = local.read_at(offset, length)
-                elif local is None:
-                    remote_sids.append(sid)
-            sp.set_attr("local_shards", len(gathered))
-            # piggybacked volumes: shards 0..d (data + the unpiggybacked
-            # parity) decode positionally anywhere, so fetch those first
-            # and touch piggybacked parities only when the plain set
-            # cannot reach d (they need a paired a-range fetch to strip)
-            if piggybacked:
-                waves = [[s for s in remote_sids if s <= geo.d],
-                         [s for s in remote_sids if s > geo.d]]
-            else:
-                waves = [remote_sids]
-            for wave in waves:
-                if len(gathered) >= geo.d or not wave:
-                    continue
-                import concurrent.futures as cf
-                import contextvars
-                # copy_context per submit: the pool threads' fetch spans
-                # must land under THIS reconstruct span, not as orphan
-                # roots (ThreadPoolExecutor does not propagate contextvars)
-                futs = {}
-                for sid in wave:
-                    ctx = contextvars.copy_context()
-                    futs[self._ec_read_pool.submit(
-                        ctx.run, self._fetch_remote_shard, vid, sid,
-                        offset, length, locs.get(sid, []))] = sid
-                for fut in cf.as_completed(futs):
-                    data = fut.result()
-                    if data is not None:
-                        gathered[futs[fut]] = data
-                    if len(gathered) >= geo.d:
-                        for f in futs:  # stop burning pool workers on
-                            f.cancel()  # fetches nobody will use
-                        break
-            if len(gathered) < geo.d:
-                # healthy shards alone can't reach d: as a last resort
-                # probe the circuit-open holders too — an open breaker
-                # should cost latency, never turn a recoverable read
-                # into an error
-                for sid in remote_sids:
-                    if sid in gathered or len(gathered) >= geo.d:
+            coder = self.store.coder(geo.d, geo.p, codec=ev.codec)
+            inner = coder.inner if piggybacked else coder
+            where = ec_degraded.where(inner)
+            sp.set_attr("where", where)
+            acct = StageAccount("degraded", ("gather", "decode"))
+            with acct.stage("gather"):
+                gathered: dict[int, bytes] = {}
+                remote_sids = []
+                for sid in range(geo.n):
+                    if sid == shard_id:
                         continue
-                    data = self._fetch_remote_shard(
-                        vid, sid, offset, length, locs.get(sid, []),
-                        include_open=True)
-                    if data is not None:
-                        gathered[sid] = data
+                    local = ev.shards.get(sid)
+                    if local is not None and len(gathered) < geo.d:
+                        gathered[sid] = local.read_at(offset, length)
+                    elif local is None:
+                        remote_sids.append(sid)
+                sp.set_attr("local_shards", len(gathered))
+                # piggybacked volumes: shards 0..d (data + the
+                # unpiggybacked parity) decode positionally anywhere, so
+                # fetch those first and touch piggybacked parities only
+                # when the plain set cannot reach d (they need a paired
+                # a-range fetch to strip)
+                if piggybacked:
+                    waves = [[s for s in remote_sids if s <= geo.d],
+                             [s for s in remote_sids if s > geo.d]]
+                else:
+                    waves = [remote_sids]
+                for wave in waves:
+                    if len(gathered) >= geo.d or not wave:
+                        continue
+                    import concurrent.futures as cf
+                    import contextvars
+                    # copy_context per submit: the pool threads' fetch
+                    # spans must land under THIS reconstruct span, not as
+                    # orphan roots (ThreadPoolExecutor does not propagate
+                    # contextvars)
+                    futs = {}
+                    for sid in wave:
+                        ctx = contextvars.copy_context()
+                        futs[self._ec_read_pool.submit(
+                            ctx.run, self._fetch_remote_shard, vid, sid,
+                            offset, length, locs.get(sid, []))] = sid
+                    for fut in cf.as_completed(futs):
+                        data = fut.result()
+                        if data is not None:
+                            gathered[futs[fut]] = data
+                        if len(gathered) >= geo.d:
+                            for f in futs:  # stop burning pool workers on
+                                f.cancel()  # fetches nobody will use
+                            break
+                if len(gathered) < geo.d:
+                    # healthy shards alone can't reach d: as a last
+                    # resort probe the circuit-open holders too — an open
+                    # breaker should cost latency, never turn a
+                    # recoverable read into an error
+                    for sid in remote_sids:
+                        if sid in gathered or len(gathered) >= geo.d:
+                            continue
+                        data = self._fetch_remote_shard(
+                            vid, sid, offset, length, locs.get(sid, []),
+                            include_open=True)
+                        if data is not None:
+                            gathered[sid] = data
             sp.set_attr("gathered", len(gathered))
             sp.set_attr("needed", geo.d)
             if len(gathered) < geo.d:
@@ -2003,10 +2014,7 @@ class VolumeServer:
                 raise OSError(
                     f"cannot reconstruct shard {shard_id}: only "
                     f"{len(gathered)} shards reachable")
-            import numpy as np
-
             present = tuple(sorted(gathered))[:geo.d]
-            coder = self.store.coder(geo.d, geo.p, codec=ev.codec)
             from ..stats import DEGRADED_EC_READS
             if piggybacked and any(s > geo.d for s in present):
                 # a piggybacked parity is load-bearing: strip its
@@ -2036,10 +2044,15 @@ class VolumeServer:
                     fetch_map=fetch_map)
                 DEGRADED_EC_READS.inc()
                 return out_b
-            inner = coder.inner if piggybacked else coder
-            sl = np.stack([np.frombuffer(gathered[s], dtype=np.uint8)
-                           for s in present])
-            out = np.asarray(inner.reconstruct(sl, present, (shard_id,)))
+            with acct.stage("decode", needed=length * (geo.d + 1),
+                            cols=length, where=where):
+                out = ec_degraded.decode_interval(
+                    inner, [gathered[s] for s in present], present,
+                    (shard_id,))
+            for stage in ("gather", "decode"):
+                EC_RECONSTRUCT_SECONDS.observe(stage, where,
+                                               value=acct.seconds(stage))
+            EC_RECONSTRUCT_BYTES.inc(where, amount=length)
             DEGRADED_EC_READS.inc()
             return out[0].tobytes()
 

@@ -390,6 +390,21 @@ BREAKER_TRANSITIONS = _counter(
 DEGRADED_EC_READS = _counter(
     "SeaweedFS_degraded_ec_reads_total",
     "EC reads served by reconstructing from surviving shards")
+# One degraded read's reconstruct of an interval (ec/degraded.py): the
+# gather of d survivors and the decode, and the bytes decoded, by where
+# the decode ran (host|device): the split says what sets a degraded
+# GET's time, and how much of it the chip took.
+EC_RECONSTRUCT_SECONDS = _histogram(
+    "SeaweedFS_volumeServer_ec_reconstruct_seconds",
+    "degraded EC read seconds per reconstructed interval, by stage "
+    "(gather, decode) and where the decode ran",
+    ("stage", "where"),
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.1, 1.0))
+EC_RECONSTRUCT_BYTES = _counter(
+    "SeaweedFS_volumeServer_ec_reconstruct_bytes_total",
+    "lost-shard bytes decoded by degraded EC reads, by where the decode "
+    "ran", ("where",))
 # Tracing layer (tracing/trace.py): spans recorded per component, and
 # spans evicted from the bounded ring buffer before anyone read them.
 TRACE_SPANS = _counter(

@@ -30,7 +30,14 @@ Stage intervals do not go into the /debug/traces ring (a scrub would
 evict every request trace within a minute): `publish` sets the sums as
 attrs on the operation's own span, and `fields` feeds its finish event.
 The granularity is the batch or block — on the order of 100 stages a
-second at most — never the needle or a served request.
+second at most — never the needle or a served request. One operation is
+finer than a batch and is its own: a degraded EC read's reconstruct of
+one interval (`StageAccount("degraded")`, `server/volume_server.py`),
+whose two stages `gather` (d survivors: this server's shards read, the
+rest fetched) and `decode` (carrying `needed`, `cols` and `where` =
+host|device, `ec/degraded.py`) are what a degraded GET waits
+on; their seconds go to `SeaweedFS_volumeServer_ec_reconstruct_seconds`
+and never to a span ring.
 """
 
 from __future__ import annotations

@@ -255,6 +255,9 @@ def cluster(tmp_path_factory):
             time.sleep(0.1)
     mc = MasterClient(f"127.0.0.1:{mport}").start()
     mc.wait_connected()
+    # an assign before the server's first heartbeat finds no slots to grow
+    wait_until(lambda: master.topo.all_nodes(), timeout=15,
+               msg="the volume server registered")
     yield master, vs, mc
     mc.stop()
     vs.stop()

@@ -165,7 +165,21 @@ class TestSampler:
 class TestLagMonitors:
     def test_loop_lag_probe_detects_blocked_loop(self):
         from seaweedfs_tpu.stats import EVENT_LOOP_LAG
-        mon = LoopLagMonitor("lagtest", interval_s=0.02)
+
+        class Recording(LoopLagMonitor):
+            """Keeps each probe's lag as the loop thread books it: with
+            a probe every 20 ms, the one right after the block is
+            overwritten before a polling thread can read it."""
+
+            def __init__(self, *args, **kwargs):
+                super().__init__(*args, **kwargs)
+                self.seen: "list[tuple[int, float]]" = []
+
+            def _tick(self):
+                super()._tick()
+                self.seen.append((self.probes, self.last_lag_s))
+
+        mon = Recording("lagtest", interval_s=0.02)
         loop = asyncio.new_event_loop()
         t = threading.Thread(target=loop.run_forever, daemon=True)
         t.start()
@@ -178,12 +192,25 @@ class TestLagMonitors:
             before = EVENT_LOOP_LAG.count("lagtest")
             assert before >= 1
             # block the loop thread outright: the next probe fires late
-            # by roughly the block length
-            probes0 = mon.probes
-            loop.call_soon_threadsafe(time.sleep, 0.3)
-            wait_until(lambda: mon.probes > probes0, timeout=10,
+            # by roughly the block length. The count is read on the loop
+            # thread as the block starts, so a probe that fires before it
+            # does not count as the one after it.
+            probes0 = []
+
+            def block():
+                probes0.append(mon.probes)
+                time.sleep(0.3)
+            loop.call_soon_threadsafe(block)
+
+            def after_block() -> "list[float]":
+                # `seen` gains the probe once its whole tick, the
+                # histogram's observation included, has run
+                return [lag for n, lag in list(mon.seen)
+                        if probes0 and n == probes0[0] + 1]
+            wait_until(after_block, timeout=10,
                        msg="probe fired after the block")
-            assert mon.last_lag_s > 0.15, mon.last_lag_s
+            (last_lag_s,) = after_block()
+            assert last_lag_s > 0.15, last_lag_s
             assert EVENT_LOOP_LAG.count("lagtest") > before
         finally:
             loop.call_soon_threadsafe(mon.close)
